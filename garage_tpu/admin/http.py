@@ -947,6 +947,10 @@ class AdminHttpServer:
                        "Corruptions found across all scrub passes")
             out.append("# TYPE block_scrub_corruptions counter")
             gauge("block_scrub_corruptions", sw.state.corruptions)
+            gauge("block_scrub_last_completed_seconds",
+                  sw.state.last_completed,
+                  "Unix time the last scrub pass finished (0 = running "
+                  "or never)")
             out.append("# TYPE block_scrub_deep_stripes_checked counter")
             gauge("block_scrub_deep_stripes_checked", sw.deep_checked)
             out.append("# TYPE block_scrub_deep_stripes_repaired counter")
@@ -1113,6 +1117,35 @@ class AdminHttpServer:
               "Decode/repair items that ran on the device path (the "
               "read-side engagement proof metric)")
         gauge("feeder_decode_device_bytes", fs["decode_device_bytes"])
+        for op, n in sorted(feeder.device_items_by_op.items()):
+            gauge("feeder_device_op_items", n, op=op)
+        gauge("feeder_device_errors", fs["device_errors"],
+              "Device legs that raised or hung")
+        gauge("feeder_host_reruns", fs["host_reruns"],
+              "Failed device legs re-run on the host (mode auto only)")
+        # the one device verdict of this process and the route it led
+        # to (block/feeder.py): which platform the feeder's own backend
+        # thread got from jax.devices(), and why the route is what it is
+        reason = " ".join(feeder.route_reason.replace('"', "'").split())
+        gauge("feeder_device_route", 1,
+              "Route of device-eligible batches, with the reason",
+              mode=feeder.mode, route=feeder.route, reason=reason[:200])
+        di = feeder.device_info
+        if di is not None:
+            gauge("feeder_device_count", di["count"],
+                  "Devices jax.devices() gave this process",
+                  platform=di["platform"], device_kind=di["device_kind"])
+        # JAX's own count of compilations in this process, beside the
+        # feeder's shape accounting above: a gap between the two is a
+        # program that recompiles where the feeder sees one shape
+        from ..ops import jaxenv
+
+        cs = jaxenv.compile_stats()
+        gauge("feeder_xla_compile_requests", cs["compile_requests"])
+        gauge("feeder_xla_compiles", cs["compiles"],
+              "Programs XLA built (requests minus persistent-cache hits)")
+        gauge("feeder_xla_cache_hits", cs["cache_hits"])
+        gauge("feeder_xla_compile_seconds", cs["compile_seconds"])
         ps = feeder.pipeline_stats()
         gauge("feeder_inflight", ps["inflight"],
               "Batches currently in flight through the staged pipeline")

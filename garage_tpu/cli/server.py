@@ -43,6 +43,10 @@ async def run_server(cfg_path: str) -> None:
 
 async def _run_server_locked(cfg, cfg_path: str) -> None:
     garage = Garage(cfg)
+    if garage.block_manager.feeder.mode == "require":
+        # a node that must have its device does not come up without
+        # it: raises with the platform found (block/feeder.py)
+        await garage.block_manager.feeder.device_verdict()
     admin = AdminRpcHandler(garage)
     otlp = None
     if cfg.admin_trace_sink:

@@ -14,9 +14,13 @@ CPU, one block at a time. Here the block data path is batched math on TPU:
   treehash.py BLAKE3 tree hashing in JAX: 1 MiB block = 1024 chunks
               compressed in parallel on the VPU (replaces the reference's
               sequential blake2 block hash, src/block/manager.rs:554)
+  sha256.py   batched SHA-256 of SigV4 chunk payloads, lanes across
+              concurrent PUT streams
   pallas_gf.py fused Pallas TPU kernel for GF(2^8) matrix application:
-              unpack -> MXU matmul -> pack entirely in VMEM, cutting HBM
-              traffic ~9x vs the XLA bit-matmul path (measured on v5e-1:
-              8.4 vs 5.6 GB/s RS(10,4) encode); rs.py auto-selects it on
-              real TPU backends with XLA as the universal fallback
+              unpack -> MXU matmul -> pack entirely in VMEM, so HBM sees
+              only the raw bytes (the XLA path's 8x bit expansion stays
+              on chip); opt-in via GARAGE_TPU_PALLAS, the XLA path is
+              the default
+  jaxenv.py   the process's device verdict, compile-cache placement and
+              compile counters
 """

@@ -14,8 +14,10 @@ Layout per grid step (b, s):
   acc        (8m, T) i32 = bitmat @ bits   [MXU]
   parity     (m, T) u8   = pack(acc & 1)
 
-Used on real TPU backends only; CPU tests run it in interpreter mode
-(see tests/test_rs.py) and the production fallback is the XLA path.
+Compiled on TPU only (chip_smoke.py's kernel phase checks it there
+against the numpy reference); CPU tests run it in interpreter mode
+(tests/test_rs.py). The product launches the XLA path unless
+GARAGE_TPU_PALLAS is set (ops/rs._try_pallas).
 """
 
 from __future__ import annotations
@@ -39,8 +41,11 @@ def _kernel(mat_ref, x_ref, o_ref, *, k: int, m: int):
     import jax.numpy as jnp
 
     x = x_ref[...].astype(jnp.int32)  # (k, T)
-    # f32 matmul: this backend's Mosaic AOT path rejects int-typed
-    # dot_general; sums are <= 8k <= 2048 so f32 is exact
+    # f32 matmul: sums are <= 8k <= 2048, so f32 is exact. libtpu
+    # 0.0.34's Mosaic also compiles this dot_general with bf16 operands
+    # (f32 accumulation) and with int8 operands (int32 accumulation),
+    # both exact on the chip (PERF.md, PR 21); which operand type the
+    # product should launch is ROADMAP S7's question, not settled here
     bits = jnp.concatenate(
         [((x >> j) & 1).astype(jnp.float32) for j in range(8)],
         axis=0)  # (8k, T), row j*k+s
@@ -129,10 +134,7 @@ def encode(k: int, m: int, data, interpret: bool = False):
 
 
 def available() -> bool:
-    """Pallas TPU kernels need a real TPU backend."""
-    try:
-        import jax
+    """The compiled kernel needs a TPU (elsewhere it only interprets)."""
+    from . import jaxenv
 
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jaxenv.verdict()["platform"] == "tpu"

@@ -105,21 +105,13 @@ def _jit_apply(key, matrix_bytes, rows: int, cols: int):
     return apply
 
 
-_pallas_ok: bool | None = None
-
-
 def _try_pallas(mat: np.ndarray, x):
     """Fused Pallas kernel (ops/pallas_gf.py), opt-in via
-    GARAGE_TPU_PALLAS=1 on a real TPU. Measured on v5e-1 with
-    dependency-chained iterations (dispatch overhead amortized, no
-    async-overlap artifacts): XLA bit-matmul 15.5 GB/s vs Pallas
-    13.0 GB/s for RS(10,4) encode — XLA's fusion wins once the encode
-    is embedded in a larger jitted program, so it stays the default;
-    the kernel remains available for standalone-call workloads where
-    its single-pass HBM profile helps."""
-    global _pallas_ok
-    if _pallas_ok is False:
-        return None
+    GARAGE_TPU_PALLAS=1 on a TPU; -> None where it does not apply
+    (flag unset, another platform, a shard length it cannot tile) and
+    the XLA bit-matmul runs instead. Once it applies, a kernel that
+    fails to compile or run raises: whoever asked for the Pallas path
+    must not be handed an XLA result under its name."""
     import os
 
     if not os.environ.get("GARAGE_TPU_PALLAS"):
@@ -128,23 +120,14 @@ def _try_pallas(mat: np.ndarray, x):
     if len(shape) < 2:
         return None
     n = shape[-1]
-    from . import pallas_gf
-
     if n % 256 or n < 256:
         return None
-    if _pallas_ok is None:
-        if not pallas_gf.available():
-            _pallas_ok = False
-            return None
-    x3 = x.reshape((-1,) + tuple(shape[-2:]))
-    try:
-        out = pallas_gf.gf_apply(mat, x3)
-        _pallas_ok = True
-    except Exception:
-        # first failure disables the kernel for the process (a broken
-        # Mosaic path must not retry-compile per call)
-        _pallas_ok = False
+    from . import pallas_gf
+
+    if not pallas_gf.available():
         return None
+    x3 = x.reshape((-1,) + tuple(shape[-2:]))
+    out = pallas_gf.gf_apply(mat, x3)
     return out.reshape(tuple(shape[:-2]) + (mat.shape[0], n))
 
 

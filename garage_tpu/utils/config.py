@@ -41,7 +41,11 @@ class TpuConfig:
     # feeder's greedy-drain cap; 256 matches the previously hard-coded
     # value)
     batch_blocks: int = 256
-    # platform override for tests ("cpu" forces the jnp fallback path)
+    # the JAX platform the device path must find in this process
+    # (None = "tpu"). Under GARAGE_TPU_DEVICE=require anything else
+    # fails the boot; in auto mode it routes host-side, said once.
+    # Tests and `chip_smoke.py rehearse` name "cpu" to stand the CPU
+    # backend in for a chip.
     platform: Optional[str] = None
     # staged-pipeline depth: device batches concurrently in flight
     # through the h2d/compute/d2h stages (3 = one per stage; 2 = double

@@ -3,8 +3,8 @@
 Usage: python scripts/profile_put.py [nblocks] [--cprofile] [--mode=off]
 
 Imports bench.py's _build_cluster so the profile measures exactly what
-the bench measures (VERDICT r3 task 1: find the gap between the encode
-kernel and the end-to-end system number).
+the bench measures: the gap between the encode kernel and the
+end-to-end system number.
 """
 from __future__ import annotations
 
@@ -70,4 +70,3 @@ if __name__ == "__main__":
     n = int(sys.argv[1]) if len(sys.argv) > 1 and sys.argv[1].isdigit() else 128
     mode = "off" if "--mode=off" in sys.argv else "auto"
     asyncio.run(run(n, "--cprofile" in sys.argv, mode))
-    os._exit(0)

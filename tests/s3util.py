@@ -156,7 +156,8 @@ class S3Client:
                     trailer: Optional[tuple[str, str]] = None,
                     corrupt_chunk_sig: bool = False,
                     extra_headers: Optional[dict[str, str]] = None,
-                    query: Optional[list[tuple[str, str]]] = None):
+                    query: Optional[list[tuple[str, str]]] = None,
+                    timeout: float = 30.0):
         """PUT with aws-chunked signed framing (+ optional signed
         trailer)."""
         mode = ("STREAMING-AWS4-HMAC-SHA256-PAYLOAD-TRAILER" if trailer
@@ -185,7 +186,8 @@ class S3Client:
         if query:
             url += "?" + "&".join(
                 f"{uri_encode(k)}={uri_encode(v)}" for k, v in query)
-        conn = http.client.HTTPConnection(self.host, self.port, timeout=30)
+        conn = http.client.HTTPConnection(self.host, self.port,
+                                          timeout=timeout)
         try:
             conn.request("PUT", url, body=body, headers=headers)
             r = conn.getresponse()

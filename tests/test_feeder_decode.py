@@ -180,18 +180,22 @@ def test_rs_decode_repair_share_one_jit_across_patterns():
 
 
 def test_decode_hang_reruns_host_every_future_resolves(monkeypatch):
-    """Injected device hang with decode batches in flight at depth 2:
-    every caller gets the CORRECT packed bytes via the host re-run, no
-    future is lost, and the device path is disabled — the read-side
-    edition of the pipeline hang test."""
+    """mode="auto", injected device hang with decode batches in flight
+    at depth 2: every caller gets the CORRECT packed bytes via the host
+    re-run, no future is lost, and the device path is shut — the
+    read-side edition of the pipeline hang test."""
+    # conftest exports GARAGE_TPU_DEVICE=off (auto would become off)
     monkeypatch.delenv("GARAGE_TPU_DEVICE", raising=False)
     k, m = 4, 2
     codec = ErasureCodec(k, m, use_jax=False)
     stub = StubDeviceBackend(None, fixed_s=0.01)
     stub.hang_stage = "compute"
-    f = DeviceFeeder(codec=codec, mode="require", max_batch=2,
+    f = DeviceFeeder(codec=codec, mode="auto", max_batch=2,
                      backend=stub)
     f._device_ok = True
+    f.device_min_decode_items = 1  # 2-item batches take the device route
+    f._record("decode", "device", 1 << 30, 1.0)  # device "winning"
+    f._record("decode", "host", 1 << 20, 1.0)
     f.batch_timeout = 1.0
     rng = np.random.default_rng(31)
     blocks = [rng.integers(0, 256, 20_000 + i, dtype=np.uint8).tobytes()
@@ -218,6 +222,7 @@ def test_decode_hang_reruns_host_every_future_resolves(monkeypatch):
         assert got == want
     assert dev_ok is False
     assert f.stats["decode_device_items"] == 0
+    assert f.stats["host_reruns"] >= 1
 
 
 # ---------------------------------------------------------------------------
