@@ -1156,6 +1156,18 @@ class AdminHttpServer:
         for stage, s in ps["busy_s"].items():
             gauge("feeder_pipeline_busy_seconds", s, stage=stage)
 
+        # CPU seconds of the event loop's thread and of the whole
+        # process: whether the loop is out of CPU, or runnable and not
+        # running (this render runs in a worker thread, so the loop's
+        # clock is the one cli/server.py marked, not thread_time())
+        from ..utils.tracing import tracer
+
+        cpu = tracer.cpu_seconds()
+        gauge("node_cpu_seconds", round(cpu["all"], 6),
+              "CPU seconds (user + system) by thread", thread="all")
+        if "loop" in cpu:
+            gauge("node_cpu_seconds", round(cpu["loop"], 6), thread="loop")
+
         for wid, info in g.runner.worker_info().items():
             gauge("worker_busy", 1 if info.state == "busy" else 0,
                   worker=info.name)

@@ -438,37 +438,55 @@ class HttpServer:
 
                 from ..utils.tracing import span
 
+                # label cardinality is bounded: arbitrary client
+                # method strings must not grow the registry forever
+                method = (req.method if req.method in (
+                    "GET", "HEAD", "PUT", "POST", "DELETE",
+                    "OPTIONS") else "OTHER")
                 t0 = time.perf_counter()
-                try:
-                    async with span("http.request", api=self.name,
-                                    method=req.method, path=req.path[:128]):
-                        resp = await self.handler(req)
-                except HttpError as e:
-                    resp = Response(e.status, [("content-type", "text/plain")],
-                                    e.reason.encode())
-                except Exception:
-                    log.exception("%s handler error", self.name)
-                    self.metrics["errors"] += 1
-                    resp = Response(500, [("content-type", "text/plain")],
-                                    b"internal error")
-                registry().observe(
-                    "api_request_duration_seconds",
-                    time.perf_counter() - t0,
-                    api=self.name,
-                    # label cardinality is bounded: arbitrary client
-                    # method strings must not grow the registry forever
-                    method=(req.method if req.method in (
-                        "GET", "HEAD", "PUT", "POST", "DELETE",
-                        "OPTIONS") else "OTHER"),
-                    status=resp.status // 100 * 100)
-                try:
-                    await req.body.drain()  # finish consuming the body
-                except Exception:
-                    keep = False
-                try:
-                    await write_response(writer, req, resp, keep)
-                except (ConnectionError, asyncio.CancelledError):
-                    break
+                # handler, body drain and response under one span: the
+                # block fetches of a GET are tasks made while the body
+                # streams, after http.request has ended
+                async with span("http.exchange", api=self.name,
+                                method=req.method):
+                    try:
+                        async with span("http.request", api=self.name,
+                                        method=req.method,
+                                        path=req.path[:128]):
+                            resp = await self.handler(req)
+                    except HttpError as e:
+                        resp = Response(e.status,
+                                        [("content-type", "text/plain")],
+                                        e.reason.encode())
+                    except Exception:
+                        log.exception("%s handler error", self.name)
+                        self.metrics["errors"] += 1
+                        resp = Response(500, [("content-type", "text/plain")],
+                                        b"internal error")
+                    registry().observe(
+                        "api_request_duration_seconds",
+                        time.perf_counter() - t0,
+                        api=self.name, method=method,
+                        status=resp.status // 100 * 100)
+                    try:
+                        await req.body.drain()  # finish consuming the body
+                    except Exception:
+                        keep = False
+                    tw = time.perf_counter()
+                    try:
+                        async with span("http.write", api=self.name,
+                                        method=req.method):
+                            await write_response(writer, req, resp, keep)
+                    except (ConnectionError, asyncio.CancelledError):
+                        break
+                    finally:
+                        # head and body written; on a GET the whole
+                        # block streaming, which the handler's timer
+                        # above does not see
+                        registry().observe(
+                            "api_response_write_seconds",
+                            time.perf_counter() - tw,
+                            api=self.name, method=method)
                 if not keep:
                     break
         finally:

@@ -116,9 +116,13 @@ class StageJob:
     those effects twice. `busy` is the fn's exclusive execution time —
     what calibration records, NOT the pipeline wall (which includes
     queue wait behind sibling batches and would understate device
-    throughput by up to the in-flight depth)."""
+    throughput by up to the in-flight depth). `t_sub`, `t_claim`,
+    `t_done` are `perf_counter` stamps of submit, claim and fn return
+    (0.0 = not reached); the thread only writes its own two, and the
+    coroutine that waits for the job observes them on the loop."""
 
-    __slots__ = ("loop", "fut", "fn", "claimed", "busy")
+    __slots__ = ("loop", "fut", "fn", "claimed", "busy",
+                 "t_sub", "t_claim", "t_done")
 
     def __init__(self, loop, fn):
         self.loop = loop
@@ -126,6 +130,8 @@ class StageJob:
         self.fn = fn
         self.claimed = False
         self.busy = 0.0
+        self.t_sub = time.perf_counter()
+        self.t_claim = self.t_done = 0.0
 
 
 class StageExecutor:
@@ -155,12 +161,13 @@ class StageExecutor:
             if job.fut.cancelled():
                 continue  # abandoned while queued: never execute
             job.claimed = True
-            t0 = time.perf_counter()
+            job.t_claim = time.perf_counter()
             try:
                 res, err = job.fn(), None
             except BaseException as e:
                 res, err = None, e
-            job.busy = time.perf_counter() - t0
+            job.t_done = time.perf_counter()
+            job.busy = job.t_done - job.t_claim
             self._busy[self.name] += job.busy
 
             def deliver(fut=job.fut, res=res, err=err):

@@ -57,6 +57,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..utils import tracing
+from ..utils.metrics import registry
 from .device_backend import (DEFAULT_PAD_BUCKETS, STAGES, DevicePipeline,
                              JaxDeviceBackend, StubDeviceBackend,
                              group_bytes)
@@ -121,13 +123,29 @@ class _DeviceHang(Exception):
 
 
 class _Item:
-    __slots__ = ("op", "data", "future", "extra")
+    """One queued request. `t_sub`, `t_disp`, `t_set` are
+    `perf_counter` stamps of the hop's hand-offs: put on the queue, its
+    batch handed to the pipeline, its future set (0.0 = not reached)."""
+
+    __slots__ = ("op", "data", "future", "extra", "t_sub", "t_disp", "t_set")
 
     def __init__(self, op: str, data, future, extra=None):
         self.op = op
         self.data = data
         self.future = future
         self.extra = extra
+        self.t_sub = time.perf_counter()
+        self.t_disp = self.t_set = 0.0
+
+    def resolve(self, res) -> None:
+        """Hand the caller its result or error; the first one stands."""
+        if self.future.done():
+            return
+        self.t_set = time.perf_counter()
+        if isinstance(res, BaseException):
+            self.future.set_exception(res)
+        else:
+            self.future.set_result(res)
 
 
 class DeviceFeeder:
@@ -366,7 +384,8 @@ class DeviceFeeder:
         try:
             info = await asyncio.wait_for(
                 self._stage_call(self._pipeline(), "h2d",
-                                 lambda: self._get_backend().verdict(), []),
+                                 lambda: self._get_backend().verdict(), [],
+                                 "verdict"),
                 self.batch_timeout)
         except (asyncio.TimeoutError, _DeviceHang):
             self._on_device_hang(f"jax.devices() did not return within "
@@ -397,7 +416,7 @@ class DeviceFeeder:
                 try:
                     await asyncio.wait_for(
                         self._stage_call(self._pipeline(), "compute",
-                                         self._calibrate, []),
+                                         self._calibrate, [], "calibrate"),
                         self.batch_timeout)
                 except (asyncio.TimeoutError, _DeviceHang):
                     self._on_device_hang(
@@ -448,9 +467,7 @@ class DeviceFeeder:
         # awaits forever
         if q is not None:
             while not q.empty():
-                item = q.get_nowait()
-                if not item.future.done():
-                    item.future.set_exception(RuntimeError("feeder stopped"))
+                q.get_nowait().resolve(RuntimeError("feeder stopped"))
 
     def _calibrate(self) -> None:
         from ..utils import data as _data
@@ -508,6 +525,7 @@ class DeviceFeeder:
     # ---- public async ops ---------------------------------------------
 
     async def _submit(self, op: str, data, extra=None):
+        t_in = time.perf_counter()
         self._ensure_started()
         if self.mode == "require" and self._device_ok is not True:
             await self.device_verdict()
@@ -515,9 +533,23 @@ class DeviceFeeder:
             # up; restart it or the enqueued item below would await a
             # future nothing ever resolves
             self._ensure_started()
-        fut = asyncio.get_running_loop().create_future()
-        await self._q.put(_Item(op, data, fut, extra))
-        return await fut
+        item = _Item(op, data, asyncio.get_running_loop().create_future(),
+                     extra)
+        async with tracing.span("feeder.submit", op=op) as sp:
+            await self._q.put(item)
+            try:
+                return await item.future
+            finally:
+                if item.t_set:
+                    # hand-off 4 and the whole hop; a caller cancelled
+                    # before its item was answered observes neither
+                    now = time.perf_counter()
+                    reg = registry()
+                    reg.observe("feeder_resume_lag_seconds",
+                                now - item.t_set, hop="item")
+                    reg.observe("feeder_hop_seconds", now - t_in, op=op)
+                sp.attrs["wait_us"] = int(
+                    max(0.0, item.t_disp - item.t_sub) * 1e6)
 
     async def hash(self, data: bytes) -> bytes:
         """Content hash of one block (batched with concurrent callers)."""
@@ -838,6 +870,9 @@ class DeviceFeeder:
     # ---- dispatcher ----------------------------------------------------
 
     async def _run(self) -> None:
+        # the dispatcher is made inside whichever request came first:
+        # its spans, and its batches', belong to no request
+        tracing.detach()
         while True:
             first = await self._q.get()
             batch = [first]
@@ -862,18 +897,20 @@ class DeviceFeeder:
                     # is [tpu] batch_linger_ms.
                     loop = asyncio.get_running_loop()
                     deadline = loop.time() + self.batch_linger
-                    while n_same < want:
-                        left = deadline - loop.time()
-                        if left <= 0:
-                            break
-                        try:
-                            item = await asyncio.wait_for(
-                                self._q.get(), left)
-                        except asyncio.TimeoutError:
-                            break
-                        batch.append(item)
-                        if item.op == first.op:
-                            n_same += 1
+                    with tracing.span("feeder.linger", op=first.op,
+                                      have=n_same, want=want):
+                        while n_same < want:
+                            left = deadline - loop.time()
+                            if left <= 0:
+                                break
+                            try:
+                                item = await asyncio.wait_for(
+                                    self._q.get(), left)
+                            except asyncio.TimeoutError:
+                                break
+                            batch.append(item)
+                            if item.op == first.op:
+                                n_same += 1
                 self._maybe_start_verdict()
                 # bounded in-flight depth: the dispatcher hands the
                 # batch to the staged pipeline and goes straight back
@@ -881,41 +918,50 @@ class DeviceFeeder:
                 # while batch N computes, batch N+1 stages h2d and
                 # batch N-1 reads back. Depth is live-tunable
                 # ([tpu] inflight_batches via /v1/s3/tuning).
-                while len(self._inflight_tasks) >= max(
+                if len(self._inflight_tasks) >= max(
                         1, self.inflight_batches):
-                    await asyncio.wait(self._inflight_tasks,
-                                       return_when=asyncio.FIRST_COMPLETED)
+                    with tracing.span("feeder.slot_wait", op=first.op,
+                                      inflight=len(self._inflight_tasks)):
+                        while len(self._inflight_tasks) >= max(
+                                1, self.inflight_batches):
+                            await asyncio.wait(
+                                self._inflight_tasks,
+                                return_when=asyncio.FIRST_COMPLETED)
+                # hand-off 1 ends here: drain, linger and slot wait
+                now = time.perf_counter()
+                reg = registry()
+                for item in batch:
+                    item.t_disp = now
+                    reg.observe("feeder_queue_wait_seconds",
+                                now - item.t_sub, op=item.op)
                 t = asyncio.create_task(self._finish_batch(batch),
                                         name="feeder-batch")
                 self._inflight_tasks.add(t)
                 t.add_done_callback(self._inflight_tasks.discard)
             except BaseException as e:
-                for item in batch:
-                    if not item.future.done():
-                        item.future.set_exception(
-                            e if not isinstance(e, asyncio.CancelledError)
-                            else RuntimeError("feeder stopped"))
+                self._fail_batch(batch, e)
                 if isinstance(e, asyncio.CancelledError):
                     raise
+
+    @staticmethod
+    def _fail_batch(batch: list, e: BaseException) -> None:
+        err = (RuntimeError("feeder stopped")
+               if isinstance(e, asyncio.CancelledError) else e)
+        for item in batch:
+            item.resolve(err)
 
     async def _finish_batch(self, batch: list) -> None:
         """Run one batch through plan + execution and resolve every
         item future — the one owner of a batch's futures, whatever the
         route (host thread, staged device pipeline, hang fallback)."""
         try:
-            results = await self._run_batch_staged(batch)
+            with tracing.span("feeder.batch", items=len(batch),
+                              ops=",".join(sorted({it.op for it in batch}))):
+                results = await self._run_batch_staged(batch)
             for item, res in zip(batch, results):
-                if not item.future.done():
-                    if isinstance(res, BaseException):
-                        item.future.set_exception(res)
-                    else:
-                        item.future.set_result(res)
+                item.resolve(res)
         except BaseException as e:
-            for item in batch:
-                if not item.future.done():
-                    item.future.set_exception(
-                        e if not isinstance(e, asyncio.CancelledError)
-                        else RuntimeError("feeder stopped"))
+            self._fail_batch(batch, e)
             if isinstance(e, asyncio.CancelledError):
                 raise
 
@@ -1040,16 +1086,21 @@ class DeviceFeeder:
         pl = self._pipeline()
         be = self._get_backend
         busy: list[float] = []
+        n = len(blobs)
         staged = await self._stage_call(
-            pl, "h2d", lambda: be().stage(op, blobs), busy)
+            pl, "h2d", lambda: be().stage(op, blobs), busy, op, n)
         handle = await self._stage_call(
-            pl, "compute", lambda: be().compute(op, staged), busy)
+            pl, "compute", lambda: be().compute(op, staged), busy, op, n)
         out = await self._stage_call(
-            pl, "d2h", lambda: be().readback(op, handle), busy)
+            pl, "d2h", lambda: be().readback(op, handle), busy, op, n)
         return out, sum(busy)
 
     async def _stage_call(self, pl: DevicePipeline, stage: str, fn,
-                          busy: list):
+                          busy: list, op: str, items: int = 0):
+        """Run `fn` on the stage's thread and wait for it. Hand-offs 2
+        and 3 are observed here, on the loop, once per job and as far
+        as the job got: a job abandoned in the queue observes nothing,
+        one that hangs only its wait for the thread."""
         if pl.dead:
             raise _DeviceHang("pipeline aborted")
         loop = asyncio.get_running_loop()
@@ -1072,6 +1123,18 @@ class DeviceFeeder:
             raise _DeviceHang("pipeline aborted by a sibling batch hang")
         finally:
             abort.cancel()
+            now = time.perf_counter()
+            t_claim, t_done = job.t_claim, job.t_done
+            if t_claim:
+                wait = t_claim - job.t_sub
+                reg = registry()
+                reg.observe("feeder_stage_wait_seconds", wait, stage=stage)
+                if t_done and job.fut.done():
+                    reg.observe("feeder_resume_lag_seconds", now - t_done,
+                                hop=stage)
+                    # lint: ignore[GL10] emit buffers; the open+write is one amortized page-cache append per _FLUSH_EVERY spans on an already-open file
+                    tracing.record(f"dev.{stage}", t_claim, t_done, op=op,
+                                   items=items, wait_us=int(wait * 1e6))
             if not job.fut.done():
                 # abandon: a queued job is skipped outright by the
                 # stage thread (never executed), a claimed one

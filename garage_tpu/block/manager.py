@@ -36,6 +36,7 @@ from ..rpc.rpc_helper import (
 from ..utils.data import blake2sum
 from ..utils.metrics import registry
 from ..utils.error import CorruptData, MissingBlock, QuorumError, RpcError
+from ..utils.tracing import span
 from .block import BLOCK_SUFFIXES, COMPRESSION_NONE, DataBlock, comp_of_path
 from .codec import BlockCodec, ErasureCodec, ReplicateCodec, shard_nodes_of
 from .layout import DataLayout
@@ -403,8 +404,6 @@ class BlockManager:
         """`data` is the block payload: bytes, or a hostbuf.BlockLease
         on the zero-copy ingest path (erasure + no SSE; the caller owns
         the lease and releases it after this returns)."""
-        from ..utils.tracing import span
-
         lease = data if hasattr(data, "stripe") else None
         await self._ram_sem.acquire(len(data))
         try:
@@ -501,8 +500,6 @@ class BlockManager:
 
     async def _put_erasure(self, hash32: bytes, prefix: bytes,
                            data: bytes) -> None:
-        from ..utils.tracing import span
-
         async with span("block.encode", size=len(data)):
             payloads = await self.feeder.encode_put(data, prefix=prefix)
         # shard payloads stay memoryviews over the encoder's one output
@@ -570,6 +567,11 @@ class BlockManager:
         skips the qos byte charge (the FORWARDING worker charges its
         own lease for bytes it serves to its client; the owner must not
         double-charge)."""
+        async with span("block.get", hash=hash32):
+            return await self._get_block(hash32, cacheable, route, charge)
+
+    async def _get_block(self, hash32: bytes, cacheable: bool, route: bool,
+                         charge: bool) -> bytes:
         charge_fn = self.read_qos_charge if charge else None
         fill = cacheable
         tier = None
@@ -779,9 +781,10 @@ class BlockManager:
         # MiB-scale decompress+hash release the GIL: run them in a
         # worker thread so the GET readahead pipeline's prefetches
         # genuinely overlap instead of serializing on the event loop
-        if len(packed) >= 64 * 1024:
-            return await asyncio.to_thread(unpack_verify)
-        return unpack_verify()
+        async with span("block.verify", hash=hash32):
+            if len(packed) >= 64 * 1024:
+                return await asyncio.to_thread(unpack_verify)
+            return unpack_verify()
 
     async def _get_replicate(self, hash32: bytes) -> tuple[bytes, bool]:
         """-> (packed block, already_content_verified). Local reads
@@ -915,8 +918,10 @@ class BlockManager:
             if key in tried or not placement:
                 continue
             tried.add(key)
-            got = await self._gather_parts(hash32, placement,
-                                           self.codec.read_need)
+            async with span("block.gather", hash=hash32,
+                            parts=self.codec.read_need):
+                got = await self._gather_parts(hash32, placement,
+                                               self.codec.read_need)
             if got is None:
                 continue
             gathered_any = True
@@ -932,10 +937,11 @@ class BlockManager:
 
                     # MiB-scale decompress+verify off the event loop,
                     # same rule as the replicate read path
-                    if len(packed) >= 64 * 1024:
-                        plain = await asyncio.to_thread(unpack_verify)
-                    else:
-                        plain = unpack_verify()
+                    async with span("block.verify", hash=hash32):
+                        if len(packed) >= 64 * 1024:
+                            plain = await asyncio.to_thread(unpack_verify)
+                        else:
+                            plain = unpack_verify()
                     if fill_packed:
                         # the decode just proved these ARE the packed
                         # bytes behind the content address: admit them
@@ -1008,10 +1014,12 @@ class BlockManager:
         idx = tuple(sorted(parts.keys())[: codec.read_need])
         if len(parts) < codec.read_need:
             raise MissingBlock(b"")
-        if all(i < codec.k for i in idx):
-            return codec.decode(parts, packed_len)
-        return await self.feeder.decode(idx, [parts[i] for i in idx],
-                                        packed_len)
+        degraded = not all(i < codec.k for i in idx)
+        async with span("block.decode", parts=len(idx), degraded=degraded):
+            if not degraded:
+                return codec.decode(parts, packed_len)
+            return await self.feeder.decode(idx, [parts[i] for i in idx],
+                                            packed_len)
 
     async def _gather_parts(self, hash32: bytes, placement: list[bytes],
                             need: int):

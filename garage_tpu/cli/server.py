@@ -18,6 +18,7 @@ from ..admin.rpc import AdminRpcHandler
 from ..api.s3.api_server import S3ApiServer
 from ..model.garage import Garage, parse_addr
 from ..utils.config import read_config
+from ..utils.tracing import tracer
 
 log = logging.getLogger("garage_tpu.server")
 
@@ -56,6 +57,7 @@ async def _run_server_locked(cfg, cfg_path: str) -> None:
     stop = asyncio.Event()
 
     loop = asyncio.get_event_loop()
+    tracer.mark_loop_thread()  # /metrics: node_cpu_seconds{thread="loop"}
     # SIGHUP is a shutdown signal like the reference's
     # (server.rs:185-189), not a reload; absent on some platforms
     for name in ("SIGINT", "SIGTERM", "SIGHUP"):

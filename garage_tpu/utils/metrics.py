@@ -24,7 +24,7 @@ from typing import Iterator, Optional
 # the analyzer imports this regex.
 METRIC_NAME_RE = re.compile(
     r"^(api|qos|cache|chaos|rpc|block|table|resync|resize|scrub|s3|meta"
-    r"|gateway|feeder)_[a-z0-9_]+$")
+    r"|gateway|feeder|node)_[a-z0-9_]+$")
 
 # Debug-mode strictness: on under GARAGE_METRICS_STRICT=1 (the test
 # suite sets it), off in production — a bad metric name must never

@@ -14,6 +14,12 @@ topology with a dependency-free tracer:
   when `GARAGE_TPU_TRACE=<path>` (or `enable(path)`) is set, to a
   JSON-lines file — one object per span with trace/span/parent ids,
   name, start (unix us), dur_us, and attrs
+- one clock: a span's start is a `perf_counter` stamp taken at enter,
+  moved onto unix time by one (wall, perf_counter) pair read when the
+  tracer is made or enabled, so every span of a run differs from the
+  monotonic clock by one fixed offset. `record(name, t0, t1)` emits a
+  finished span from two such stamps — how work timed on another
+  thread (the feeder's stage threads) gets a span on the same clock
 - the rpc layer propagates the trace id on the wire (conn.call header)
   so one S3 request's spans correlate across nodes
 """
@@ -52,8 +58,37 @@ class Tracer:
         # extra span consumers (e.g. the OTLP exporter, utils/otlp.py);
         # each gets every finished span record and must not block
         self.sinks: list = []
+        self._anchor = (time.time_ns(), time.perf_counter_ns())
+        # the event loop thread's CPU clock, for /metrics: the scrape
+        # renders in a worker thread, where time.thread_time() would
+        # read the wrong thread
+        self._loop_clock: Optional[int] = None
+
+    def unix_us(self, t: float) -> int:
+        """A `time.perf_counter()` stamp as unix microseconds."""
+        wall_ns, perf_ns = self._anchor
+        return (wall_ns + int(t * 1e9) - perf_ns) // 1000
+
+    def mark_loop_thread(self) -> None:
+        """Call once on the event loop's thread, at loop start."""
+        get = getattr(time, "pthread_getcpuclockid", None)
+        self._loop_clock = (get(threading.get_ident())
+                            if get is not None else None)
+
+    def cpu_seconds(self) -> dict[str, float]:
+        """CPU seconds (user + system) by thread: "all" is the process,
+        "loop" the marked thread — absent, not 0, where the platform
+        has no per-thread clock or no thread was marked."""
+        out = {"all": sum(os.times()[:2])}
+        if self._loop_clock is not None:
+            try:
+                out["loop"] = time.clock_gettime(self._loop_clock)
+            except OSError:
+                pass  # the marked thread is gone
+        return out
 
     def enable(self, path: Optional[str] = None) -> None:
+        self._anchor = (time.time_ns(), time.perf_counter_ns())
         self.enabled = True
         if path:
             self._close()
@@ -128,6 +163,44 @@ def set_remote_context(wire: Optional[str]) -> None:
         _ctx.set((wire, "remote"))
 
 
+def detach() -> None:
+    """Drop the inherited trace context of the current task: a
+    long-lived task (the feeder's dispatcher) is created inside
+    whichever request came first and must not parent its spans, and
+    those of the tasks it creates, to that request for ever."""
+    _ctx.set(None)
+
+
+def _emit(ids: tuple, name: str, t0: float, t1: float, attrs: dict,
+          exc_type=None) -> None:
+    trace_id, span_id, parent_id = ids
+    rec = {
+        "trace": trace_id,
+        "span": span_id,
+        "parent": parent_id,
+        "name": name,
+        "start_us": tracer.unix_us(t0),
+        "dur_us": int((t1 - t0) * 1e6),
+    }
+    if attrs:
+        rec["attrs"] = {k: (v.hex()[:16] if isinstance(v, bytes) else v)
+                        for k, v in attrs.items()}
+    if exc_type is not None:
+        rec["error"] = exc_type.__name__
+    tracer.emit(rec)
+
+
+def record(name: str, t0: float, t1: float, **attrs) -> None:
+    """Emit a finished span from two `time.perf_counter()` stamps, as a
+    child of the calling context's span (a trace of its own without
+    one). For work that was timed where no context lives — a stage
+    thread stamps, the coroutine that waited for it records."""
+    if not tracer.enabled:
+        return
+    trace_id, parent_id = _ctx.get() or (secrets.token_hex(8), None)
+    _emit((trace_id, secrets.token_hex(4), parent_id), name, t0, t1, attrs)
+
+
 class span:
     """with span("table.insert", table=name): ...  (sync or async)."""
 
@@ -156,22 +229,8 @@ class span:
     def _exit(self, exc_type):
         if self.token is None:
             return False
-        dur_us = int((time.perf_counter() - self.t0) * 1e6)
-        trace_id, span_id, parent_id = self.ids
-        rec = {
-            "trace": trace_id,
-            "span": span_id,
-            "parent": parent_id,
-            "name": self.name,
-            "start_us": int(time.time() * 1e6) - dur_us,
-            "dur_us": dur_us,
-        }
-        if self.attrs:
-            rec["attrs"] = {k: (v.hex()[:16] if isinstance(v, bytes) else v)
-                            for k, v in self.attrs.items()}
-        if exc_type is not None:
-            rec["error"] = exc_type.__name__
-        tracer.emit(rec)
+        _emit(self.ids, self.name, self.t0, time.perf_counter(), self.attrs,
+              exc_type)
         _ctx.reset(self.token)
         self.token = None
         return False
