@@ -11,9 +11,12 @@ Layout of the JAX path: messages are padded to a static chunk count C;
 byte *lengths* stay traced, so one compiled program serves every block
 whose size lands in the same chunk count (tail blocks don't recompile).
 Batching is lane-major (batch = trailing vector axis, see the section
-comment above _compress_lanes): all C*B chunks are lanes of one 16-step
-lax.scan over block positions, and each scan step runs the 7 rounds as
-an inner scan.
+comment above _compress_lanes): all B*C chunks are lanes of one 16-step
+lax.scan over block positions, the parent tree is a second scan with
+one step a level over one array of chaining values, and each step of
+either runs the 7 rounds as an inner scan. The device program is two
+copies of the compression function plus the message-word load, ~500
+StableHLO ops for any B and C.
 
 The pure-Python implementation is the test oracle (checked against the
 published empty-input vector) and the host fallback for small inputs.
@@ -137,69 +140,93 @@ def blake3_py(data: bytes) -> bytes:
 # ---------------------------------------------------------------------------
 #
 # Batch layout: every independent hash unit (chunk of a row, then parent
-# node of a tree level) is a *lane* — the trailing axis of every array.
-# State is (8, L), messages (16, L); the compression function is ~450
-# elementwise u32 ops on (L,) vectors regardless of batch size, so the
-# HLO graph is batch-size independent (a vmap formulation made XLA:CPU
-# compile time explode superlinearly in B) and maps straight onto the
-# TPU VPU's 128-wide lanes.
+# node of a tree level) is a *lane* — the trailing axis of every array,
+# lane = row * C + chunk. State is (8, L), messages (16, L). The program
+# holds two copies of the compression function, the chunk scan's and the
+# tree scan's, whatever B and C are: its size is independent of the
+# batch (a vmap formulation made XLA:CPU compile time explode
+# superlinearly in B) and grows with log2(C) by two scalars a level. The
+# size is what a launch, a trace, a build and a load from the compile
+# cache all pay for, so tests/test_treehash.py holds it.
+
+
+@functools.lru_cache(maxsize=None)
+def _round_words() -> np.ndarray:
+    """(7, 4, 4) message-word indices: for each round the x words and
+    the y words of the four column G's, then of the four diagonal G's."""
+    s = np.array(_schedules(), dtype=np.int32)
+    return np.stack([s[:, 0:8:2], s[:, 1:8:2], s[:, 8:16:2], s[:, 9:16:2]],
+                    axis=1)
 
 
 def _compress_lanes(h, m, counter, block_len, flags):
     """h (8, L), m (16, L), counter/block_len/flags (L,) or scalar u32
     -> (8, L). All ops lane-vectorized.
 
-    The 7 rounds run as a lax.scan whose body gathers that round's
-    message schedule — keeping the HLO body near 70 ops. A fully
-    unrolled formulation (~450 interdependent u32 ops) sends XLA:CPU's
-    backend into multi-minute compiles for any lane count >= 4; the
-    scan form compiles in seconds everywhere and XLA still unrolls or
-    pipelines it on TPU as it sees fit.
+    The state is its four row groups a, b, c, d = v[0:4], v[4:8],
+    v[8:12], v[12:16], each (4, L). A round is one G on the four
+    columns at once, a roll of b, c, d by one, two, three rows, one G on
+    the four diagonals, and the roll back. The message is permuted once,
+    outside the rounds, and each round takes its sixteen words as the
+    scan's xs.
+
+    The 7 rounds run as a lax.scan: unrolled, the ~450 interdependent
+    u32 ops send XLA:CPU's backend into multi-minute compiles for any
+    lane count >= 4; the scan form compiles in seconds everywhere.
     """
     import jax
     import jax.numpy as jnp
 
     u32 = jnp.uint32
-    ones = jnp.ones_like(h[0])
-    sched = jnp.asarray(np.array(_schedules(), dtype=np.int32))  # (7, 16)
-    v0 = jnp.stack(
-        [h[i] for i in range(8)]
-        + [
-            u32(IV[0]) * ones, u32(IV[1]) * ones, u32(IV[2]) * ones, u32(IV[3]) * ones,
-            counter * ones, jnp.zeros_like(ones),
-            block_len * ones, flags * ones,
-        ]
-    )  # (16, L)
+    lanes = h.shape[1]
+
+    def row(x):
+        return jnp.broadcast_to(jnp.asarray(x, u32), (lanes,))
+
+    iv = jnp.broadcast_to(jnp.asarray(IV[:4], u32)[:, None], (4, lanes))
+    tail = jnp.stack([row(counter), row(0), row(block_len), row(flags)])
+    words = m[_round_words()]  # (7, 4, 4, L)
 
     def rotr(x, n):
         return (x >> u32(n)) | (x << u32(32 - n))
 
-    def round_body(vs, idx):
-        mr = jnp.take(m, idx, axis=0)  # (16, L) permuted message
-        v = [vs[i] for i in range(16)]
+    def g(a, b, c, d, mx, my):
+        a = a + b + mx
+        d = rotr(d ^ a, 16)
+        c = c + d
+        b = rotr(b ^ c, 12)
+        a = a + b + my
+        d = rotr(d ^ a, 8)
+        c = c + d
+        b = rotr(b ^ c, 7)
+        return a, b, c, d
 
-        def g(a, b, c, d, mx, my):
-            v[a] = v[a] + v[b] + mx
-            v[d] = rotr(v[d] ^ v[a], 16)
-            v[c] = v[c] + v[d]
-            v[b] = rotr(v[b] ^ v[c], 12)
-            v[a] = v[a] + v[b] + my
-            v[d] = rotr(v[d] ^ v[a], 8)
-            v[c] = v[c] + v[d]
-            v[b] = rotr(v[b] ^ v[c], 7)
+    def round_body(v, mr):
+        a, b, c, d = g(*v, mr[0], mr[1])
+        b, c, d = jnp.roll(b, -1, 0), jnp.roll(c, -2, 0), jnp.roll(d, -3, 0)
+        a, b, c, d = g(a, b, c, d, mr[2], mr[3])
+        return (a, jnp.roll(b, 1, 0), jnp.roll(c, 2, 0), jnp.roll(d, 3, 0)), None
 
-        g(0, 4, 8, 12, mr[0], mr[1])
-        g(1, 5, 9, 13, mr[2], mr[3])
-        g(2, 6, 10, 14, mr[4], mr[5])
-        g(3, 7, 11, 15, mr[6], mr[7])
-        g(0, 5, 10, 15, mr[8], mr[9])
-        g(1, 6, 11, 12, mr[10], mr[11])
-        g(2, 7, 8, 13, mr[12], mr[13])
-        g(3, 4, 9, 14, mr[14], mr[15])
-        return jnp.stack(v), None
+    (a, b, c, d), _ = jax.lax.scan(round_body, (h[0:4], h[4:8], iv, tail), words)
+    return jnp.concatenate([a ^ c, b ^ d])
 
-    v, _ = jax.lax.scan(round_body, v0, sched)
-    return v[:8] ^ v[8:16]
+
+def _message_words(msgs):
+    """(B, C*1024) u8 -> (16, 16, B*C) u32: block position, word, lane.
+
+    One chunk a row, transposed so that chunks are lanes and the four
+    bytes of a word lie four rows apart in one lane. The shape that
+    comes to mind first, reshape(b, c, 16, 16, 4) of the u8 array and a
+    five-axis transpose, has a minor dimension of 4: on the v5e it took
+    the compiler 43-53 s of a 58 s build and was 3 to 10 ms of every
+    launch (PERF.md, PR 26).
+    """
+    import jax.numpy as jnp
+
+    u32 = jnp.uint32
+    x = msgs.reshape(-1, CHUNK_LEN).T.astype(u32).reshape(CHUNK_LEN // 4, 4, -1)
+    words = x[:, 0] | (x[:, 1] << 8) | (x[:, 2] << 16) | (x[:, 3] << 24)
+    return words.reshape(BLOCKS_PER_CHUNK, 16, -1)
 
 
 def hash_rows(msgs, lengths, n_chunks: int):
@@ -211,10 +238,13 @@ def hash_rows(msgs, lengths, n_chunks: int):
     is silently wrong (phantom all-zero chunks enter the tree).
 
     Composable inside larger jitted programs (parallel/ data-plane steps);
-    _hash_fn below is the standalone jitted wrapper. All C*B chunks hash
-    as lanes of one 16-step lax.scan over block positions; the parent
-    tree is a static log2(C) unroll, each level one lane-vectorized
-    compression over all pairs of all rows.
+    _hash_fn below is the standalone jitted wrapper. All B*C chunks hash
+    as lanes of one 16-step lax.scan over block positions. The parent
+    tree is a second scan, one step a level, over one (8, B, 2*W) array
+    of chaining values, W = ceil(C/2): a step merges the array's W pairs
+    in the lanes of one compression, keeps the parents of the pairs the
+    level really has and carries the odd node, so every level has the
+    shape of the first and the finished lanes are masked, not sliced off.
     """
     import jax
     import jax.numpy as jnp
@@ -222,30 +252,24 @@ def hash_rows(msgs, lengths, n_chunks: int):
     u32 = jnp.uint32
     b = msgs.shape[0]
     c = n_chunks
-    w = msgs.reshape(b, c, BLOCKS_PER_CHUNK, BLOCK_LEN // 4, 4).astype(u32)
-    words = w[..., 0] | (w[..., 1] << 8) | (w[..., 2] << 16) | (w[..., 3] << 24)
-    # (B, C, block, word) -> (block, word, C*B) lane = chunk-major
-    words = words.transpose(2, 3, 1, 0).reshape(BLOCKS_PER_CHUNK, 16, c * b)
+    n = b * c
+    words = _message_words(msgs)
 
-    counters = jnp.repeat(jnp.arange(c, dtype=u32), b)  # (C*B,)
+    chunk = jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (b, c))
+    counters = chunk.astype(u32).reshape(n)
     chunk_lens = jnp.clip(
-        lengths[None, :] - jnp.arange(c, dtype=jnp.int32)[:, None] * CHUNK_LEN,
-        0,
-        CHUNK_LEN,
-    ).astype(u32).reshape(c * b)
-    n_blocks = jnp.maximum(u32(1), (chunk_lens + u32(BLOCK_LEN - 1)) // u32(BLOCK_LEN))
+        lengths[:, None] - chunk * CHUNK_LEN, 0, CHUNK_LEN
+    ).reshape(n)
+    n_blocks = jnp.maximum(1, (chunk_lens + (BLOCK_LEN - 1)) // BLOCK_LEN)
 
-    pos = jnp.arange(BLOCKS_PER_CHUNK, dtype=u32)[:, None]  # (block, 1)
+    pos = jnp.arange(BLOCKS_PER_CHUNK, dtype=jnp.int32)[:, None]  # (block, 1)
     block_lens = jnp.clip(
-        chunk_lens[None, :].astype(jnp.int32) - (pos * BLOCK_LEN).astype(jnp.int32),
-        0,
-        BLOCK_LEN,
-    ).astype(u32)  # (block, C*B)
+        chunk_lens[None, :] - pos * BLOCK_LEN, 0, BLOCK_LEN
+    ).astype(u32)  # (block, B*C)
     is_end = pos == (n_blocks - 1)[None, :]
-    root_if_single = u32(ROOT if c == 1 else 0)
     flags = (
         jnp.where(pos == 0, u32(CHUNK_START), u32(0))
-        | jnp.where(is_end, u32(CHUNK_END) | root_if_single, u32(0))
+        | jnp.where(is_end, u32(CHUNK_END | (ROOT if c == 1 else 0)), u32(0))
     )
     active = pos < n_blocks[None, :]
 
@@ -254,33 +278,44 @@ def hash_rows(msgs, lengths, n_chunks: int):
         new_cv = _compress_lanes(cv, m, counters, blen, flg)
         return jnp.where(act, new_cv, cv), None
 
-    init = jnp.tile(jnp.array(IV, dtype=u32)[:, None], (1, c * b))
-    cv, _ = jax.lax.scan(step, init, (words, block_lens, flags, active))  # (8, C*B)
+    iv = jnp.asarray(IV, dtype=u32)[:, None]
+    cv, _ = jax.lax.scan(
+        step, jnp.broadcast_to(iv, (8, n)), (words, block_lens, flags, active)
+    )  # (8, B*C)
 
     if c == 1:
         return cv.T  # (B, 8)
 
-    # Parent tree: pairwise merge with odd tail carried, all rows' pairs
-    # in lanes of one compression per level.
-    level = [cv.reshape(8, c, b)[:, i, :] for i in range(c)]  # C x (8, B)
-    zero = u32(0)
+    # Parent tree: pairwise merge with the odd tail carried, as in
+    # blake3_py. Level sizes c, ceil(c/2), ... , 2; the merge of the
+    # last two is the root.
+    sizes = [c]
+    while sizes[-1] > 2:
+        sizes.append((sizes[-1] + 1) // 2)
+    w = (c + 1) // 2
+    level = cv.reshape(8, b, c)
+    if c % 2:
+        level = jnp.concatenate([level, jnp.zeros((8, b, 1), u32)], axis=2)
+    pair = jnp.arange(w, dtype=jnp.int32)
+    spare = jnp.zeros((8, b, w), u32)
+    iv_w = jnp.broadcast_to(iv, (8, b * w))
 
-    def merge(pairs_l, pairs_r, flags_val):
-        ln = len(pairs_l)
-        left = jnp.concatenate(pairs_l, axis=-1)  # (8, ln*B)
-        right = jnp.concatenate(pairs_r, axis=-1)
-        m = jnp.concatenate([left, right], axis=0)  # (16, ln*B)
-        iv = jnp.tile(jnp.array(IV, dtype=u32)[:, None], (1, ln * b))
-        out = _compress_lanes(iv, m, zero, u32(BLOCK_LEN), u32(flags_val))
-        return [out[:, i * b : (i + 1) * b] for i in range(ln)]
+    def merge(level, xs):
+        size, flg = xs
+        left = level[:, :, 0::2]
+        m = jnp.concatenate([left, level[:, :, 1::2]]).reshape(16, b * w)
+        parents = _compress_lanes(iv_w, m, 0, BLOCK_LEN, flg).reshape(8, b, w)
+        # pairs past size // 2 do not exist at this level: their lanes
+        # keep `left`, which at size // 2 is the odd node carried up
+        nxt = jnp.where(pair < size // 2, parents, left)
+        return jnp.concatenate([nxt, spare], axis=2), None
 
-    while len(level) > 2:
-        nxt = merge(level[0:-1:2], level[1::2], PARENT)
-        if len(level) % 2:
-            nxt.append(level[-1])
-        level = nxt
-    (root,) = merge([level[0]], [level[1]], PARENT | ROOT)
-    return root.T  # (B, 8)
+    level, _ = jax.lax.scan(
+        merge, level,
+        (jnp.asarray(sizes, jnp.int32),
+         jnp.asarray([PARENT | (ROOT if s == 2 else 0) for s in sizes], u32)),
+    )
+    return level[:, :, 0].T  # (B, 8)
 
 
 @functools.lru_cache(maxsize=None)

@@ -63,6 +63,21 @@ def test_put_step_sharded_matches_host(k, m, dp, tp):
     np.testing.assert_array_equal(np.asarray(hashes), ref_hashes)
 
 
+@pytest.mark.parametrize("shard_len", [1024, 3 * 1024, 5 * 1024])
+def test_put_step_digests_at_odd_chunk_counts(shard_len):
+    """hash_rows composed inside the sharded step where its tree has no
+    level (one chunk), one carried node (three) and a node carried over
+    two levels (five): the digests are the reference's."""
+    k, m = 4, 2
+    mesh = _mesh(4, 2)
+    rng = np.random.default_rng(shard_len)
+    data = rng.integers(0, 256, size=(8, k, shard_len), dtype=np.uint8)
+    parity, hashes = make_put_step(mesh, k, m, shard_len)(data)
+    ref_parity, _, ref_hashes = _host_reference(data, k, m)
+    np.testing.assert_array_equal(np.asarray(parity), ref_parity)
+    np.testing.assert_array_equal(np.asarray(hashes), ref_hashes)
+
+
 @pytest.mark.parametrize("dp,tp", [(4, 2), (2, 4)])
 @pytest.mark.parametrize("k,m", SHAPES)
 def test_scrub_step_detects_injected_corruption(k, m, dp, tp):

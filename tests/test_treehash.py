@@ -66,3 +66,61 @@ class TestJaxMatchesReference:
         msgs = np.zeros((1, 2048), dtype=np.uint8)
         with pytest.raises(ValueError):
             treehash.hash_batch_jax(msgs, np.array([0]))
+
+
+def _ragged_batch(c: int, b: int):
+    """(b, c*1024) zero-padded rows whose lengths all span exactly c
+    chunks: row 0 full, the others cut anywhere inside the last chunk
+    (inside the only chunk, down to empty, when c == 1)."""
+    rng = np.random.default_rng(c * 31 + b)
+    lo = (c - 1) * treehash.CHUNK_LEN + 1 if c > 1 else 0
+    lengths = rng.integers(lo, c * treehash.CHUNK_LEN + 1, size=b)
+    lengths[0] = c * treehash.CHUNK_LEN
+    buf = np.zeros((b, c * treehash.CHUNK_LEN), dtype=np.uint8)
+    for i, n in enumerate(lengths):
+        buf[i, :n] = rng.integers(0, 256, size=n, dtype=np.uint8)
+    return buf, lengths.astype(np.int32)
+
+
+class TestDeviceProgram:
+    """The jitted program itself (hash_fn), every tree shape: one chunk
+    (no tree), powers of two, odd tails carried at one level or several
+    (3, 5, 13, 1023), and the 1 MiB block the servers launch."""
+
+    @pytest.mark.parametrize("b", [1, 3])
+    @pytest.mark.parametrize("c", [1, 2, 3, 5, 8, 13, 64, 1023, 1024])
+    def test_digests_equal_reference(self, c, b):
+        buf, lengths = _ragged_batch(c, b)
+        cvs = np.asarray(treehash.hash_fn(c)(buf, lengths)).astype("<u4")
+        got = [np.ascontiguousarray(cvs[i]).tobytes() for i in range(b)]
+        # the pure-Python oracle takes ~2 s a MiB: at the two large
+        # chunk counts only row 0 goes through it, the other rows
+        # through the host's digest (native C where it built, else the
+        # same oracle)
+        from garage_tpu.utils.data import blake3sum
+
+        for i, n in enumerate(lengths):
+            ref = treehash.blake3_py if (c <= 64 or i == 0) else blake3sum
+            assert got[i] == ref(buf[i, :n].tobytes()), f"c={c} b={b} row={i}"
+
+    @staticmethod
+    def _lowered_ops(c: int, b: int) -> int:
+        import jax
+
+        text = treehash.hash_fn(c).lower(
+            jax.ShapeDtypeStruct((b, c * treehash.CHUNK_LEN), np.uint8),
+            jax.ShapeDtypeStruct((b,), np.int32)).as_text()
+        return text.count("stablehlo.")
+
+    def test_program_size_guard(self):
+        """The program's size is an invariant (DEVICE_PATH.md "The
+        BLAKE3 program"): independent of the batch, and of the chunk
+        count but for two scalars a level. Every trace, build, load and
+        launch pays for each op: a tree unrolled over per-chunk slices
+        was 9,729 of them at C = 1024 (484 when this was written)."""
+        at_1024 = self._lowered_ops(1024, 1)
+        assert at_1024 <= 1000
+        assert self._lowered_ops(1024, 8) == at_1024
+        # the two levels between C = 256 and C = 1024 are two more
+        # steps of the tree's scan, not two more copies of its body
+        assert abs(at_1024 - self._lowered_ops(256, 1)) <= 8
