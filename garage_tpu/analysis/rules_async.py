@@ -177,7 +177,7 @@ class SwallowedException(Rule):
 
 # ---- GL06 --------------------------------------------------------------
 
-RPC_METHODS = {"try_call_many", "try_write_many_sets",
+RPC_METHODS = {"try_call_many", "try_write_many_sets", "_quorum_write",
                "rpc_get_block", "rpc_put_block"}
 RPC_RECEIVERS = {"rpc", "ep", "endpoint", "rpc_helper"}
 GL06_DIRS = re.compile(r"(^|/)(table|block)/")

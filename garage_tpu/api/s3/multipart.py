@@ -368,11 +368,11 @@ async def handle_complete_multipart(ctx, req: Request) -> Response:
                                                  ctx.key.encode())
     await check_quotas(ctx.garage, ctx.bucket_id, total_size, existing)
     await ctx.garage.version_table.insert(final)
-    # re-point block refs from part versions to the final version
-    for pn, part in parts:
-        pv = await ctx.garage.version_table.get(part.version, b"")
-        for _k, (h, _s) in pv.blocks.items():
-            await ctx.garage.block_ref_table.insert(BlockRef.new(h, ov.uuid))
+    # re-point block refs from part versions to the final version: one
+    # batched quorum write (one RPC a node), not one insert a block in
+    # series (ref: multipart.rs block_ref_table.insert_many)
+    await ctx.garage.block_ref_table.insert_many(
+        [BlockRef.new(h, ov.uuid) for _k, (h, _s) in final.blocks.items()])
 
     etag = f"{etag_md5.hexdigest()}-{len(parts)}"
     headers = (ov.state.headers if ov.state.kind == "uploading" else {})

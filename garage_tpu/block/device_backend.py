@@ -228,6 +228,7 @@ class JaxDeviceBackend:
         self.stats = stats if stats is not None else {
             "pad_waste_bytes": 0, "recompiles": 0, "mesh_batches": 0}
         self._shapes_seen: set = set()
+        self._zero_stripes: dict = {}  # (k, slen) -> device zeros
         self._mesh = None
 
     # ---- shape accounting ------------------------------------------------
@@ -365,12 +366,19 @@ class JaxDeviceBackend:
             # pad to (bpad, k, smax) happens on-device; batch=None
             # tells readback to slice the data shards straight from
             # the leases (host memory) instead of a staging array.
+            # Missing items are zero stripes stacked in, so the stack
+            # and the pad are one program an item BUCKET, as the
+            # kernels are, and not one an item count.
             import jax.numpy as jnp
 
-            dev = jnp.stack([jax.device_put(b.stripe()) for b in blocks])
-            if bpad > len(blocks) or smax > slens[0]:
-                dev = jnp.pad(dev, ((0, bpad - len(blocks)), (0, 0),
-                                    (0, smax - slens[0])))
+            devs = [jax.device_put(b.stripe()) for b in blocks]
+            zero = self._zero_stripes.get(devs[0].shape)
+            if zero is None:
+                zero = self._zero_stripes[devs[0].shape] = \
+                    jnp.zeros_like(devs[0])
+            dev = jnp.stack(devs + [zero] * (bpad - len(blocks)))
+            if smax > slens[0]:
+                dev = jnp.pad(dev, ((0, 0), (0, 0), (0, smax - slens[0])))
             return (blocks, slens, None, dev, None, smax)
         batch = np.zeros((bpad, k, smax), dtype=np.uint8)
         copied = 0

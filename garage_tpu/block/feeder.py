@@ -61,7 +61,7 @@ from ..utils import tracing
 from ..utils.metrics import registry
 from .device_backend import (DEFAULT_PAD_BUCKETS, STAGES, DevicePipeline,
                              JaxDeviceBackend, StubDeviceBackend,
-                             group_bytes)
+                             bucket_items, group_bytes)
 
 log = logging.getLogger("garage_tpu.block.feeder")
 
@@ -395,6 +395,42 @@ class DeviceFeeder:
                               f"({type(e).__name__}: {e})")
         else:
             self._judge(info)
+
+    async def warm_put_programs(self, block_len: int, lease=None,
+                                max_items: int = 1) -> None:
+        """Launch once, for every item bucket up to `max_items`, what a
+        PUT of full `block_len`-byte blocks launches: the content hash
+        and, given a full ingest `lease`, the all-lease RS encode leg.
+        A node that serves from its device then meets no program for
+        the first time inside a request: they are built (or loaded from
+        the compile cache) here, at boot, on the stage threads and
+        under the batch watchdog like any leg. Results are thrown away
+        and no item is counted; a failure is the caller's to raise."""
+        await self.device_verdict()
+        if not self._device_ok or self._backend_is_stub():
+            return
+        from ..ops import jaxenv
+        from ..utils import data as _data
+
+        t0, before = time.perf_counter(), jaxenv.compile_stats()
+        zero = bytes(block_len)
+        n = 1  # the fewest items that launch the next bucket
+        while n <= max_items:
+            legs = []
+            if _data._content_algo == "blake3":  # blake2 never leaves the host
+                legs.append(("hash", [zero] * n))
+            if lease is not None and self.codec is not None:
+                legs.append(("encode_put", [lease] * n))
+            for op, blobs in legs:
+                await asyncio.wait_for(self._staged_op(op, blobs),
+                                       self.batch_timeout)
+            n = bucket_items(n, self.pad_buckets) + 1
+        after = jaxenv.compile_stats()
+        log.info("feeder: PUT programs of %d-byte blocks warm to %d items "
+                 "in %.1f s (%d requested, %d built)", block_len, max_items,
+                 time.perf_counter() - t0,
+                 after["compile_requests"] - before["compile_requests"],
+                 after["compiles"] - before["compiles"])
 
     def _maybe_start_verdict(self) -> None:
         """auto: ask for the device in the background at the first

@@ -143,15 +143,19 @@ class HostBufPool:
         """FIFO backpressure: when the pool is dry, park until a
         release hands this waiter a buffer directly (never allocates —
         a PUT burst queues instead of growing RAM)."""
+        reg = registry()
         lease = self.try_acquire()
         if lease is not None:
+            # observed on every acquisition, so the mean is per block
+            reg.observe("s3_ingest_wait_seconds", 0.0)
             return lease
         import asyncio
 
         fut = asyncio.get_running_loop().create_future()
         self._waiters.append(fut)
-        registry().inc("s3_ingest_buf_wait")
-        return await fut
+        reg.inc("s3_ingest_buf_wait")
+        with reg.timer("s3_ingest_wait_seconds"):
+            return await fut
 
     def release(self, lease: BlockLease) -> None:
         if lease.released:

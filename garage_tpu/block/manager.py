@@ -398,6 +398,23 @@ class BlockManager:
             self._ingest_pool = pool
         return pool
 
+    async def warm_device(self, block_size: int, ingest_buffers: int
+                          ) -> None:
+        """Boot of a node that must serve from its device: build or
+        load, before the first request, every program a PUT of full
+        blocks launches (feeder.warm_put_programs), up to the largest
+        batch the ingest pool's leases allow."""
+        pool = self.ingest_pool(block_size, ingest_buffers)
+        lease = pool.try_acquire() if pool is not None else None
+        try:
+            if lease is not None:
+                lease.length = lease.cap  # a full block of zeros
+            await self.feeder.warm_put_programs(
+                block_size, lease, max(1, ingest_buffers))
+        finally:
+            if lease is not None:
+                lease.release()
+
     async def rpc_put_block(self, hash32: bytes, data: bytes,
                             compress: Optional[bool] = None,
                             cacheable: bool = True) -> None:
@@ -488,15 +505,28 @@ class BlockManager:
         helper = self.system.layout_helper
         with helper.write_lock():
             sets = helper.write_sets_of(hash32)
-            # lint: ignore[GL06] write_lock is a layout-version PIN (refcount), not mutual exclusion; holding it across the quorum write IS the union-window contract (manager.rs:344)
-            await self.rpc.try_write_many_sets(
-                self.endpoint, sets,
-                {"op": "put", "hash": hash32, "part": None, "comp": comp,
-                 "data": payload},
-                RequestStrategy(quorum=self.codec.write_quorum,
-                                prio=PRIO_NORMAL,
-                                timeout=60.0),
-            )
+            async with span("block.write_shards", width=self.codec.width,
+                            quorum=self.codec.write_quorum):
+                # lint: ignore[GL06] write_lock is a layout-version PIN (refcount), not mutual exclusion; holding it across the quorum write IS the union-window contract (manager.rs:344)
+                await self._quorum_write(
+                    "replicate", sets,
+                    {"op": "put", "hash": hash32, "part": None,
+                     "comp": comp, "data": payload},
+                    RequestStrategy(quorum=self.codec.write_quorum,
+                                    prio=PRIO_NORMAL,
+                                    timeout=60.0),
+                )
+
+    async def _quorum_write(self, mode: str, sets, payload,
+                            strategy: RequestStrategy, **kw) -> None:
+        """One block's quorum write, timed from entering
+        try_write_many_sets to the quorum reached (the stragglers write
+        on behind it). A refused write observes nothing."""
+        t0 = time.perf_counter()
+        await self.rpc.try_write_many_sets(
+            self.endpoint, sets, payload, strategy, **kw)
+        registry().observe("block_write_seconds",
+                           time.perf_counter() - t0, mode=mode)
 
     async def _put_erasure(self, hash32: bytes, prefix: bytes,
                            data: bytes) -> None:
@@ -524,7 +554,8 @@ class BlockManager:
             # quorum unit = placement entry (node, shard index): a node
             # may be assigned different shard indices under different
             # layout versions, so keys are tuples, not bare node ids
-            async with span("block.write_shards", width=self.codec.width):
+            async with span("block.write_shards", width=self.codec.width,
+                            quorum=self.codec.write_quorum):
                 await self._write_shard_sets(hash32, payloads, sets)
 
     async def _write_shard_sets(self, hash32, payloads, sets) -> None:
@@ -534,8 +565,8 @@ class BlockManager:
         # during a resize. Shard puts are keyed by content hash + shard
         # index, so a re-issued backup push landing twice writes the
         # same bytes to the same path: idempotent, first ack wins.
-        await self.rpc.try_write_many_sets(
-            self.endpoint, sets, None,
+        await self._quorum_write(
+            "erasure", sets, None,
             RequestStrategy(quorum=self.codec.write_quorum,
                             prio=PRIO_NORMAL, timeout=60.0,
                             hedge=True),  # lint: ignore[GL02] shard puts are content-addressed and idempotent; a duplicate backup push re-writes identical bytes

@@ -13,7 +13,11 @@ Quorum arithmetic:
   erasure(k, m): a block read needs any k of n=k+m shards; a write is
   durable against the same failures as replicate-(m+1) once k+m shards
   land, but is *decodable* after any k — write quorum k+q_extra, where
-  q_extra = ceil((m+1)/2) keeps read-your-writes through m failures.
+  q_extra = (m+1)//2. At acknowledgement a block is decodable through
+  the loss of any q_extra of the acknowledged shards (quorum - k: 1 of
+  5 for (4,2), 2 of 12 for (10,4)); through m losses only once all k+m
+  shards have landed, which the stragglers of the quorum write do
+  behind the acknowledgement.
 """
 
 from __future__ import annotations

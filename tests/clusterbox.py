@@ -66,7 +66,8 @@ class ClusterBox:
                  ping_interval: float = 0.3,
                  resync_retry_delay: float = 0.25,
                  zones: Optional[list[str]] = None,
-                 zone_redundancy=None):
+                 zone_redundancy=None,
+                 block_size: int = 1 << 20):
         self.tmp = str(tmp_path)
         self.n = n
         self.rf = rf
@@ -81,6 +82,7 @@ class ClusterBox:
             raise ValueError(f"zones has {len(zones)} entries for {n} nodes")
         self.zones = zones if zones is not None else ["z1"] * n
         self.zone_redundancy = zone_redundancy
+        self.block_size = block_size  # 1 MiB is Config's own default
         self.db_engine = db_engine
         self.governor = governor
         self.status_interval = status_interval
@@ -104,6 +106,7 @@ class ClusterBox:
                           # resize experiments: let resync sprint when
                           # foreground is quiet, yield hard when not
                           resync_tranquility_max=0.5),
+            block_size=self.block_size,
         )
 
     def _boot(self, node: BoxNode) -> None:
