@@ -1167,6 +1167,27 @@ class AdminHttpServer:
               "CPU seconds (user + system) by thread", thread="all")
         if "loop" in cpu:
             gauge("node_cpu_seconds", round(cpu["loop"], 6), thread="loop")
+        # ... and what the loop's thread spent them on, while the
+        # sampling profile runs (utils/loopprof.py): absent otherwise
+        from ..utils.loopprof import profiler
+
+        prof = profiler.snapshot()
+        if prof is not None:
+            gauge("loop_profile_samples", prof["samples"],
+                  "Samples of the loop thread's stack taken so far")
+            for family in ("root", "leaf"):
+                name = f"loop_profile_{family}_seconds"
+                out.append(f"# HELP {name} Seconds of the loop's thread by "
+                           f"{family} of the sampled stack; blocked = wall "
+                           f"- cpu")
+                out.append(f"# TYPE {name} gauge")
+                for label, (cpu_s, wall_s) in prof[family].items():
+                    clocks = [("cpu", cpu_s), ("wall", wall_s)]
+                    if label != "idle":
+                        clocks.append(("blocked", wall_s - cpu_s))
+                    for clock, v in clocks:
+                        gauge(name, round(v, 6), clock=clock,
+                              **{family: label})
 
         for wid, info in g.runner.worker_info().items():
             gauge("worker_busy", 1 if info.state == "busy" else 0,

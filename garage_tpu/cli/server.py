@@ -18,6 +18,7 @@ from ..admin.rpc import AdminRpcHandler
 from ..api.s3.api_server import S3ApiServer
 from ..model.garage import Garage, parse_addr
 from ..utils.config import read_config
+from ..utils.loopprof import profiler
 from ..utils.tracing import tracer
 
 log = logging.getLogger("garage_tpu.server")
@@ -39,6 +40,7 @@ async def run_server(cfg_path: str) -> None:
         # released on EVERY exit (GL11): a failed Garage boot or
         # frontend bind must not leave the lock held when the caller
         # (tests, repair-offline in the same process) survives us
+        profiler.stop()  # while the loop still runs; off unless started
         lockfile.release(lock_fd)
 
 
@@ -63,6 +65,9 @@ async def _run_server_locked(cfg, cfg_path: str) -> None:
 
     loop = asyncio.get_event_loop()
     tracer.mark_loop_thread()  # /metrics: node_cpu_seconds{thread="loop"}
+    # under GARAGE_TPU_TRACE on the main thread alone: what that thread
+    # spends its CPU on (/metrics: loop_profile_*_seconds)
+    profiler.start(loop)
     # SIGHUP is a shutdown signal like the reference's
     # (server.rs:185-189), not a reload; absent on some platforms
     for name in ("SIGINT", "SIGTERM", "SIGHUP"):
