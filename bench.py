@@ -8,8 +8,8 @@ GB/s/chip; RS encode MB/s; scrub blocks/s"):
   put_gbps             block throughput measured THROUGH
                        BlockManager.rpc_put_block on an in-process
                        6-node erasure(4,2) loopback cluster (quorum-
-                       acked writes; host/native or device per feeder
-                       calibration)
+                       acked writes; host/native or device per the
+                       feeder's mode and device verdict)
   device_put_gbps      same path with DeviceFeeder(mode="require"):
                        every encode batch forced onto the accelerator —
                        proves the device data path end to end
@@ -269,7 +269,7 @@ async def _teardown(systems, managers, tasks) -> None:
 
 
 async def _settle_feeder(feeder, timeout: float = 150.0) -> None:
-    """Wait for the one-time device verdict + calibration to finish so
+    """Wait for the one-time device verdict to land so
     the timed window measures steady state, not jax-import/XLA-compile
     startup cost (a server pays that once at boot, off the request
     path). No-op when the feeder is pinned host/device."""
@@ -277,7 +277,7 @@ async def _settle_feeder(feeder, timeout: float = 150.0) -> None:
         return
     deadline = time.perf_counter() + timeout
     while time.perf_counter() < deadline:
-        if feeder._device_ok is not None and not feeder._calibrating:
+        if feeder._device_ok is not None:
             return
         await asyncio.sleep(0.25)
 
@@ -864,7 +864,7 @@ def bench_put_path(nobj: int = 8, obj_mib: int = 6,
                 "put_sha256_device_items":
                     feeder.stats["device_items"] - sha_items0,
                 "put_stub_gbps": stub_gbps,
-                # per-lane calibration ledger ([MB, s] per op/backend,
+                # per-lane throughput ledger ([MB, s] per op/backend,
                 # exponentially forgotten) and the per-stage busy split
                 # — the two readings the TPU recapture runbook
                 # (DEVICE_PATH.md) interprets
@@ -2373,11 +2373,11 @@ def bench_native_blake3() -> float:
 
 def bench_native_parity() -> float:
     """The HOST route of the deep-scrub detect pass
-    (feeder._do_parity_check backend=host: native GF matmul + compare)
-    in logical 1 MiB blocks/s — what the product's deep scrub sustains
-    when calibration keeps it host-side."""
+    (block/host_legs.parity_check: native GF matmul + compare) in
+    logical 1 MiB blocks/s — what the product's deep scrub sustains on
+    a node whose route is the host."""
+    from garage_tpu.block import host_legs
     from garage_tpu.block.codec import ErasureCodec
-    from garage_tpu.block.feeder import DeviceFeeder
 
     from garage_tpu import native
 
@@ -2386,17 +2386,16 @@ def bench_native_parity() -> float:
         # (same honesty rule as the blake3/jax-on-host relabeling)
         raise RuntimeError("native kernels unavailable")
     codec = ErasureCodec(10, 4, use_jax=False)
-    f = DeviceFeeder(codec=codec, mode="off")
     rng = np.random.default_rng(4)
     stripes = [codec.encode(
         rng.integers(0, 256, 1 << 20, dtype=np.uint8).tobytes())
         for _ in range(8)]
-    f._do_parity_check(stripes, "host")  # warm
+    host_legs.parity_check(codec, stripes)  # warm
     best = 0.0
     for _rep in range(3):
         t0 = time.perf_counter()
         for _ in range(3):
-            verdicts = f._do_parity_check(stripes, "host")
+            verdicts = host_legs.parity_check(codec, stripes)
             if not all(verdicts):
                 raise RuntimeError(f"healthy stripes flagged: {verdicts}")
         dt = time.perf_counter() - t0
@@ -2476,7 +2475,7 @@ def main() -> int:
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
 
-    # main segment: erasure(4,2), feeder auto-calibrated. Run TWICE,
+    # main segment: erasure(4,2), feeder in mode auto. Run TWICE,
     # interleaved with the cpu-baseline segment below, and keep each
     # segment's best.
     def best_of(a: dict, b: dict) -> dict:

@@ -50,16 +50,11 @@ def apply_s3_tuning(garage, spec: dict) -> dict:
               # on/off without a server restart (0 = disabled)
               "read_cache_max_bytes": (0, 1 << 40),
               "read_cache_probation_pct": (1, 90),
-              # device feeder ([tpu] knobs, block/feeder.py): pipeline
-              # depth and host/device routing floors, live-tunable so
+              # device feeder ([tpu] inflight_batches,
+              # block/feeder.py): pipeline depth, live-tunable so
               # bench sweeps walk the overlap/latency trade without a
               # server restart
-              "feeder_inflight_batches": (1, 16),
-              "feeder_device_min_bytes": (0, 1 << 40),
-              "feeder_device_min_items": (1, 4096),
-              # read-side routing floors (decode/repair — ISSUE 13)
-              "feeder_device_min_decode_bytes": (0, 1 << 40),
-              "feeder_device_min_decode_items": (1, 4096)}
+              "feeder_inflight_batches": (1, 16)}
     validated = {}
     for k, raw in spec.items():
         if k not in bounds:
@@ -108,10 +103,6 @@ def s3_tuning_state(garage) -> dict:
                                   None) is not None
                        else {"enabled": False}),
         "feeder_inflight_batches": feeder.inflight_batches,
-        "feeder_device_min_bytes": feeder.device_min_bytes,
-        "feeder_device_min_items": feeder.device_min_items,
-        "feeder_device_min_decode_bytes": feeder.device_min_decode_bytes,
-        "feeder_device_min_decode_items": feeder.device_min_decode_items,
         "feeder_pipeline": feeder.pipeline_stats(),
     }
 
@@ -1085,7 +1076,7 @@ class AdminHttpServer:
 
         out.extend(registry().render())
 
-        # device feeder calibration + staged-pipeline observability.
+        # device feeder throughput + staged-pipeline observability.
         # Names are registered literally (GL07-checkable, and `feeder`
         # is in METRIC_NAME_RE) — the old `gauge(f"feeder_{k}")` loop
         # was a dynamic name no static rule could audit.

@@ -22,8 +22,8 @@ returned. This module is the staged replacement:
   lengths to the next power of two, so XLA compiles a handful of
   programs instead of one per distinct batch shape. Zero padding is
   safe for the RS ops because the code is linear (zero rows encode to
-  zero parity — `_do_parity_check` already relies on this); hash pad
-  rows are full-length zero messages whose digests are sliced away
+  zero parity); hash pad rows are full-length zero messages whose
+  digests are sliced away
   (BLAKE3's tree shape depends on the true chunk count, so the chunk
   axis is NOT bucketed — only the item axis is). Padding waste and
   recompile count are tracked in the feeder's stats
@@ -57,6 +57,8 @@ import threading
 import time
 
 import numpy as np
+
+from . import host_legs
 
 log = logging.getLogger("garage_tpu.block.device_backend")
 
@@ -114,12 +116,13 @@ class StageJob:
     can carry side effects (the d2h MD5 lane advance), and running one
     after its batch already failed over to the host path would apply
     those effects twice. `busy` is the fn's exclusive execution time —
-    what calibration records, NOT the pipeline wall (which includes
-    queue wait behind sibling batches and would understate device
-    throughput by up to the in-flight depth). `t_sub`, `t_claim`,
-    `t_done` are `perf_counter` stamps of submit, claim and fn return
-    (0.0 = not reached); the thread only writes its own two, and the
-    coroutine that waits for the job observes them on the loop."""
+    what the feeder records as device time, NOT the pipeline wall
+    (which includes queue wait behind sibling batches and would
+    understate device throughput by up to the in-flight depth).
+    `t_sub`, `t_claim`, `t_done` are `perf_counter` stamps of submit,
+    claim and fn return (0.0 = not reached); the thread only writes its
+    own two, and the coroutine that waits for the job observes them on
+    the loop."""
 
     __slots__ = ("loop", "fut", "fn", "claimed", "busy",
                  "t_sub", "t_claim", "t_done")
@@ -572,9 +575,7 @@ class JaxDeviceBackend:
                 for row, i in enumerate(idxs):
                     digests[i] = arr[row].tobytes()
             if op == "verify":
-                from .feeder import _verify_matches
-
-                return _verify_matches(digests, blobs)
+                return host_legs.verify_matches(digests, blobs)
             if op == "hash_md5":
                 # hash results are safely back on the host FIRST: a
                 # device failure raises before this point, so the host
@@ -662,7 +663,7 @@ class StubDeviceBackend:
 
     name = "stub"
 
-    def __init__(self, feeder=None, h2d_gbps: float = 1.0,
+    def __init__(self, codec=None, h2d_gbps: float = 1.0,
                  compute_gbps: float = 8.0, d2h_gbps: float = 1.0,
                  fixed_s: float = 0.0):
         env = os.environ.get("GARAGE_TPU_STUB_GBPS")
@@ -677,7 +678,7 @@ class StubDeviceBackend:
             except ValueError:
                 log.warning("bad GARAGE_TPU_STUB_GBPS %r; using defaults",
                             env)
-        self.feeder = feeder
+        self.codec = codec
         self.rates = {"h2d": h2d_gbps, "compute": compute_gbps,
                       "d2h": d2h_gbps}
         self.fixed_s = float(fixed_s)
@@ -706,24 +707,12 @@ class StubDeviceBackend:
         self._maybe_hang("compute")
         op, blobs, nbytes = staged
         self._sleep("compute", nbytes)
-        f = self.feeder
-        if op in ("hash", "verify", "hash_md5"):
-            datas = blobs if op == "hash" else [d for _, d in blobs]
-            res = f._do_hash(list(datas), "host")
-        elif op == "sha256":
-            res = f._do_sha256(list(blobs), "host")
-        elif op == "encode":
-            res = f._do_encode(list(blobs), "host")
-        elif op == "encode_put":
-            res = f._do_encode_put(list(blobs), "host")
-        elif op == "parity_check":
-            res = f._do_parity_check(list(blobs), "host")
-        elif op == "decode":
-            res = f._do_decode(list(blobs), "host")
-        elif op == "repair":
-            res = f._do_repair(list(blobs), "host")
+        if op in ("verify", "hash_md5"):
+            # digests only: the match rule and the MD5 advance are the
+            # d2h stage's, as on the real backend
+            res = host_legs.content_hashes([d for _, d in blobs])
         else:
-            raise RuntimeError(f"unknown device op {op!r}")
+            res = host_legs.run(self.codec, op, list(blobs))
         return (op, blobs, res)
 
     def readback(self, op: str, handle) -> list:
@@ -741,9 +730,7 @@ class StubDeviceBackend:
             out_bytes = len(res)
         self._sleep("d2h", out_bytes)
         if op == "verify":
-            from .feeder import _verify_matches
-
-            return _verify_matches(res, blobs)
+            return host_legs.verify_matches(res, blobs)
         if op == "hash_md5":
             from .. import native
 

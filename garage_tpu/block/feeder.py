@@ -25,12 +25,17 @@ follows is logged once and shown in /metrics (`feeder_device_route`):
   raises or hangs fails its items with the device's own error. Nothing
   is re-run on the host, so a compile error, an out-of-memory launch
   or a refused kernel cannot pass as a slow green run.
-- mode "auto": a missing or wrong platform routes everything host-side,
-  said once. With a device, the feeder CALIBRATES — it tracks observed
-  bytes/s per (op, backend) and routes each batch to the faster one,
-  re-trying the loser periodically — and a failed or hung device leg is
-  re-run on the host (`feeder_host_reruns` counts them).
+- mode "auto": the device is asked for in the background at the first
+  batch, and batches run on the host until the verdict lands. The
+  required platform opens the device route, and every queued batch then
+  runs on it; a missing or wrong platform routes everything host-side,
+  said once. A failed or hung device leg is re-run on the host
+  (`feeder_host_reruns` counts them), and a hang shuts the route.
 - mode "off": host only; JAX is never imported.
+
+The route is a function of the mode and that one verdict, and of
+nothing else: not of a batch's size, not of a measured rate. The host
+side of every op is block/host_legs.py.
 
 The device route is a STAGED PIPELINE (block/device_backend.py): each
 batch flows h2d -> compute -> d2h through three dedicated worker
@@ -59,39 +64,17 @@ import numpy as np
 
 from ..utils import tracing
 from ..utils.metrics import registry
+from . import host_legs
 from .device_backend import (DEFAULT_PAD_BUCKETS, STAGES, DevicePipeline,
                              JaxDeviceBackend, StubDeviceBackend,
                              bucket_items, group_bytes)
 
 log = logging.getLogger("garage_tpu.block.feeder")
 
-# a device round trip only pays above these sizes
-_DEVICE_MIN_BYTES = 4 << 20
-_DEVICE_MIN_ITEMS = 4
-# separate floors for the READ-side ops (decode/repair): degraded GETs
-# are latency-sensitive, so a lone decode stays host-inline and only
-# coalesced bursts (concurrent degraded GETs, scrub/resync rebuild
-# waves) pay a device trip
-_DEVICE_MIN_DECODE_BYTES = 4 << 20
-_DEVICE_MIN_DECODE_ITEMS = 4
 # inline decode/repair fast path size ceiling: above this the GF
 # matmul runs in a worker thread via the queue (a multi-MiB stripe
 # matmul inline would park the event loop for milliseconds per GET)
 _INLINE_DECODE_MAX_BYTES = 1 << 20
-# re-try the losing backend at most this often (wall clock) so a
-# warmed-up XLA program gets re-discovered. Time-based, not
-# count-based: a per-N-calls rule taxes busy traffic while an idle
-# server never re-tries at all.
-_EXPLORE_SECS = 60.0
-# exploration trials of the LOSING backend are capped: the re-try only
-# needs one timing sample, not the whole batch. The cap is byte-aware —
-# at least 2 items, growing to 8 while the slice is under
-# _TRIAL_MAX_BYTES — so a trial of small blobs still amortizes the
-# backend's fixed round-trip latency instead of permanently
-# under-measuring it. The rest of the batch runs on the winner.
-_TRIAL_MAX_ITEMS = 2
-_TRIAL_ITEMS_CAP = 8
-_TRIAL_MAX_BYTES = 4 << 20
 # a batch stuck longer than this means the device backend hung: the
 # stage threads are abandoned and the device path is disabled
 _BATCH_TIMEOUT = 300.0
@@ -105,16 +88,6 @@ _LINGER_OPS = ("hash_md5", "hash", "encode_put", "sha256")
 # the JAX platform the device path must find unless [tpu] platform says
 # otherwise (tests and chip_smoke's rehearsal name "cpu")
 _DEVICE_PLATFORM = "tpu"
-
-
-def _verify_matches(digs: list, items: list) -> list[bool]:
-    """Per-item content-hash verdicts; one copy of the match rule
-    (digest equality, legacy-algo fallback) for the inline fast path
-    and the batch-queue path alike."""
-    from ..utils.data import content_hash_matches
-
-    return [dg == h or content_hash_matches(d, h)
-            for dg, (h, d) in zip(digs, items)]
 
 
 class _DeviceHang(Exception):
@@ -150,9 +123,9 @@ class _Item:
 
 class DeviceFeeder:
     """One per BlockManager. mode: "auto" (device when this process has
-    one and it measures faster), "off" (host only), "require" (device
-    only: no host re-run, fail with the platform or the device's error
-    named)."""
+    the required one, host otherwise and for a device leg that fails),
+    "off" (host only), "require" (device only: no host re-run, fail
+    with the platform or the device's error named)."""
 
     def __init__(self, codec=None, mode: str = "auto",
                  max_batch: int = 256, tpu_cfg=None, backend=None):
@@ -160,28 +133,12 @@ class DeviceFeeder:
         # greedy-drain cap: blocks per device batch ([tpu] batch_blocks)
         self.max_batch = max(1, int(max_batch))
 
-        # [tpu] knobs (utils/config.py TpuConfig); the module constants
-        # stay as defaults so direct-constructed feeders (tests, bench)
-        # behave exactly as before. Runtime-tunable via the admin
-        # GET/POST /v1/s3/tuning endpoint like the s3 knobs.
+        # [tpu] knobs (utils/config.py TpuConfig), with the defaults a
+        # direct-constructed feeder (tests, bench) gets
         def knob(name, default):
             v = getattr(tpu_cfg, name, None) if tpu_cfg is not None else None
             return default if v is None else v
 
-        self.device_min_bytes = int(knob("device_min_bytes",
-                                         _DEVICE_MIN_BYTES))
-        self.device_min_items = int(knob("device_min_items",
-                                         _DEVICE_MIN_ITEMS))
-        self.device_min_decode_bytes = int(knob("device_min_decode_bytes",
-                                                _DEVICE_MIN_DECODE_BYTES))
-        self.device_min_decode_items = int(knob("device_min_decode_items",
-                                                _DEVICE_MIN_DECODE_ITEMS))
-        self.trial_max_items = int(knob("trial_max_items",
-                                        _TRIAL_MAX_ITEMS))
-        self.trial_items_cap = int(knob("trial_items_cap",
-                                        _TRIAL_ITEMS_CAP))
-        self.trial_max_bytes = int(knob("trial_max_bytes",
-                                        _TRIAL_MAX_BYTES))
         # staged-pipeline depth: batches concurrently in flight through
         # the h2d/compute/d2h stages. A batch's dispatch slot is held
         # until its d2h readback drains, so depth 2 (double buffering)
@@ -214,8 +171,8 @@ class DeviceFeeder:
 
         env_mode = os.environ.get("GARAGE_TPU_DEVICE")
         if mode == "auto" and env_mode == "off":
-            # test/CI kill-switch: JAX is never imported, no
-            # calibration threads are spawned
+            # test/CI kill-switch: JAX is never imported, no stage
+            # threads are spawned
             mode = "off"
         elif mode == "auto" and env_mode == "require":
             # the device path is mandatory (chip_smoke's node 1, the
@@ -234,7 +191,6 @@ class DeviceFeeder:
         self.route_reason = "mode off" if mode == "off" else ""
         self._verdict_lock: Optional[asyncio.Lock] = None
         self._verdict_task: Optional[asyncio.Task] = None
-        self._calibrating = False
         self.stats = {"batches": 0, "items": 0, "device_batches": 0,
                       "device_items": 0, "device_bytes": 0,
                       "inline_items": 0, "max_batch": 0,
@@ -263,13 +219,10 @@ class DeviceFeeder:
         # PUT streams currently inside read_and_put_blocks: sizes the
         # hash_md5 gather window (one block hash in flight per stream)
         self.active_streams = 0
-        # calibration: (op, backend) -> [bytes, seconds]; routing picks
-        # the backend with the best observed bytes/s, exploring the
-        # other every _EXPLORE_EVERY batches
+        # observed throughput: (op, backend) -> [bytes, seconds], for
+        # perf_summary; nothing routes by it
         self._perf: dict[tuple[str, str], list[float]] = {}
         self._perf_lock = threading.Lock()  # inline (loop) vs worker thread
-        self._last_explore: dict[str, float] = {}
-        self._force_device: dict[str, bool] = {}
         # items that ran on the device path, per feeder op
         self.device_items_by_op: dict[str, int] = {}
 
@@ -278,15 +231,6 @@ class DeviceFeeder:
         with self._perf_lock:
             return {f"{op}/{be}": round(b / t / 1e6, 1)
                     for (op, be), (b, t) in self._perf.items() if t > 0}
-
-    def _rates(self, op: str):
-        """(device_rate|None, host_rate|None) under the lock — readers
-        on the loop thread race _record in the worker thread."""
-        with self._perf_lock:
-            dev = self._perf.get((op, "device"))
-            host = self._perf.get((op, "host"))
-            return (dev[0] / dev[1] if dev else None,
-                    host[0] / host[1] if host else None)
 
     # ---- lifecycle ----------------------------------------------------
 
@@ -316,10 +260,8 @@ class DeviceFeeder:
                 sel = self._backend_sel
                 if not isinstance(sel, str):
                     self._backend = sel
-                    if getattr(sel, "feeder", False) is None:
-                        sel.feeder = self  # test-built stubs wire back
                 elif sel == "stub":
-                    self._backend = StubDeviceBackend(self)
+                    self._backend = StubDeviceBackend(self.codec)
                 else:
                     self._backend = JaxDeviceBackend(
                         codec=self.codec, pad_buckets=self.pad_buckets,
@@ -434,33 +376,11 @@ class DeviceFeeder:
 
     def _maybe_start_verdict(self) -> None:
         """auto: ask for the device in the background at the first
-        batch, then seed both backends' throughput samples off the
-        request path. Everything runs host-side until that lands."""
+        batch. Everything runs host-side until the verdict lands."""
         if self.mode != "auto" or self._device_ok is not None \
                 or self._verdict_task is not None:
             return
-        self._calibrating = True
-
-        async def run():
-            try:
-                await self.device_verdict()
-                if not self._device_ok:
-                    return
-                # calibration occupies the compute stage thread (daemon,
-                # abandoned with its generation on a hang) and sits
-                # under the same watchdog as a batch
-                try:
-                    await asyncio.wait_for(
-                        self._stage_call(self._pipeline(), "compute",
-                                         self._calibrate, [], "calibrate"),
-                        self.batch_timeout)
-                except (asyncio.TimeoutError, _DeviceHang):
-                    self._on_device_hang(
-                        f"calibration stuck >{self.batch_timeout:g}s")
-            finally:
-                self._calibrating = False
-
-        self._verdict_task = asyncio.create_task(run(),
+        self._verdict_task = asyncio.create_task(self.device_verdict(),
                                                  name="feeder-verdict")
         # supervised by stop(): not a leak at loop teardown
         self._verdict_task._garage_background = True
@@ -505,59 +425,6 @@ class DeviceFeeder:
             while not q.empty():
                 q.get_nowait().resolve(RuntimeError("feeder stopped"))
 
-    def _calibrate(self) -> None:
-        from ..utils import data as _data
-
-        blob = bytes(np.random.default_rng(0).integers(
-            0, 256, 1 << 20, dtype=np.uint8))
-        batch = [blob] * 4
-        dec_items = None
-        if self.codec is not None and self.codec.m >= 1:
-            # read-side seed: a degraded stripe (shard 0 lost, first
-            # parity standing in) — without it the first production
-            # decode wave pays a cold device trial inline, exactly what
-            # calibration exists to avoid on the PUT ops
-            k = self.codec.k
-            present = tuple(range(1, k + 1))
-            stripes = self._do_encode(batch, "host")
-            dec_items = [(present, [st[i] for i in present], len(blob))
-                         for st in stripes]
-        for backend in ("host", "device"):
-            try:
-                # blake2 hashing never runs on device — recording a
-                # host timing under the device key would fabricate a
-                # backend that never ran
-                if _data._content_algo == "blake3" or backend == "host":
-                    t0 = time.perf_counter()
-                    self._do_hash(batch, backend)
-                    self._record("hash", backend, len(batch) << 20,
-                                 time.perf_counter() - t0)
-                if self.codec is not None:
-                    t0 = time.perf_counter()
-                    self._do_encode(batch, backend)
-                    self._record("encode", backend, len(batch) << 20,
-                                 time.perf_counter() - t0)
-                if dec_items is not None:
-                    t0 = time.perf_counter()
-                    self._do_decode(dec_items, backend)
-                    self._record("decode", backend,
-                                 sum(len(b) for it in dec_items
-                                     for b in it[1]),
-                                 time.perf_counter() - t0)
-            except Exception as e:
-                # a host-leg failure must not kill the thread silently
-                # (the device leg would then never run and the first
-                # production batch would pay the cold device trial the
-                # calibration exists to avoid)
-                log.warning("%s calibration leg failed (%s: %s)",
-                            backend, type(e).__name__, e)
-                if backend == "device":
-                    self.stats["device_errors"] += 1
-                    self._record("hash", "device", 0, 60.0)
-                    self._record("encode", "device", 0, 60.0)
-                    self._record("decode", "device", 0, 60.0)
-        log.info("feeder calibration: %s", self.perf_summary())
-
     # ---- public async ops ---------------------------------------------
 
     async def _submit(self, op: str, data, extra=None):
@@ -589,7 +456,7 @@ class DeviceFeeder:
 
     async def hash(self, data: bytes) -> bytes:
         """Content hash of one block (batched with concurrent callers)."""
-        if self._host_inline_ok("hash"):
+        if self._host_inline_ok():
             from ..utils import data as _data
 
             if _data._content_algo == "blake3":
@@ -619,7 +486,7 @@ class DeviceFeeder:
 
             if _data._content_algo == "blake3":
                 if self.active_streams <= 1 \
-                        and self._host_inline_ok("hash"):
+                        and self._host_inline_ok():
                     # lone stream: no lanes to gather — the inline
                     # one-pass interleaved kernel beats the queue hop
                     # plus a 1-lane batch
@@ -662,61 +529,21 @@ class DeviceFeeder:
             raise RuntimeError("feeder has no codec")
         return await self._submit("encode", packed)
 
-    def _host_inline_ok(self, op: str) -> bool:
+    def _host_inline_ok(self) -> bool:
         """True when the queue+thread hop is pure overhead: the route is
-        host anyway and the native kernel (which releases the GIL) can
-        run inline on the event loop. The queue path exists to build
-        device batches; paying two thread handoffs per item to then run
-        host-side was a top cost in the r3 kernel-vs-system gap."""
+        the host for good (mode "off", or the verdict shut the device
+        route) and the native kernel (which releases the GIL) can run
+        inline on the event loop. The queue path exists to build device
+        batches; paying two thread handoffs per item to then run
+        host-side was a top cost in the r3 kernel-vs-system gap. False
+        under "require", while the verdict is out, and when the device
+        route is open."""
         from .. import native
 
         if not native.loaded():
             return False
-        if self.mode == "off":
-            return True
-        if self.mode == "require" or self._device_ok is None:
-            return False  # device mandatory / verdict still out
-        if self._device_ok is False:
-            return True
-        dev_rate, host_rate = self._rates(op)
-        if dev_rate is not None and host_rate is not None \
-                and dev_rate < host_rate:
-            # host is winning on data; still send an occasional call
-            # through the queue WITH a forced device trial so a
-            # recovered device gets re-discovered
-            if self._explore_due(op):
-                self._force_device[op] = True
-                return False
-            return True
-        return False
-
-    def _explore_due(self, op: str) -> bool:
-        now = time.monotonic()
-        if op not in self._last_explore:
-            # calibration just measured both backends — the clock starts
-            # there, not at zero (else the first production batch pays a
-            # pointless trial on the known-slow backend)
-            self._last_explore[op] = now
-            return False
-        # adaptive interval: the wider the measured gap, the rarer the
-        # re-try. A backend losing 8x gets the base 60 s cadence; one
-        # losing 500x is re-tried ~hourly — a trial there costs real
-        # seconds of live traffic, and a gap that wide doesn't close
-        # by itself anyway.
-        dev, host = self._rates(op)
-        interval = _EXPLORE_SECS
-        if dev is not None and host is not None:
-            # a 0.0 rate (every byte of that backend's window failed)
-            # is the WIDEST gap, not missing data: cap straight to 64x
-            if min(dev, host) <= 0.0:
-                interval *= 64.0
-            else:
-                ratio = max(dev, host) / min(dev, host)
-                interval *= min(64.0, max(1.0, ratio / 8.0))
-        if now - self._last_explore[op] >= interval:
-            self._last_explore[op] = now
-            return True
-        return False
+        return self.mode == "off" or (self.mode == "auto"
+                                      and self._device_ok is False)
 
     async def encode_put(self, data: bytes, prefix: bytes = b"") -> list:
         """Erasure parts for one packed block (logical stream
@@ -728,7 +555,7 @@ class DeviceFeeder:
         if self.codec is None:
             raise RuntimeError("feeder has no codec")
         lease = data if hasattr(data, "stripe") else None
-        if self._host_inline_ok("encode"):
+        if self._host_inline_ok():
             from .. import native
             from ..ops import rs
 
@@ -764,7 +591,7 @@ class DeviceFeeder:
         """[(hash32, plain)] -> per-item content-hash match (scrub)."""
         if not items:
             return []
-        if self._host_inline_ok("hash"):
+        if self._host_inline_ok():
             from ..utils import data as _data
 
             if _data._content_algo == "blake3":
@@ -779,7 +606,7 @@ class DeviceFeeder:
                     native.blake3_many, [d for _, d in items])
                 self._record("hash", "host", sum(len(d) for _, d in items),
                              time.perf_counter() - t0)
-                return _verify_matches(digs, items)
+                return host_legs.verify_matches(digs, items)
         futs = [self._submit("verify", (h, d)) for h, d in items]
         return list(await asyncio.gather(*futs))
 
@@ -797,13 +624,13 @@ class DeviceFeeder:
             raise RuntimeError("feeder has no codec")
         if not stripes:
             return []
-        if self._host_inline_ok("parity"):
+        if self._host_inline_ok():
             # already batched; one thread handoff amortized over the
             # whole multi-MiB native call (same shape as verify_blocks)
             self.stats["inline_items"] += len(stripes)
             t0 = time.perf_counter()
-            out = await asyncio.to_thread(self._do_parity_check, stripes,
-                                          "host")
+            out = await asyncio.to_thread(host_legs.parity_check,
+                                          self.codec, stripes)
             self._record("parity", "host",
                          sum(len(b) for s in stripes for b in s),
                          time.perf_counter() - t0)
@@ -847,7 +674,7 @@ class DeviceFeeder:
                                      codec.k + codec.m)
         total = sum(len(s) for s in shards)
         if total <= _INLINE_DECODE_MAX_BYTES \
-                and self._host_inline_ok("decode"):
+                and self._host_inline_ok():
             from .. import native
             from ..ops import rs
 
@@ -885,7 +712,7 @@ class DeviceFeeder:
                 f"missing indices must be in [0, {width}); got {missing}")
         total = sum(len(s) for s in shards)
         if total <= _INLINE_DECODE_MAX_BYTES \
-                and self._host_inline_ok("decode"):
+                and self._host_inline_ok():
             from .. import native
             from ..ops import rs
 
@@ -1010,23 +837,21 @@ class DeviceFeeder:
             1 for it in batch if it.op in ("decode", "repair"))
         self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
         results: list = [None] * len(batch)
-        legs = self._plan_batch(batch)
-        host_legs = [leg for leg in legs if leg[3] != "device"]
-        device_legs = [leg for leg in legs if leg[3] == "device"]
-        if not device_legs:
+        on_device, on_host = self._plan_batch(batch)
+        if not on_device:
             # pure host batch: exactly the pre-pipeline behavior (one
             # thread hop), still bounded so a stalled host path fails
             # the batch instead of wedging a pipeline slot forever
             await asyncio.wait_for(
-                asyncio.to_thread(self._exec_legs, batch, legs, results),
+                asyncio.to_thread(self._exec_legs, batch, on_host, results),
                 self.batch_timeout)
             return results
         tasks = [asyncio.create_task(
             self._exec_device_leg(op, perf_op, batch, idxs, results))
-            for op, perf_op, idxs, _b in device_legs]
-        if host_legs:
+            for op, perf_op, idxs in on_device]
+        if on_host:
             tasks.append(asyncio.create_task(asyncio.wait_for(
-                asyncio.to_thread(self._exec_legs, batch, host_legs,
+                asyncio.to_thread(self._exec_legs, batch, on_host,
                                   results),
                 self.batch_timeout)))
         try:
@@ -1069,12 +894,10 @@ class DeviceFeeder:
                 return
             for i, o in zip(idxs, out):
                 results[i] = o
-            # calibration records the EXCLUSIVE stage execution time,
-            # not this coroutine's wall: wall includes queue wait
-            # behind sibling batches in the single-thread stage
-            # executors, which would understate device throughput by
-            # up to the in-flight depth and flip routing back to host
-            # precisely because pipelining engaged
+            # the EXCLUSIVE stage execution time, not this coroutine's
+            # wall: wall includes queue wait behind sibling batches in
+            # the single-thread stage executors, which would understate
+            # device throughput by up to the in-flight depth
             self._record(perf_op, "device", total, busy)
             self._credit_device(op, len(idxs), total)
         finally:
@@ -1094,8 +917,7 @@ class DeviceFeeder:
                                  err: BaseException) -> None:
         """A device leg raised or hung. "require": the items fail with
         the device's own error — no host result may stand in for it.
-        "auto": penalize the device in calibration and re-run the group
-        on the host, counted in host_reruns."""
+        "auto": re-run the group on the host, counted in host_reruns."""
         self.stats["device_errors"] += 1
         if self.mode == "require":
             log.error("device %s batch failed (%s: %s); device is "
@@ -1106,11 +928,10 @@ class DeviceFeeder:
             return
         log.warning("device %s batch failed (%s: %s); re-running on "
                     "the host", op, type(err).__name__, err)
-        self._record(perf_op, "device", 0, 60.0)
         self.stats["host_reruns"] += 1
         await asyncio.wait_for(
             asyncio.to_thread(self._exec_group, op, perf_op, batch, idxs,
-                              "host", results),
+                              results),
             self.batch_timeout)
 
     async def _staged_op(self, op: str, blobs: list) -> tuple[list, float]:
@@ -1220,38 +1041,7 @@ class DeviceFeeder:
                 else 0.0,
                 "inflight": len(self._inflight_tasks)}
 
-    # ---- batch execution (worker thread) -------------------------------
-
-    def _pick_backend(self, op: str, total_bytes: int,
-                      n_items: int) -> tuple[str, bool]:
-        """-> (backend, trial). trial=True marks an exploration of the
-        currently-losing backend: _run_batch caps that slice to
-        _TRIAL_MAX_ITEMS and runs the rest on the winner."""
-        if self.mode == "require":
-            return "device", False  # forced: proof of the device path
-        if self._device_ok is not True or self._calibrating:
-            return "host", False
-        if self._force_device.pop(op, False):
-            return "device", True  # inline fast-path escape: re-try now
-        if op == "decode":
-            # the read-side floors ([tpu] device_min_decode_*): degraded
-            # GETs are latency-sensitive, so lone decodes stay host
-            min_bytes, min_items = (self.device_min_decode_bytes,
-                                    self.device_min_decode_items)
-        else:
-            min_bytes, min_items = (self.device_min_bytes,
-                                    self.device_min_items)
-        if total_bytes < min_bytes and n_items < min_items:
-            return "host", False  # tiny batches never amortize a round trip
-        dev_rate, host_rate = self._rates(op)
-        if dev_rate is None:
-            return "device", False  # first sizeable batch: measure it
-        if host_rate is None:
-            return "host", False
-        if self._explore_due(op):
-            # periodic re-try of whichever backend is currently losing
-            return ("device" if dev_rate < host_rate else "host"), True
-        return ("device" if dev_rate >= host_rate else "host"), False
+    # ---- the plan, and the host legs (worker thread) -------------------
 
     def _record(self, op: str, backend: str, nbytes: int, dt: float) -> None:
         with self._perf_lock:  # inline paths record from the loop thread
@@ -1263,413 +1053,47 @@ class DeviceFeeder:
             ent[0] += nbytes
             ent[1] += max(dt, 1e-6)
 
-    def _plan_batch(self, batch: list[_Item], force_host: bool = False
-                    ) -> list[tuple]:
-        """-> [(op, perf_op, idxs, backend)] legs, trial splits applied
-        — the routing brain shared by the staged pipeline (async) and
-        the synchronous host paths (hang re-run, direct callers)."""
+    def _plan_batch(self, batch: list[_Item]) -> tuple[list, list]:
+        """-> (device legs, host legs), each [(op, perf_op, idxs)], one
+        leg an op. The route is the mode and the one device verdict:
+        the device under "require", and under "auto" once the verdict
+        opened the route; the host while the verdict is out and when it
+        shut the route."""
+        from ..utils import data as _data
+
+        route_open = self.mode == "require" or self._device_ok is True
         by_op: dict[str, list[int]] = {}
         for i, item in enumerate(batch):
             by_op.setdefault(item.op, []).append(i)
-        legs: list[tuple] = []
+        on_device: list[tuple] = []
+        on_host: list[tuple] = []
         for op, idxs in by_op.items():
-            total = group_bytes(op, [batch[i].data for i in idxs])
             perf_op = ("hash" if op in ("verify", "hash_md5") else
                        "encode" if op == "encode_put" else
                        "parity" if op == "parity_check" else
                        "decode" if op == "repair" else op)
-            host_only = force_host
-            if perf_op == "hash":
-                from ..utils import data as _data
-
-                if _data._content_algo != "blake3":
-                    host_only = True  # blake2 never runs on device
-            if host_only:
-                backend, trial = "host", False
-            else:
-                backend, trial = self._pick_backend(perf_op, total,
-                                                    len(idxs))
-            cut = self._trial_cut(op, batch, idxs) if trial else len(idxs)
-            if cut < len(idxs):
-                # exploration of the losing backend: one small timing
-                # sample there, the bulk stays on the winner
-                other = "host" if backend == "device" else "device"
-                legs.append((op, perf_op, idxs[:cut], backend))
-                legs.append((op, perf_op, idxs[cut:], other))
-            else:
-                legs.append((op, perf_op, idxs, backend))
-        return legs
+            # blake2 never runs on the device
+            device = route_open and not (
+                perf_op == "hash" and _data._content_algo != "blake3")
+            (on_device if device else on_host).append((op, perf_op, idxs))
+        return on_device, on_host
 
     def _exec_legs(self, batch: list, legs: list, results: list) -> None:
-        for op, perf_op, idxs, backend in legs:
-            self._exec_group(op, perf_op, batch, idxs, backend, results)
-
-    def _run_batch(self, batch: list[_Item], force_host: bool = False
-                   ) -> list:
-        """Synchronous (worker-thread) batch execution — the hang
-        fallback and direct test/bench entry point. The live dispatcher
-        routes through _run_batch_staged instead."""
-        self.stats["batches"] += 1
-        self.stats["items"] += len(batch)
-        self.stats["decode_items"] += sum(
-            1 for it in batch if it.op in ("decode", "repair"))
-        self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
-        results: list = [None] * len(batch)
-        self._exec_legs(batch, self._plan_batch(batch, force_host), results)
-        return results
-
-    def _trial_cut(self, op: str, batch: list, idxs: list) -> int:
-        """Items in the exploration slice: at least trial_max_items,
-        growing to trial_items_cap while under trial_max_bytes."""
-        cut, size = 0, 0
-        for i in idxs:
-            if cut >= self.trial_max_items and (
-                    size >= self.trial_max_bytes
-                    or cut >= self.trial_items_cap):
-                break
-            d = batch[i].data
-            if op in ("verify", "encode_put", "hash_md5") \
-                    and isinstance(d, tuple):
-                d = d[1]
-            if hasattr(d, "total_len"):
-                size += d.total_len
-                cut += 1
-                continue
-            if op == "parity_check":
-                size += sum(len(b) for b in d)
-            elif op == "sha256" and isinstance(d, (list, tuple)):
-                size += sum(len(b) for b in d)  # span-list message
-            elif op == "decode":
-                size += sum(len(b) for b in d[1])
-            elif op == "repair":
-                size += sum(len(b) for b in d[2])
-            else:
-                size += len(d) if isinstance(d, (bytes, bytearray,
-                                                 memoryview)) else 0
-            cut += 1
-        return cut
+        for op, perf_op, idxs in legs:
+            self._exec_group(op, perf_op, batch, idxs, results)
 
     def _exec_group(self, op: str, perf_op: str, batch: list,
-                    idxs: list, backend: str, results: list) -> None:
+                    idxs: list, results: list) -> None:
+        """One op group on the host (block/host_legs.py); an error is
+        its items' result."""
         blobs = [batch[i].data for i in idxs]
-        total = group_bytes(op, blobs)
         t0 = time.perf_counter()
         try:
-            try:
-                out = self._do_op(op, blobs, backend)
-            except Exception as e:
-                if backend != "device":
-                    raise
-                self.stats["device_errors"] += 1
-                if self.mode == "require":
-                    raise  # the device's own error fails the items
-                # auto: a failing device must not fail requests while
-                # the host path works — re-run host-side and penalize
-                # the device in calibration
-                log.warning("device %s batch failed (%s: %s); "
-                            "re-running on the host", op,
-                            type(e).__name__, e)
-                self._record(perf_op, "device", 0, 60.0)
-                self.stats["host_reruns"] += 1
-                backend = "host"
-                t0 = time.perf_counter()
-                out = self._do_op(op, blobs, backend)
+            out = host_legs.run(self.codec, op, blobs)
             for i, o in zip(idxs, out):
                 results[i] = o
-            self._record(perf_op, backend, total,
+            self._record(perf_op, "host", group_bytes(op, blobs),
                          time.perf_counter() - t0)
-            if backend == "device":
-                self._credit_device(op, len(idxs), total)
         except Exception as e:
             for i in idxs:
                 results[i] = e
-
-    def _do_op(self, op: str, blobs: list, backend: str) -> list:
-        if op == "hash":
-            return self._do_hash(blobs, backend)
-        if op == "hash_md5":
-            from .. import native
-
-            if backend == "device":
-                # content hash batches to the device FIRST: if it
-                # raises, the host re-run (auto) repeats this op
-                # from scratch, and MD5 state must not have advanced
-                # yet or the retry double-counts the bytes into the
-                # ETag chain. Only then batch-advance the MD5s host-
-                # side (8-way across items).
-                out = self._do_hash([d for _, d in blobs], backend)
-                native.md5_update_many(list(blobs))
-                return out
-            return native.b3_md5_many(list(blobs))
-        if op == "sha256":
-            return self._do_sha256(blobs, backend)
-        if op == "verify":
-            digs = self._do_hash([b for _, b in blobs], backend)
-            return _verify_matches(digs, blobs)
-        if op == "encode":
-            return self._do_encode(blobs, backend)
-        if op == "encode_put":
-            return self._do_encode_put(blobs, backend)
-        if op == "parity_check":
-            return self._do_parity_check(blobs, backend)
-        if op == "decode":
-            return self._do_decode(blobs, backend)
-        if op == "repair":
-            return self._do_repair(blobs, backend)
-        raise RuntimeError(f"unknown feeder op {op!r}")
-
-    def _do_hash(self, blobs: list[bytes], backend: str) -> list[bytes]:
-        from ..utils import data as _data
-
-        if _data._content_algo != "blake3":
-            return [_data.content_hash(b) for b in blobs]
-        if backend == "device":
-            from ..ops import treehash
-
-            return treehash.blake3_many(blobs)
-        try:
-            from .. import native
-
-            if native.available():
-                return native.blake3_many(blobs)
-        except Exception:
-            # lint: ignore[GL05] native backend optional; pure-python fallback follows
-            pass
-        from ..utils.data import blake3sum
-
-        return [blake3sum(b) for b in blobs]
-
-    def _do_sha256(self, blobs: list, backend: str) -> list[str]:
-        """SigV4 chunk digests (hex) — independent across items, so the
-        whole group is one device launch (ops/sha256) or a host loop."""
-        from ..ops import sha256 as _sha
-
-        if backend == "device":
-            return _sha.sha256_hex_many(blobs)
-        return [_sha.sha256_hex_py(b) for b in blobs]
-
-    def _do_encode_put(self, items: list, backend: str
-                       ) -> list[list]:
-        """items = [(prefix, data)] or ingest leases (scheme byte + body
-        resident in one pool buffer); like _do_encode but each part is a
-        complete shard payload (pack_shard framing, crc32c). Host+native
-        is the PUT hot path."""
-        from .manager import pack_shard
-
-        codec = self.codec
-        if backend != "device":
-            try:
-                from .. import native
-
-                if native.available():
-                    from ..ops import rs
-
-                    pmat = rs.parity_matrix(codec.k, codec.m)
-                    out = []
-                    for it in items:
-                        if hasattr(it, "stripe"):
-                            out.append(native.rs_encode_packed(
-                                it.view(), codec.k, codec.m, pmat,
-                                prefix=bytes([it.buf[0]])))
-                        else:
-                            out.append(native.rs_encode_packed(
-                                it[1], codec.k, codec.m, pmat,
-                                prefix=it[0]))
-                    return out
-            except Exception:
-                # lint: ignore[GL05] native backend optional; _do_encode fallback follows
-                pass
-        # device, or host without native: delegate the encode itself to
-        # _do_encode (single source of truth) and wrap with pack_shard.
-        # Leases materialize here — the non-native fallback is off the
-        # perf path, and _do_encode wants plain byte blocks.
-        blocks = [bytes(it.buf[:it.total_len]) if hasattr(it, "total_len")
-                  else it[0] + it[1] for it in items]
-        parts_lists = (codec.encode_batch(blocks) if backend == "device"
-                       else self._do_encode(blocks, backend))
-        return [[pack_shard(pp, len(b)) for pp in parts]
-                for b, parts in zip(blocks, parts_lists)]
-
-    def _do_encode(self, blocks: list[bytes], backend: str
-                   ) -> list[list[bytes]]:
-        from ..ops import rs
-
-        codec = self.codec
-        if backend == "device":
-            return codec.encode_batch(blocks)
-        try:
-            from .. import native
-
-            if native.available():
-                out = []
-                for b in blocks:
-                    shards = rs.split_stripe(b, codec.k)
-                    parity = native.gf_matmul(
-                        rs.parity_matrix(codec.k, codec.m), shards)
-                    out.append([bytes(s) for s in shards]
-                               + [bytes(p) for p in parity])
-                return out
-        except Exception:
-            # lint: ignore[GL05] native backend optional; numpy fallback follows
-            pass
-        # last resort: pure numpy — NEVER codec.encode here, whose JAX
-        # path would re-enter the possibly-dead backend this host branch
-        # exists to avoid
-        out = []
-        for b in blocks:
-            shards = rs.split_stripe(b, codec.k)
-            parity = rs.encode_np(codec.k, codec.m, shards)
-            out.append([bytes(s) for s in shards]
-                       + [bytes(p) for p in parity])
-        return out
-
-    def _do_parity_check(self, stripes: list[list[bytes]], backend: str
-                         ) -> list[bool]:
-        """stripes = [[k data + m parity shard payloads]] -> per-stripe
-        consistency verdicts. Device: one padded (B, k+m, S) batch
-        through the encode bit-matmul + compare (zero padding is safe:
-        the code is linear). Host: native GF matmul per stripe, numpy
-        as last resort — same no-JAX-on-host rule as _do_encode."""
-        from ..ops import rs
-
-        codec = self.codec
-        k, m = codec.k, codec.m
-        if backend == "device":
-            smax = max(len(s[0]) for s in stripes)
-            arr = np.zeros((len(stripes), k + m, smax), dtype=np.uint8)
-            for i, s in enumerate(stripes):
-                for j, b in enumerate(s):
-                    arr[i, j, : len(b)] = np.frombuffer(b, dtype=np.uint8)
-            return [bool(v) for v in np.asarray(rs.parity_check(k, m, arr))]
-        pmat = rs.parity_matrix(k, m)
-        native_mod = None
-        try:
-            from .. import native
-
-            if native.available():
-                native_mod = native
-        except Exception:
-            # lint: ignore[GL05] native backend optional; numpy path handles it
-            pass
-        out = []
-        for s in stripes:
-            data = np.stack(
-                [np.frombuffer(b, dtype=np.uint8) for b in s[:k]])
-            parity = (native_mod.gf_matmul(pmat, data)
-                      if native_mod is not None
-                      else rs.encode_np(k, m, data))
-            out.append(all(bytes(parity[j]) == bytes(s[k + j])
-                           for j in range(m)))
-        return out
-
-    @staticmethod
-    def _native_or_none():
-        """The optional native kernel module, or None — one copy of the
-        guarded import the host legs share."""
-        try:
-            from .. import native
-
-            if native.available():
-                return native
-        except Exception:
-            # lint: ignore[GL05] native backend optional; numpy path handles it
-            pass
-        return None
-
-    def _do_decode(self, items: list[tuple], backend: str) -> list[bytes]:
-        """items = [(present, shards, plain_len)] -> packed block bytes
-        per item. Device: the batched pattern-as-data launch (one
-        compiled program per shape — the per-item decode matrices ride
-        as data). Host: native GF matmul per stripe, numpy as last
-        resort — same no-JAX-on-host rule as _do_encode."""
-        from ..ops import rs
-
-        codec = self.codec
-        k, m = codec.k, codec.m
-        if backend == "device":
-            return self._device_gf_batched("decode", items)
-        native_mod = self._native_or_none()
-        out = []
-        for present, shards, plain_len in items:
-            present = tuple(present)
-            st = np.stack([np.frombuffer(s, dtype=np.uint8)
-                           for s in shards])
-            if all(i < k for i in present):
-                data = st  # all-systematic: no math needed
-            elif native_mod is not None:
-                data = native_mod.gf_matmul(
-                    rs.decode_matrix(k, m, present), st)
-            else:
-                data = rs.decode_np(k, m, present, st)
-            out.append(rs.join_stripe(data, plain_len))
-        return out
-
-    def _device_gf_batched(self, op: str, items: list[tuple]) -> list:
-        """Synchronous-path device decode/repair: ONE padded
-        pattern-as-data launch per output-row group (the calibration /
-        sync-_run_batch twin of the backend's _stage_gf). Shapes pad up
-        the same bucket ladder, so the compiled programs are shared
-        with the staged route instead of jitting one B=1 program per
-        distinct shard length and paying N serial round-trips."""
-        from .device_backend import bucket_items, bucket_len
-        from ..ops import rs
-
-        codec = self.codec
-        k, m = codec.k, codec.m
-        shards_of = ((lambda it: it[1]) if op == "decode"
-                     else (lambda it: it[2]))
-        groups: dict[int, list[int]] = {}
-        for i, it in enumerate(items):
-            rows = k if op == "decode" else len(it[1])
-            groups.setdefault(rows, []).append(i)
-        results: list = [None] * len(items)
-        for rows, idxs in groups.items():
-            slens = [len(shards_of(items[i])[0]) for i in idxs]
-            smax = bucket_len(max(slens))
-            bpad = bucket_items(len(idxs), self.pad_buckets)
-            batch = np.zeros((bpad, k, smax), dtype=np.uint8)
-            mats = np.zeros((bpad, 8 * k, 8 * rows), dtype=np.int8)
-            for row, i in enumerate(idxs):
-                it = items[i]
-                present = tuple(it[0])
-                for j, s in enumerate(shards_of(it)):
-                    batch[row, j, : len(s)] = np.frombuffer(
-                        s, dtype=np.uint8)
-                mats[row] = (rs.decode_bitmat_t(k, m, present)
-                             if op == "decode"
-                             else rs.repair_bitmat_t(k, m, present,
-                                                     tuple(it[1])))
-            out = np.asarray(rs.gf_apply_batched(mats, batch))
-            for row, i in enumerate(idxs):
-                sl = slens[row]
-                if op == "decode":
-                    results[i] = rs.join_stripe(out[row, :, :sl],
-                                                items[i][2])
-                else:
-                    results[i] = {
-                        mi: bytes(out[row, j, :sl])
-                        for j, mi in enumerate(tuple(items[i][1]))}
-        return results
-
-    def _do_repair(self, items: list[tuple], backend: str) -> list[dict]:
-        """items = [(present, missing, shards)] -> {missing_index:
-        payload} per item (the resync/scrub rebuild op)."""
-        from ..ops import rs
-
-        codec = self.codec
-        k, m = codec.k, codec.m
-        if backend == "device":
-            return self._device_gf_batched("repair", items)
-        out = []
-        native_mod = self._native_or_none()
-        for present, missing, shards in items:
-            present, missing = tuple(present), tuple(missing)
-            st = np.stack([np.frombuffer(s, dtype=np.uint8)
-                           for s in shards])
-            rows = (native_mod.gf_matmul(
-                        rs.repair_matrix(k, m, present, missing), st)
-                    if native_mod is not None
-                    else rs.repair_np(k, m, present, missing, st))
-            out.append({mi: bytes(rows[j])
-                        for j, mi in enumerate(missing)})
-        return out

@@ -1,9 +1,9 @@
 """Multi-process S3/K2V/web gateway (ISSUE 8; no reference analogue).
 
 One asyncio loop plus the GIL caps a node's frontend throughput
-regardless of how fast the data plane underneath it is (BENCH_r05:
-s3_put 0.16 GB/s vs internal put 0.36 GB/s vs host RS encode
-1.56 GB/s). The standard answer is shared-nothing per-core frontends
+regardless of how fast the data plane underneath it is (a CPU run of
+bench.py before the chip: s3_put 0.16 GB/s vs internal put 0.36 GB/s
+vs host RS encode 1.56 GB/s). The standard answer is shared-nothing per-core frontends
 (Seastar/ScyllaDB thread-per-core; nginx/Envoy `SO_REUSEPORT` worker
 processes), and that is what this package builds:
 

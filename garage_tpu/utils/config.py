@@ -30,11 +30,11 @@ class DataDir:
 @dataclass
 class TpuConfig:
     """TPU data-plane knobs (no reference analogue; README "The TPU
-    data plane"). The feeder routing/trial knobs were hard-coded module
-    constants before the staged pipeline landed; a None leaves the
-    feeder's built-in default in force. inflight_batches /
-    device_min_bytes / device_min_items are also runtime-tunable via
-    admin GET/POST /v1/s3/tuning."""
+    data plane"). Which batches run on the device is not among them:
+    the feeder's route is its mode (`enable`, GARAGE_TPU_DEVICE) and
+    the one device verdict (`platform`), never a size or a rate. A None
+    leaves the feeder's built-in default in force. inflight_batches is
+    also runtime-tunable via admin GET/POST /v1/s3/tuning."""
 
     enable: bool = True
     # max blocks shipped to the device in one encode/hash call (the
@@ -52,21 +52,6 @@ class TpuConfig:
     # buffering, cheaper on device RAM but leaves the transfer engine
     # idle while the batch ahead computes + reads back)
     inflight_batches: int = 3
-    # calibration routing floors: batches below BOTH never leave the
-    # host (a device round trip costs more than it saves there)
-    device_min_bytes: Optional[int] = None  # default 4 MiB
-    device_min_items: Optional[int] = None  # default 4
-    # read-side floors (decode/repair, ISSUE 13): a lone degraded GET
-    # decodes host-inline for latency; only coalesced bursts
-    # (concurrent degraded GETs, scrub/resync rebuild waves) pay a
-    # device trip. Runtime-tunable via admin /v1/s3/tuning.
-    device_min_decode_bytes: Optional[int] = None  # default 4 MiB
-    device_min_decode_items: Optional[int] = None  # default 4
-    # exploration-trial caps: items/bytes sacrificed to re-time the
-    # currently-losing backend (block/feeder.py _trial_cut)
-    trial_max_items: Optional[int] = None   # default 2
-    trial_items_cap: Optional[int] = None   # default 8
-    trial_max_bytes: Optional[int] = None   # default 4 MiB
     # fixed-shape launch buckets: item counts pad up to the next value
     # here so XLA compiles a handful of programs instead of one per
     # batch shape (feeder_pad_waste_bytes / feeder_recompiles track
@@ -89,6 +74,27 @@ class TpuConfig:
     # drain found an empty queue; the linger (still gated on >1 active
     # stream) lets them coalesce into one device launch. 0 disables.
     batch_linger_ms: Optional[float] = None  # default 6.0
+
+
+# [tpu] keys that routed batches by size or by a timed trial, until the
+# route became the mode and the device verdict alone
+_TPU_REMOVED = ("device_min_bytes", "device_min_items",
+                "device_min_decode_bytes", "device_min_decode_items",
+                "trial_max_items", "trial_items_cap", "trial_max_bytes")
+
+
+def _tpu_config(val: dict) -> TpuConfig:
+    """[tpu] from the file; a key it does not have is refused by name."""
+    known = {f.name for f in dataclasses.fields(TpuConfig)}
+    for key in val:
+        if key in _TPU_REMOVED:
+            raise ValueError(
+                f"[tpu] {key}: removed; the feeder no longer routes by "
+                "size or by trial (the route is the mode and the device "
+                "verdict) — delete the key")
+        if key not in known:
+            raise ValueError(f"[tpu] {key}: unknown key")
+    return TpuConfig(**val)
 
 
 @dataclass
@@ -476,7 +482,7 @@ def config_from_dict(raw: dict) -> Config:
         if key == "data_dir":
             cfg.data_dir = _parse_data_dir(val)
         elif key == "tpu" and isinstance(val, dict):
-            cfg.tpu = TpuConfig(**val)
+            cfg.tpu = _tpu_config(val)
         elif key == "qos" and isinstance(val, dict):
             cfg.qos = QosConfig(**val)
         elif key == "chaos" and isinstance(val, dict):

@@ -188,14 +188,10 @@ def test_decode_hang_reruns_host_every_future_resolves(monkeypatch):
     monkeypatch.delenv("GARAGE_TPU_DEVICE", raising=False)
     k, m = 4, 2
     codec = ErasureCodec(k, m, use_jax=False)
-    stub = StubDeviceBackend(None, fixed_s=0.01)
+    stub = StubDeviceBackend(codec, fixed_s=0.01)
     stub.hang_stage = "compute"
     f = DeviceFeeder(codec=codec, mode="auto", max_batch=2,
                      backend=stub)
-    f._device_ok = True
-    f.device_min_decode_items = 1  # 2-item batches take the device route
-    f._record("decode", "device", 1 << 30, 1.0)  # device "winning"
-    f._record("decode", "host", 1 << 20, 1.0)
     f.batch_timeout = 1.0
     rng = np.random.default_rng(31)
     blocks = [rng.integers(0, 256, 20_000 + i, dtype=np.uint8).tobytes()
@@ -304,67 +300,6 @@ def test_deep_scrub_gather_window_bounded():
     assert out == items  # order preserved
     assert peak <= 4, f"window exceeded: {peak}"
     assert peak >= 2  # it did actually run concurrently
-
-
-# ---------------------------------------------------------------------------
-# knobs: [tpu] decode floors flow into the feeder + admin tuning
-# ---------------------------------------------------------------------------
-
-
-def test_decode_knobs_flow_into_feeder_and_tuning():
-    from types import SimpleNamespace
-
-    from garage_tpu.admin.http import apply_s3_tuning, s3_tuning_state
-    from garage_tpu.block.cache import BlockCache
-    from garage_tpu.block import feeder as fmod
-    from garage_tpu.utils.config import Config, config_from_dict
-
-    cfg = config_from_dict({
-        "metadata_dir": "/tmp/x",
-        "tpu": {"device_min_decode_bytes": 2048,
-                "device_min_decode_items": 3},
-    })
-    f = DeviceFeeder(mode="off", tpu_cfg=cfg.tpu)
-    assert f.device_min_decode_bytes == 2048
-    assert f.device_min_decode_items == 3
-    # None leaves the module defaults in force
-    f2 = DeviceFeeder(mode="off")
-    assert f2.device_min_decode_bytes == fmod._DEVICE_MIN_DECODE_BYTES
-    assert f2.device_min_decode_items == fmod._DEVICE_MIN_DECODE_ITEMS
-
-    feeder = DeviceFeeder(mode="off")
-    garage = SimpleNamespace(
-        config=Config(metadata_dir="/tmp/x"),
-        block_manager=SimpleNamespace(cache=BlockCache(1 << 20),
-                                      feeder=feeder))
-    state = apply_s3_tuning(garage, {
-        "feeder_device_min_decode_bytes": 1 << 21,
-        "feeder_device_min_decode_items": 7})
-    assert feeder.device_min_decode_bytes == 1 << 21
-    assert feeder.device_min_decode_items == 7
-    assert state["feeder_device_min_decode_items"] == 7
-    assert s3_tuning_state(garage)["feeder_device_min_decode_bytes"] \
-        == 1 << 21
-
-
-def test_decode_routing_floor_keeps_lone_small_decode_on_host():
-    """A single small decode below both [tpu] device_min_decode_*
-    floors must not pay a device trip even when the device is healthy
-    (auto mode, device winning on calibration data)."""
-    k, m = 4, 2
-    codec = ErasureCodec(k, m, use_jax=False)
-    stub = StubDeviceBackend(None, fixed_s=0.0)
-    f = DeviceFeeder(codec=codec, mode="auto", max_batch=8, backend=stub)
-    f._device_ok = True
-    f._record("decode", "device", 1 << 30, 1.0)  # device hugely winning
-    f._record("decode", "host", 1 << 20, 1.0)
-    backend, trial = f._pick_backend("decode", 4096, 1)
-    assert backend == "host" and trial is False
-    # a coalesced wave above the item floor goes device
-    backend, _ = f._pick_backend(
-        "decode", 4096 * f.device_min_decode_items,
-        f.device_min_decode_items)
-    assert backend == "device"
 
 
 def test_malformed_decode_item_fails_its_caller_only():

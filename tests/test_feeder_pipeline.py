@@ -18,6 +18,7 @@ import time
 import pytest
 
 from garage_tpu.block import feeder as fmod
+from garage_tpu.block import host_legs
 from garage_tpu.block.codec import ErasureCodec
 from garage_tpu.block.device_backend import (StubDeviceBackend,
                                              bucket_items, bucket_len)
@@ -80,16 +81,12 @@ def test_pipeline_overlap_beats_serial_sum():
 
 
 def _auto_feeder_on_stub(monkeypatch, stub, **kw) -> DeviceFeeder:
-    """mode="auto" feeder whose calibration says the (stub) device is
-    winning, so batches of >= 4 items take the device route. conftest
-    exports GARAGE_TPU_DEVICE=off, which would turn auto into off."""
+    """mode="auto" feeder on the stub: the stub says what it is at the
+    first request, the route opens and every queued batch takes it.
+    conftest exports GARAGE_TPU_DEVICE=off, which would turn auto into
+    off."""
     monkeypatch.delenv("GARAGE_TPU_DEVICE", raising=False)
-    f = DeviceFeeder(mode="auto", backend=stub, **kw)
-    f._device_ok = True
-    for op in ("hash", "decode"):
-        f._record(op, "device", 1 << 30, 1.0)
-        f._record(op, "host", 1 << 20, 1.0)
-    return f
+    return DeviceFeeder(mode="auto", backend=stub, **kw)
 
 
 def test_pipeline_hang_reruns_all_inflight_host_side(monkeypatch):
@@ -130,17 +127,17 @@ def test_pipeline_hang_reruns_all_inflight_host_side(monkeypatch):
 
 
 class _HostLegSpy:
-    """Counts host-side op executions of a feeder."""
+    """Counts the op groups a feeder runs on the host."""
 
     def __init__(self, f: DeviceFeeder):
         self.calls = 0
-        real = f._do_op
+        real = f._exec_group
 
-        def spy(op, blobs, backend):
+        def spy(*a):
             self.calls += 1
-            return real(op, blobs, backend)
+            return real(*a)
 
-        f._do_op = spy
+        f._exec_group = spy
 
 
 class _RaisingStub(StubDeviceBackend):
@@ -289,7 +286,6 @@ def test_hash_md5_hang_fallback_advances_etag_exactly_once(monkeypatch):
     stub = StubDeviceBackend(None, fixed_s=0.01)
     stub.hang_stage = "compute"
     f = _auto_feeder_on_stub(monkeypatch, stub, max_batch=2)
-    f.device_min_items = 1  # 2-item batches must take the device route
     f.batch_timeout = 1.0
     f.active_streams = 4
     blobs = [os.urandom(4096) for _ in range(4)]
@@ -338,6 +334,151 @@ def test_stop_with_inflight_batches_resolves_every_future():
 
 
 # ---------------------------------------------------------------------------
+# the route: a function of the mode and the one device verdict
+# ---------------------------------------------------------------------------
+
+
+class _FakeDevice(StubDeviceBackend):
+    """A device that is asked: its verdict names `platform`, or nothing
+    answers. (The stub proper is accepted by name and never asked.)"""
+
+    name = "fake"
+
+    def __init__(self, platform):
+        super().__init__(None, h2d_gbps=1e6, compute_gbps=1e6, d2h_gbps=1e6)
+        self.platform = platform
+
+    def verdict(self) -> dict:
+        if self.platform is None:
+            raise RuntimeError("no chip here (injected)")
+        return {"platform": self.platform, "device_kind": "fake", "count": 1}
+
+
+_WRONG = "platform 'cpu' found where 'tpu' is required"
+_NONE = "no device answered (RuntimeError: no chip here (injected))"
+
+
+@pytest.mark.parametrize("mode,platform,route,reason,where", [
+    ("off", "tpu", "host", "mode off", "host"),
+    ("require", "tpu", "device", "1 x fake (tpu)", "device"),
+    ("require", "cpu", "refused", _WRONG, "nowhere"),
+    ("require", None, "refused", _NONE, "nowhere"),
+    ("auto", "tpu", "device", "1 x fake (tpu)", "device"),
+    ("auto", "cpu", "host", _WRONG, "host"),
+    ("auto", None, "host", _NONE, "host"),
+])
+def test_route_is_mode_and_verdict(monkeypatch, mode, platform, route,
+                                   reason, where):
+    """The whole routing rule: nothing but the mode and what the device
+    said decides where a batch runs — a two-item batch and a lone item
+    go the same way."""
+    from garage_tpu import native
+
+    monkeypatch.delenv("GARAGE_TPU_DEVICE", raising=False)
+    dev = _FakeDevice(platform)
+    f = DeviceFeeder(mode=mode, backend=dev, max_batch=8)
+    blobs = [os.urandom(4096) for _ in range(4)]
+
+    async def go():
+        first = await asyncio.gather(f.hash(blobs[0]),
+                                     return_exceptions=True)
+        if mode == "auto":
+            # the first batch asked in the background and ran host-side
+            assert f.stats["device_items"] == 0
+            await f._verdict_task
+        before = dict(f.stats)
+        pair = await asyncio.gather(f.hash(blobs[1]), f.hash(blobs[2]),
+                                    return_exceptions=True)
+        lone = await asyncio.gather(f.hash(blobs[3]),
+                                    return_exceptions=True)
+        await f.stop()
+        return first + pair + lone, before
+
+    res, before = run(go())
+    assert (f.route, f.route_reason) == (route, reason)
+    on_device = f.stats["device_items"] - before["device_items"]
+    inline = f.stats["inline_items"] - before["inline_items"]
+    queued = f.stats["items"] - before["items"]
+    if where == "nowhere":
+        assert all(isinstance(r, RuntimeError)
+                   and f"device required but {reason}" in str(r)
+                   for r in res), res
+        assert (on_device, inline, queued) == (0, 0, 0)
+        return
+    assert res == [blake3sum(b) for b in blobs]
+    if where == "device":
+        assert (on_device, inline, queued) == (3, 0, 3)
+    elif native.loaded():
+        # the host for good: the queue hop is skipped
+        assert (on_device, inline, queued) == (0, 3, 0)
+    else:
+        assert (on_device, inline, queued) == (0, 0, 3)
+    if mode == "off":
+        assert f.device_info is None and f._backend is None  # never asked
+
+
+def test_auto_on_stub_sends_lone_small_items_to_the_device(monkeypatch):
+    """mode="auto" with the route open: a lone 4 KiB decode and a lone
+    hash take the device route — no floor keeps a small batch on the
+    host."""
+    import numpy as np
+
+    from garage_tpu.ops import rs
+
+    k, m = 4, 2
+    codec = ErasureCodec(k, m, use_jax=False)
+    f = _auto_feeder_on_stub(
+        monkeypatch, StubDeviceBackend(codec, fixed_s=0.0), codec=codec)
+    block = os.urandom(4096)
+    stripe = codec.encode(block)
+    present = (1, 2, 3, 4)  # degraded: shard 0 lost
+
+    async def go():
+        out = await f.decode(present, [stripe[i] for i in present],
+                             len(block))
+        dig = await f.hash(block)
+        await f.stop()
+        return out, dig
+
+    out, dig = run(go())
+    assert out == block and dig == blake3sum(block)
+    assert f.route == "device"
+    assert f.stats["decode_device_items"] == 1
+    assert f.device_items_by_op == {"decode": 1, "hash": 1}
+    assert f.stats["inline_items"] == 0 and f.stats["host_reruns"] == 0
+    st = np.stack([np.frombuffer(s, dtype=np.uint8) for s in stripe])
+    assert out == rs.join_stripe(
+        rs.decode_np(k, m, present, st[list(present)]), len(block))
+
+
+def test_stub_backend_needs_no_feeder():
+    """The stub computes with block/host_legs.py and its own codec: no
+    back-pointer to a feeder, and its three stages run without one."""
+    codec = ErasureCodec(4, 2, use_jax=False)
+    stub = StubDeviceBackend(codec, h2d_gbps=1e6, compute_gbps=1e6,
+                             d2h_gbps=1e6)
+    assert not hasattr(stub, "feeder")
+    block = os.urandom(10_000)
+    stripe = codec.encode(block)
+    present = (0, 2, 3, 5)
+    cases = [
+        ("hash", [block]),
+        ("verify", [(blake3sum(block), block), (b"\x00" * 32, block)]),
+        ("sha256", [block]),
+        ("encode", [block]),
+        ("encode_put", [(b"\x00", block)]),
+        ("parity_check", [stripe]),
+        ("decode", [(present, [stripe[i] for i in present], len(block))]),
+        ("repair", [(present, (1, 4), [stripe[i] for i in present])]),
+    ]
+    for op, blobs in cases:
+        got = stub.readback(op, stub.compute(op, stub.stage(op, blobs)))
+        assert got == host_legs.run(codec, op, blobs), op
+    assert stub.readback("repair", stub.compute("repair", stub.stage(
+        "repair", cases[-1][1]))) == [{1: stripe[1], 4: stripe[4]}]
+
+
+# ---------------------------------------------------------------------------
 # fixed-shape padded launches (jax backend on the cpu "device")
 # ---------------------------------------------------------------------------
 
@@ -383,7 +524,7 @@ def test_padded_launches_correct_and_shape_stable():
         batch = [_Item("encode_put", it, asyncio.get_running_loop()
                        .create_future()) for it in items(5, 65536)]
         res = await f._run_batch_staged(batch)
-        host = f._do_encode_put([it.data for it in batch], "host")
+        host = host_legs.encode_put(codec, [it.data for it in batch])
         for pa, pb in zip(res, host):
             for sa, sb in zip(pa, pb):
                 da, la = unpack_shard(bytes(sa))
@@ -397,7 +538,7 @@ def test_padded_launches_correct_and_shape_stable():
         batch2 = [_Item("encode_put", it, asyncio.get_running_loop()
                         .create_future()) for it in items(6, 65536)]
         res2 = await f._run_batch_staged(batch2)
-        host2 = f._do_encode_put([it.data for it in batch2], "host")
+        host2 = host_legs.encode_put(codec, [it.data for it in batch2])
         for pa, pb in zip(res2, host2):
             for sa, sb in zip(pa, pb):
                 da, la = unpack_shard(bytes(sa))
@@ -470,7 +611,7 @@ def test_mesh_sharded_encode_matches_host():
         batch = [_Item("encode", b, asyncio.get_running_loop()
                        .create_future()) for b in blocks]
         res = await f._run_batch_staged(batch)
-        host = f._do_encode(blocks, "host")
+        host = host_legs.encode(codec, blocks)
         for a, b in zip(res, host):
             assert [bytes(x) for x in a] == [bytes(x) for x in b]
         assert f.stats["mesh_batches"] >= 1
@@ -519,32 +660,32 @@ def test_tpu_config_knobs_flow_into_feeder():
 
     cfg = config_from_dict({
         "metadata_dir": "/tmp/x",
-        "tpu": {"inflight_batches": 3, "device_min_bytes": 1024,
-                "device_min_items": 2, "pad_buckets": [2, 4],
+        "tpu": {"inflight_batches": 2, "pad_buckets": [2, 4],
                 "mesh_min_items": 5, "device_backend": "stub",
-                "trial_max_items": 1, "trial_items_cap": 4,
-                "trial_max_bytes": 123, "batch_timeout_s": 7.5},
+                "batch_timeout_s": 7.5, "batch_linger_ms": 2.5,
+                "platform": "cpu", "batch_blocks": 64},
     })
-    f = DeviceFeeder(mode="off", tpu_cfg=cfg.tpu)
-    assert f.inflight_batches == 3
-    assert f.device_min_bytes == 1024
-    assert f.device_min_items == 2
+    f = DeviceFeeder(mode="off", tpu_cfg=cfg.tpu,
+                     max_batch=cfg.tpu.batch_blocks)
+    assert f.inflight_batches == 2
     assert f.pad_buckets == (2, 4)
     assert f.mesh_min_items == 5
-    assert f.trial_max_items == 1
-    assert f.trial_items_cap == 4
-    assert f.trial_max_bytes == 123
     assert f.batch_timeout == 7.5
+    assert f.batch_linger == 0.0025
+    assert f.platform == "cpu"
+    assert f.max_batch == 64
     assert f._backend_is_stub()
     # None fields leave the feeder defaults in force
     f2 = DeviceFeeder(mode="off")
-    assert f2.device_min_bytes == fmod._DEVICE_MIN_BYTES
+    assert f2.inflight_batches == 3
     assert f2.batch_timeout == fmod._BATCH_TIMEOUT
+    assert f2.platform == fmod._DEVICE_PLATFORM
 
 
 def test_s3_tuning_feeder_knobs():
-    """The admin /v1/s3/tuning surface tunes the live feeder: depth and
-    routing floors apply, the state echoes them, bad values 400."""
+    """The admin /v1/s3/tuning surface tunes the live feeder: the depth
+    applies, the state echoes it, bad values 400 — and so do the four
+    routing floors that went with the router, each by name."""
     from types import SimpleNamespace
 
     from garage_tpu.admin.http import apply_s3_tuning, s3_tuning_state
@@ -557,19 +698,22 @@ def test_s3_tuning_feeder_knobs():
         config=Config(metadata_dir="/tmp/x"),
         block_manager=SimpleNamespace(cache=BlockCache(1 << 20),
                                       feeder=feeder))
-    state = apply_s3_tuning(garage, {"feeder_inflight_batches": 4,
-                                     "feeder_device_min_bytes": 1 << 20,
-                                     "feeder_device_min_items": 7})
+    state = apply_s3_tuning(garage, {"feeder_inflight_batches": 4})
     assert feeder.inflight_batches == 4
-    assert feeder.device_min_bytes == 1 << 20
-    assert feeder.device_min_items == 7
     assert state["feeder_inflight_batches"] == 4
     assert "feeder_pipeline" in state
-    assert s3_tuning_state(garage)["feeder_device_min_items"] == 7
+    assert s3_tuning_state(garage)["feeder_inflight_batches"] == 4
     with pytest.raises(BadRequest):
         apply_s3_tuning(garage, {"feeder_inflight_batches": 0})
     with pytest.raises(BadRequest):
         apply_s3_tuning(garage, {"feeder_bogus": 1})
+    for gone in ("feeder_device_min_bytes", "feeder_device_min_items",
+                 "feeder_device_min_decode_bytes",
+                 "feeder_device_min_decode_items"):
+        with pytest.raises(BadRequest, match="unknown s3 tuning knob"):
+            apply_s3_tuning(garage, {gone: 1,
+                                     "feeder_inflight_batches": 9})
+        assert gone not in s3_tuning_state(garage)
     # a rejected spec must not have half-applied
     assert feeder.inflight_batches == 4
 

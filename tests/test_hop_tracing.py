@@ -57,10 +57,10 @@ def delta(before: tuple, name: str, **match) -> tuple[int, float]:
 def stub_feeder(fixed_s: float = 0.0, **kw) -> DeviceFeeder:
     """mode "require" on the stub: every queued item takes the staged
     device route, one launch per op group."""
-    stub = StubDeviceBackend(None, h2d_gbps=1e6, compute_gbps=1e6,
+    codec = ErasureCodec(4, 2, use_jax=False)
+    stub = StubDeviceBackend(codec, h2d_gbps=1e6, compute_gbps=1e6,
                              d2h_gbps=1e6, fixed_s=fixed_s)
-    return DeviceFeeder(codec=ErasureCodec(4, 2, use_jax=False),
-                        mode="require", backend=stub, **kw)
+    return DeviceFeeder(codec=codec, mode="require", backend=stub, **kw)
 
 
 def by_name(recs, name: str) -> list[dict]:

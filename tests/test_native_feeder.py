@@ -253,19 +253,37 @@ def test_rs_encode_packed_matches_reference(k, m, dlen):
         assert bytes(p) == pack_shard(ref.tobytes(), len(block))
 
 
+def _staged_feeder(codec, **kw) -> DeviceFeeder:
+    """mode "require" with the CPU standing in for the chip: every
+    queued item takes the staged device route (JAX backend)."""
+    from garage_tpu.utils.config import TpuConfig
+
+    return DeviceFeeder(codec=codec, mode="require",
+                        tpu_cfg=TpuConfig(platform="cpu"), **kw)
+
+
 def test_encode_put_backends_agree():
-    """_do_encode_put host-native, host-numpy and device paths must emit
-    interchangeable payloads (same shard bytes after unpack)."""
+    """The host leg (native, or numpy fallback) and the staged device
+    route must emit interchangeable encode_put payloads (same shard
+    bytes after unpack)."""
+    from garage_tpu.block import host_legs
     from garage_tpu.block.codec import ErasureCodec
     from garage_tpu.block.manager import unpack_shard
 
     codec = ErasureCodec(4, 2, use_jax=False)
-    f = DeviceFeeder(codec=codec, mode="off")
+    f = _staged_feeder(codec)
     rng = np.random.default_rng(3)
     items = [(b"\x00", rng.integers(0, 256, n, dtype=np.uint8).tobytes())
              for n in (100, 65536, (1 << 20) + 5)]
-    a = f._do_encode_put(items, "host")   # native (or numpy fallback)
-    b = f._do_encode_put(items, "device")  # codec.encode_batch path
+    a = host_legs.encode_put(codec, items)
+
+    async def go():
+        out = await asyncio.gather(*[f.encode_put(d, p) for p, d in items])
+        await f.stop()
+        return out
+
+    b = run(go())
+    assert f.stats["device_items"] == len(items)
     for pa, pb in zip(a, b):
         for sa, sb in zip(pa, pb):
             da, la = unpack_shard(bytes(sa))
@@ -491,9 +509,6 @@ def test_feeder_hash_md5_device_failure_fallback_etag_correct(monkeypatch):
         monkeypatch.delenv("GARAGE_TPU_DEVICE", raising=False)
         f = DeviceFeeder(mode="auto", backend=_BrokenBackend())
         f._device_ok = True  # fake device above: no verdict to take
-        f.device_min_items = 1
-        f._record("hash", "device", 1 << 30, 1.0)  # device "winning"
-        f._record("hash", "host", 1 << 20, 1.0)
         f.active_streams = 2
         accs = [native.Md5(), native.Md5()]
         refs = [hashlib.md5(), hashlib.md5()]
@@ -511,72 +526,6 @@ def test_feeder_hash_md5_device_failure_fallback_etag_correct(monkeypatch):
         await f.stop()
 
     run(go())
-
-
-def test_feeder_explore_trial_capped_and_adaptive():
-    """Exploration of the losing backend is (a) capped to
-    _TRIAL_MAX_ITEMS per trial — a re-try needs one timing sample, not
-    a full production batch — and (b) scheduled on an interval that
-    widens with the measured rate gap, so a 500x-slower device is
-    re-tried ~hourly, not every minute."""
-    import time as _time
-
-    from garage_tpu.block import feeder as fmod
-    from garage_tpu.block.codec import ErasureCodec
-
-    f = DeviceFeeder(codec=ErasureCodec(4, 2, use_jax=False), mode="auto")
-    f._device_ok = True
-    # seed calibration: host hugely winning (a 512x gap)
-    f._record("encode", "host", 1 << 30, 1.0)     # 1 GB/s
-    f._record("encode", "device", 1 << 21, 1.0)   # 2 MB/s
-    f._last_explore["encode"] = _time.monotonic()
-
-    # (b) adaptive interval: a 512x gap stretches the 60 s base cadence
-    # to its 64x cap, so one base interval later no trial fires
-    f._last_explore["encode"] = _time.monotonic() - 2 * fmod._EXPLORE_SECS
-    assert f._explore_due("encode") is False
-    # far past the stretched interval the trial fires
-    f._last_explore["encode"] = (
-        _time.monotonic() - 65 * fmod._EXPLORE_SECS)
-    backend, trial = f._pick_backend("encode", 8 << 20, 8)
-    assert (backend, trial) == ("device", True)
-
-    # (a) the trial slice is capped: run a batch through _run_batch
-    # with the device leg stubbed, and count what each backend saw
-    seen = {"device": 0, "host": 0}
-    real = f._do_op
-
-    def spy(op, blobs, backend):
-        seen[backend] += len(blobs)
-        return real(op, blobs, "host")  # no real device in unit tests
-
-    f._do_op = spy
-    blk = os.urandom(1 << 20)  # 1 MiB items: the byte-aware cut engages
-
-    class It:
-        def __init__(self):
-            self.op = "encode_put"
-            self.data = (b"", blk)
-            self.future = asyncio.get_event_loop().create_future()
-
-    async def go():
-        f._last_explore["encode"] = (
-            _time.monotonic() - 65 * fmod._EXPLORE_SECS)
-        items = [It() for _ in range(8)]
-        f._run_batch(items)
-
-    run(go())
-    # trial grows past _TRIAL_MAX_ITEMS until _TRIAL_MAX_BYTES: 4x1 MiB
-    want = fmod._TRIAL_MAX_BYTES >> 20
-    assert seen["device"] == want
-    assert seen["host"] == 8 - want
-
-    # a DEAD device (0.0 recorded rate) is the widest gap: the adaptive
-    # interval jumps straight to the 64x cap, not the 60 s base
-    f._record("encode", "device", 0, 60.0)
-    f._perf[("encode", "device")] = [0.0, 60.0]
-    f._last_explore["encode"] = _time.monotonic() - 2 * fmod._EXPLORE_SECS
-    assert f._explore_due("encode") is False
 
 
 def _watch_tempdir(monkeypatch):
@@ -701,14 +650,16 @@ def test_try_pallas_does_not_swallow_a_compile_failure(monkeypatch):
 
 
 def test_parity_check_backends_agree_and_detect():
-    """_do_parity_check host (native/numpy) and device (padded jax
-    batch) agree, and both flag a stripe with one corrupted shard —
+    """The host leg (native/numpy) and the staged device route (padded
+    jax batch) agree, and both flag a stripe with one corrupted shard —
     mixed shard lengths in one batch exercise the zero-padding rule
     (linear code: zero rows encode to zero parity)."""
+    from garage_tpu.block import host_legs
     from garage_tpu.block.codec import ErasureCodec
 
     codec = ErasureCodec(4, 2, use_jax=False)
     f = DeviceFeeder(codec=codec, mode="off")
+    staged = _staged_feeder(codec)
     rng = np.random.default_rng(5)
     stripes = []
     for n in (1024, 65536, 100_000):
@@ -718,11 +669,13 @@ def test_parity_check_backends_agree_and_detect():
     s[2] = bytes(b ^ 1 for b in s[2])
     stripes[1] = s
     want = [True, False, True]
-    assert f._do_parity_check(stripes, "host") == want
-    assert f._do_parity_check(stripes, "device") == want
+    assert host_legs.parity_check(codec, stripes) == want
 
     async def go():
         assert await f.parity_check(stripes) == want
+        assert await staged.parity_check(stripes) == want
+        assert staged.stats["device_items"] == len(stripes)
         await f.stop()
+        await staged.stop()
 
     run(go())

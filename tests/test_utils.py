@@ -63,6 +63,26 @@ data_dir = [
     assert cfg.data_dir[1].capacity == 5 * 10**11
 
 
+@pytest.mark.parametrize("key", [
+    "device_min_bytes", "device_min_items", "device_min_decode_bytes",
+    "device_min_decode_items", "trial_max_items", "trial_items_cap",
+    "trial_max_bytes", "batch_blocs"])
+def test_config_refuses_a_tpu_key_it_does_not_have_by_name(tmp_path, key):
+    """A [tpu] key the section lacks — the seven routing knobs that went
+    with the feeder's router, or a typo — is refused with the section
+    and the key named, not with a bare TypeError."""
+    says = ("unknown key" if key == "batch_blocs"
+            else "no longer routes by size or by trial")
+    p = tmp_path / "g.toml"
+    p.write_text(f'metadata_dir = "/tmp/meta"\n\n[tpu]\n'
+                 f'batch_blocks = 8\n{key} = 4\n')
+    with pytest.raises(ValueError) as e:
+        config.read_config(str(p))
+    assert f"[tpu] {key}" in str(e.value) and says in str(e.value)
+    assert key not in {f.name for f in
+                       config.dataclasses.fields(config.TpuConfig)}
+
+
 class PVal(migrate.Migratable):
     VERSION_MARKER = b"GTpv1"
 
