@@ -1059,10 +1059,18 @@ class BlockManager:
         rest on failure). -> (parts, packed_len candidates ranked by
         vote count majority first, per-index header packed_len) or
         None. The per-index map lets deep scrub see WHICH holder's
-        header disagrees with the majority (header rot repair)."""
+        header disagrees with the majority (header rot repair).
+
+        With no more holders up than `need` (m down) there is nobody
+        else to ask, so every fetch keeps its flat timeout
+        (RpcHelper.has_spare): a holder that is silent for a second
+        answers a second late instead of being cut."""
         me = self.system.id
+        adaptive_timeout = self.rpc.has_spare(placement, need)
+        warned = False
 
         async def fetch(node, idx):
+            nonlocal warned
             try:
                 if node == me:
                     # off the event loop: deep scrub drives MiB-scale
@@ -1078,11 +1086,12 @@ class BlockManager:
                 # self.rpc.call (not endpoint.call): the helper records
                 # per-peer health and applies the adaptive timeout, so
                 # a hung holder stops costing the full flat timeout
-                # once its p99 is known
+                # once its p99 is known and another can be asked
                 resp = await self.rpc.call(
                     self.endpoint, node,
                     {"op": "get", "hash": hash32, "part": idx},
                     PRIO_NORMAL, timeout=60.0,
+                    adaptive_timeout=adaptive_timeout,
                 )
                 if resp.get("data") is None:
                     return None
@@ -1092,8 +1101,15 @@ class BlockManager:
                 # than a peer fetch failing; don't conflate them
                 registry().inc("block_shard_fetch_errors",
                                source="local" if node == me else "remote")
-                log.debug("shard fetch part=%d from %s failed: %s",
-                          idx, node[:4].hex(), e)
+                # a holder known to be down refuses at once, by the
+                # hundred a second while a zone is out; an error from
+                # a holder that is up is news, once a gather
+                news = not warned and self.system.is_up(node)
+                warned = warned or news
+                log.log(logging.WARNING if news else logging.DEBUG,
+                        "block %s: shard fetch part=%d from %s failed: "
+                        "%s: %s", hash32[:4].hex(), idx, node[:4].hex(),
+                        type(e).__name__, e)
                 return None
 
         race = HedgedRace(self.rpc.health(), "block_get_shard")

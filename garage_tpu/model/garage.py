@@ -219,7 +219,6 @@ class Garage:
         self.system.peering.health.configure(
             hedging=config.rpc_hedging,
             hedge_rate=config.rpc_hedge_rate,
-            adaptive_timeout=config.rpc_adaptive_timeout,
             write_hedging=config.rpc_hedge_writes,
         )
 

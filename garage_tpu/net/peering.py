@@ -42,7 +42,8 @@ CONN_MAX_RETRIES = 10
 # and three consumers read it back —
 #   * request_order deprioritizes peers whose circuit breaker is open,
 #   * per-call timeouts derive from the peer's observed p99 instead of
-#     the flat 30 s default,
+#     the flat 30 s default, while another live node could be asked in
+#     the peer's place (RpcHelper.has_spare),
 #   * hedged reads fire a backup request after the peer's observed p95
 #     instead of waiting for an error.
 
@@ -105,7 +106,6 @@ class PeerHealthTracker:
         # `[rpc] hedge_writes` knob — writes additionally need an
         # explicit per-call hedge=True opt-in, audited by GL02
         self.write_hedging_enabled = True
-        self.adaptive_timeout_enabled = True
         self.hedge_rate = 8.0  # sustained hedges/s across all calls
         self._hedge_tokens = HEDGE_BUCKET_CAP
         self._hedge_t = time.monotonic()
@@ -116,14 +116,11 @@ class PeerHealthTracker:
 
     def configure(self, hedging: Optional[bool] = None,
                   hedge_rate: Optional[float] = None,
-                  adaptive_timeout: Optional[bool] = None,
                   write_hedging: Optional[bool] = None) -> None:
         if hedging is not None:
             self.hedging_enabled = bool(hedging)
         if hedge_rate is not None:
             self.hedge_rate = max(0.0, float(hedge_rate))
-        if adaptive_timeout is not None:
-            self.adaptive_timeout_enabled = bool(adaptive_timeout)
         if write_hedging is not None:
             self.write_hedging_enabled = bool(write_hedging)
 
@@ -237,7 +234,7 @@ class PeerHealthTracker:
         """Adaptive per-call timeout: clamp(p99 * 4) once the peer has
         enough samples; the caller's flat value is both the default and
         the ceiling (adaptation only ever tightens)."""
-        if flat is None or not self.adaptive_timeout_enabled:
+        if flat is None:
             return flat
         p = self.peers.get(node)
         if p is None or p.samples < HEALTH_MIN_SAMPLES:
@@ -290,7 +287,6 @@ class PeerHealthTracker:
             "breaker_closes": self.breaker_closes,
             "hedging_enabled": self.hedging_enabled,
             "write_hedging_enabled": self.write_hedging_enabled,
-            "adaptive_timeout_enabled": self.adaptive_timeout_enabled,
         }
 
     def peer_state(self) -> dict:

@@ -283,6 +283,17 @@ class RpcHelper:
         peering = getattr(self.system, "peering", None)
         return getattr(peering, "health", None)
 
+    def has_spare(self, nodes: list[bytes], need: int) -> bool:
+        """Could a call to one of `nodes` be made to another instead?
+        True while more of them are up than the caller needs answers
+        (a `system` without `is_up`, as bare test stubs have, counts as
+        spare). The one rule for when a call may be cut short: a
+        peer's tightened timeout is right while somebody else can be
+        asked, and turns a late answer into a lost one when nobody
+        can."""
+        is_up = getattr(self.system, "is_up", None)
+        return is_up is None or sum(map(is_up, nodes)) > need
+
     # ---- node ordering (ref: rpc_helper.rs:621-660) --------------------
 
     def request_order(self, nodes: list[bytes]) -> list[bytes]:
@@ -337,14 +348,18 @@ class RpcHelper:
         prio: int,
         timeout: Optional[float],
         stream=None,
+        adaptive_timeout: bool = True,
     ):
         """endpoint.call with the self-healing bookkeeping: adaptive
         per-peer timeout, half-open probe accounting, success/failure
         recording, and peer+endpoint-named errors. Returns the raw
-        (resp, reply_stream) pair."""
+        (resp, reply_stream) pair. `adaptive_timeout=False` keeps the
+        caller's flat `timeout`: for a caller that has nobody else to
+        ask (has_spare)."""
         health = self.health()
         if health is not None:
-            timeout = health.call_timeout(node, timeout)
+            if adaptive_timeout:
+                timeout = health.call_timeout(node, timeout)
             health.note_launch(node)
         t0 = time.monotonic()
         try:
@@ -370,9 +385,11 @@ class RpcHelper:
         prio: int = PRIO_NORMAL,
         timeout: float = DEFAULT_TIMEOUT,
         stream=None,
+        adaptive_timeout: bool = True,
     ):
         resp, rstream = await self._tracked_call(
-            endpoint, node, payload, prio, timeout, stream=stream
+            endpoint, node, payload, prio, timeout, stream=stream,
+            adaptive_timeout=adaptive_timeout,
         )
         return (resp, rstream) if rstream is not None else resp
 
@@ -404,6 +421,9 @@ class RpcHelper:
         if quorum > len(nodes):
             raise QuorumError(quorum, 1, 0, len(nodes), ["not enough nodes"])
         order = self.request_order(list(nodes))
+        # one copy of three dead and two wanted: a call cut at its
+        # peer's tightened timeout would be the quorum lost
+        adaptive_timeout = self.has_spare(nodes, quorum)
         race = HedgedRace(
             self.health(), endpoint.path,
             enabled=(False if strategy.send_all_at_once
@@ -418,7 +438,8 @@ class RpcHelper:
             next_i += 1
             pl = make_payload(node) if make_payload else payload
             race.launch(node, self._tracked_call(
-                endpoint, node, pl, strategy.prio, strategy.timeout),
+                endpoint, node, pl, strategy.prio, strategy.timeout,
+                adaptive_timeout=adaptive_timeout),
                 hedged)
 
         n_initial = len(order) if strategy.send_all_at_once else min(quorum, len(order))

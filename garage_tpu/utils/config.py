@@ -255,15 +255,14 @@ class Config:
     rpc_public_addr: Optional[str] = None
     # [rpc] self-healing knobs (rpc/rpc_helper.py + net/peering.py
     # PeerHealthTracker; README "Fault injection & self-healing RPC"):
-    # hedged reads on/off, the cluster-wide hedge rate cap (token
-    # bucket, hedges/s), and p99-derived adaptive per-call timeouts
+    # hedged reads on/off and the cluster-wide hedge rate cap (token
+    # bucket, hedges/s)
     rpc_hedging: bool = True
     rpc_hedge_rate: float = 8.0
     # [rpc] hedge_writes: backup pushes for IDEMPOTENT writes that
     # opted in per-call (erasure shard puts; README "Cluster resize").
     # Off = writes never hedge, regardless of per-call opt-ins.
     rpc_hedge_writes: bool = True
-    rpc_adaptive_timeout: bool = True
     # [rpc] layout_debounce_ms: coalescing window for layout gossip
     # broadcasts (rpc/layout/manager.py). Every tracker tick during a
     # resize fires a change; broadcasting each one is an O(N^2) gossip

@@ -163,8 +163,6 @@ def test_adaptive_timeout_clamps_and_preserves_flat_default():
     assert 4.0 <= ht.call_timeout(A, 30.0) <= 8.0
     # the flat value is a ceiling, adaptation never grows past it
     assert ht.call_timeout(A, 3.0) == 3.0
-    ht.adaptive_timeout_enabled = False
-    assert ht.call_timeout(A, 30.0) == 30.0
 
 
 def test_hedge_delay_and_rate_cap():
