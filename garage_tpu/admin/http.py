@@ -1108,6 +1108,9 @@ class AdminHttpServer:
               "Decode/repair items that ran on the device path (the "
               "read-side engagement proof metric)")
         gauge("feeder_decode_device_bytes", fs["decode_device_bytes"])
+        gauge("rs_decode_patterns", fs["decode_patterns"],
+              "Erasure patterns whose decode matrix the process keeps "
+              "(one 8k x 8k expansion each, never evicted)")
         for op, n in sorted(feeder.device_items_by_op.items()):
             gauge("feeder_device_op_items", n, op=op)
         gauge("feeder_device_errors", fs["device_errors"],

@@ -205,7 +205,11 @@ class DeviceFeeder:
                       # ran on the device path (the degraded-GET /
                       # rebuild twin of device_items)
                       "decode_items": 0, "decode_device_items": 0,
-                      "decode_device_bytes": 0}
+                      "decode_device_bytes": 0,
+                      # present-sets whose decode matrix this process
+                      # keeps (ops/rs.py decode_bitmat_t's cache), as of
+                      # the last decode leg staged
+                      "decode_patterns": 0}
         # staged pipeline state: the current executor generation, the
         # batches in flight, per-stage busy seconds and the wall-clock
         # union of windows with >= 1 device leg in flight (overlap
@@ -338,41 +342,64 @@ class DeviceFeeder:
         else:
             self._judge(info)
 
-    async def warm_put_programs(self, block_len: int, lease=None,
-                                max_items: int = 1) -> None:
-        """Launch once, for every item bucket up to `max_items`, what a
-        PUT of full `block_len`-byte blocks launches: the content hash
-        and, given a full ingest `lease`, the all-lease RS encode leg.
-        A node that serves from its device then meets no program for
-        the first time inside a request: they are built (or loaded from
-        the compile cache) here, at boot, on the stage threads and
-        under the batch watchdog like any leg. Results are thrown away
-        and no item is counted; a failure is the caller's to raise."""
+    async def warm_programs(self, block_len: int, lease=None,
+                            put_items: int = 1,
+                            decode_items: int = 0) -> None:
+        """Launch once, for every item bucket, what full
+        `block_len`-byte blocks launch on this node: up to `put_items`
+        the content hash and, given a full ingest `lease`, the all-lease
+        RS encode leg; up to `decode_items`, with a codec, the decode
+        leg of a degraded GET (k zero shards of a full block's shard
+        length — the present-set is data to the kernel, so any one
+        stands for all) and, at one item, the repair leg of one missing
+        shard, which is what resync launches when a straggler of a
+        quorum write never landed. A node that serves from its device
+        then meets no program for the first time inside a request: they
+        are built (or loaded from the compile cache) here, at boot, on
+        the stage threads and under the batch watchdog like any leg. A
+        wave of rebuilds, or a stripe missing several shards, still
+        builds its program when it comes. Results are thrown away and no
+        item is counted; a failure is the caller's to raise."""
         await self.device_verdict()
         if not self._device_ok or self._backend_is_stub():
             return
         from ..ops import jaxenv
         from ..utils import data as _data
+        from .hostbuf import stripe_shard_len
 
-        t0, before = time.perf_counter(), jaxenv.compile_stats()
-        zero = bytes(block_len)
-        n = 1  # the fewest items that launch the next bucket
-        while n <= max_items:
-            legs = []
-            if _data._content_algo == "blake3":  # blake2 never leaves the host
-                legs.append(("hash", [zero] * n))
-            if lease is not None and self.codec is not None:
-                legs.append(("encode_put", [lease] * n))
-            for op, blobs in legs:
-                await asyncio.wait_for(self._staged_op(op, blobs),
-                                       self.batch_timeout)
-            n = bucket_items(n, self.pad_buckets) + 1
-        after = jaxenv.compile_stats()
-        log.info("feeder: PUT programs of %d-byte blocks warm to %d items "
-                 "in %.1f s (%d requested, %d built)", block_len, max_items,
-                 time.perf_counter() - t0,
-                 after["compile_requests"] - before["compile_requests"],
-                 after["compiles"] - before["compiles"])
+        put_legs = []
+        if _data._content_algo == "blake3":  # blake2 never leaves the host
+            put_legs.append(("hash", bytes(block_len)))
+        if lease is not None and self.codec is not None:
+            put_legs.append(("encode_put", lease))
+        decode_legs, repair_legs = [], []
+        if self.codec is not None and decode_items > 0:
+            k = self.codec.k
+            first_k = tuple(range(k))
+            shards = [bytes(stripe_shard_len(1 + block_len, k))] * k
+            decode_legs.append(("decode", (first_k, shards, 1 + block_len)))
+            if self.codec.m > 0:
+                repair_legs.append(("repair", (first_k, (k,), shards)))
+        said = []
+        for what, legs, max_items in (("PUT", put_legs, put_items),
+                                      ("decode", decode_legs, decode_items),
+                                      ("repair", repair_legs, 1)):
+            if not legs:
+                continue
+            t0, before = time.perf_counter(), jaxenv.compile_stats()
+            n = 1  # the fewest items that launch the next bucket
+            while n <= max_items:
+                for op, item in legs:
+                    await asyncio.wait_for(self._staged_op(op, [item] * n),
+                                           self.batch_timeout)
+                n = bucket_items(n, self.pad_buckets) + 1
+            after = jaxenv.compile_stats()
+            said.append("%s to %d items in %.1f s (%d requested, %d built)" % (
+                what, max_items, time.perf_counter() - t0,
+                after["compile_requests"] - before["compile_requests"],
+                after["compiles"] - before["compiles"]))
+        log.info("feeder: programs of %d-byte blocks warm: %s", block_len,
+                 "; ".join(said) or "none to launch")
 
     def _maybe_start_verdict(self) -> None:
         """auto: ask for the device in the background at the first

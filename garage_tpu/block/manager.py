@@ -45,6 +45,11 @@ from .rc import BlockRc
 log = logging.getLogger("garage_tpu.block")
 
 INLINE_THRESHOLD = 3072  # ref: block/manager.rs:46
+# the GET streams a node's decode programs are built for at boot: a
+# decode batch holds at most streams x (1 + [s3_api]
+# get_readahead_blocks) blocks; a larger one still runs, and builds its
+# program inside a request as every batch did before the warm-up
+WARM_GET_STREAMS = 8
 
 _tmp_ctr = itertools.count()
 _TMP_MAX_AGE = 3600.0  # stale .tmpN orphans (crash mid-write) get swept
@@ -398,19 +403,22 @@ class BlockManager:
             self._ingest_pool = pool
         return pool
 
-    async def warm_device(self, block_size: int, ingest_buffers: int
-                          ) -> None:
+    async def warm_device(self, block_size: int, ingest_buffers: int,
+                          get_readahead_blocks: int) -> None:
         """Boot of a node that must serve from its device: build or
-        load, before the first request, every program a PUT of full
-        blocks launches (feeder.warm_put_programs), up to the largest
-        batch the ingest pool's leases allow."""
+        load, before the first request, every program that full blocks
+        launch (feeder.warm_programs) — a PUT's up to the largest batch
+        the ingest pool's leases allow, a degraded GET's decode up to
+        WARM_GET_STREAMS streams' blocks in flight, one shard's
+        repair."""
         pool = self.ingest_pool(block_size, ingest_buffers)
         lease = pool.try_acquire() if pool is not None else None
         try:
             if lease is not None:
                 lease.length = lease.cap  # a full block of zeros
-            await self.feeder.warm_put_programs(
-                block_size, lease, max(1, ingest_buffers))
+            await self.feeder.warm_programs(
+                block_size, lease, max(1, ingest_buffers),
+                WARM_GET_STREAMS * (1 + max(0, get_readahead_blocks)))
         finally:
             if lease is not None:
                 lease.release()
@@ -949,10 +957,8 @@ class BlockManager:
             if key in tried or not placement:
                 continue
             tried.add(key)
-            async with span("block.gather", hash=hash32,
-                            parts=self.codec.read_need):
-                got = await self._gather_parts(hash32, placement,
-                                               self.codec.read_need)
+            got = await self._gather_parts(hash32, placement,
+                                           self.codec.read_need)
             if got is None:
                 continue
             gathered_any = True
@@ -1055,21 +1061,34 @@ class BlockManager:
     async def _gather_parts(self, hash32: bytes, placement: list[bytes],
                             need: int):
         """Fetch parts concurrently until `need` distinct indices are in
-        hand; over-request nothing (systematic shards first, then the
-        rest on failure). -> (parts, packed_len candidates ranked by
+        hand: keep as many fetches in flight as parts are still wanted,
+        in placement order (systematic shards first), launch the next
+        holder for each that comes back empty, and hedge one more when
+        every fetch in flight is past its holder's p95 (so more than
+        `need` may be asked). -> (parts, packed_len candidates ranked by
         vote count majority first, per-index header packed_len) or
         None. The per-index map lets deep scrub see WHICH holder's
         header disagrees with the majority (header rot repair).
 
-        With no more holders up than `need` (m down) there is nobody
-        else to ask, so every fetch keeps its flat timeout
-        (RpcHelper.has_spare): a holder that is silent for a second
-        answers a second late instead of being cut."""
-        me = self.system.id
-        adaptive_timeout = self.rpc.has_spare(placement, need)
-        warned = False
+        A fetch is tightened to its holder's observed latency only
+        while, at its launch, more holders that can still answer are up
+        than parts are wanted (RpcHelper.has_spare): with m down, or
+        once failures have used the spares up, nobody else can be asked
+        and a holder that is silent for a second answers a second late
+        instead of being cut.
 
-        async def fetch(node, idx):
+        Counted once a gather: block_gather_seconds{outcome} (`ok`:
+        `need` parts in hand; `short`: None) and, a fetch,
+        block_gather_fetches{result} — `ok`, `refused` (no part from a
+        holder known to be down), `failed` (none from one that is up:
+        an error, or it has no such shard), `cancelled` (stragglers)."""
+        me = self.system.id
+        is_up = self.system.is_up
+        t0 = time.perf_counter()
+        warned = False
+        waves = 0
+
+        async def fetch(node, idx, adaptive_timeout):
             nonlocal warned
             try:
                 if node == me:
@@ -1079,23 +1098,22 @@ class BlockManager:
                     # (ADVICE r5)
                     raw = await asyncio.to_thread(
                         self.read_local_shard, hash32, idx)
-                    if raw is None:
-                        return None
                     # lint: ignore[GL10] shard crc is native-C microseconds; the flagged open/cc chain is the one-time kernel build, cached for the process lifetime
-                    return unpack_shard(raw)
-                # self.rpc.call (not endpoint.call): the helper records
-                # per-peer health and applies the adaptive timeout, so
-                # a hung holder stops costing the full flat timeout
-                # once its p99 is known and another can be asked
-                resp = await self.rpc.call(
-                    self.endpoint, node,
-                    {"op": "get", "hash": hash32, "part": idx},
-                    PRIO_NORMAL, timeout=60.0,
-                    adaptive_timeout=adaptive_timeout,
-                )
-                if resp.get("data") is None:
-                    return None
-                return unpack_shard(resp["data"])
+                    got = None if raw is None else unpack_shard(raw)
+                else:
+                    # self.rpc.call (not endpoint.call): the helper
+                    # records per-peer health, and tightens the timeout
+                    # to the holder's p99 when this launch was told it
+                    # may (a hung holder then stops costing the flat
+                    # 60 s, since another can be asked)
+                    resp = await self.rpc.call(
+                        self.endpoint, node,
+                        {"op": "get", "hash": hash32, "part": idx},
+                        PRIO_NORMAL, timeout=60.0,
+                        adaptive_timeout=adaptive_timeout,
+                    )
+                    got = (None if resp.get("data") is None
+                           else unpack_shard(resp["data"]))
             except Exception as e:
                 # local disk/unpack failures are a different signal
                 # than a peer fetch failing; don't conflate them
@@ -1104,13 +1122,17 @@ class BlockManager:
                 # a holder known to be down refuses at once, by the
                 # hundred a second while a zone is out; an error from
                 # a holder that is up is news, once a gather
-                news = not warned and self.system.is_up(node)
+                news = not warned and is_up(node)
                 warned = warned or news
                 log.log(logging.WARNING if news else logging.DEBUG,
                         "block %s: shard fetch part=%d from %s failed: "
                         "%s: %s", hash32[:4].hex(), idx, node[:4].hex(),
                         type(e).__name__, e)
-                return None
+                got = None
+            registry().inc("block_gather_fetches", result=(
+                "ok" if got is not None
+                else "failed" if is_up(node) else "refused"))
+            return got
 
         race = HedgedRace(self.rpc.health(), "block_get_shard")
         parts: dict[int, bytes] = {}
@@ -1118,41 +1140,66 @@ class BlockManager:
         order = list(enumerate(placement))  # systematic first by design
         i = 0
 
-        def launch_next(hedged: bool = False):
+        def spare() -> bool:
+            # over the holders that can still answer: in flight or not
+            # yet asked — those that failed or answered are spent
+            return self.rpc.has_spare(
+                [placement[j] for j, _ in race.pending.values()]
+                + placement[i:], need - len(parts))
+
+        def launch_next(adaptive_timeout: bool, hedged: bool = False):
             nonlocal i
             idx, node = order[i]
             i += 1
-            race.launch(idx, fetch(node, idx), hedged)
+            race.launch(idx, fetch(node, idx, adaptive_timeout), hedged)
 
-        try:
-            while len(parts) < need and (race.pending or i < len(order)):
-                while i < len(order) \
-                        and len(race.pending) < need - len(parts):
-                    launch_next()
-                if not race.pending:
-                    break
-                # when every in-flight shard fetch is past its holder's
-                # observed p95, the hedge launches the next candidate
-                # shard instead of waiting out a hung holder (exceeds
-                # the need-len(parts) concurrency cap by design)
-                done = await race.wait(
-                    can_hedge=i < len(order),
-                    launch_hedge=lambda: launch_next(hedged=True),
-                    hedge_nodes=[placement[idx]
-                                 for idx, _ in race.pending.values()])
-                for idx, was_hedged, t in done:
-                    r = t.result()
-                    if r is not None:
-                        parts[idx] = r[0]
-                        lens_by_idx[idx] = r[1]
-                        race.note_success(was_hedged)
-        finally:
-            # cancel stragglers (hedges included) on every exit path —
-            # a client disconnect cancels this coroutine at the wait
-            # above, and the in-flight MiB-scale fetches must not keep
-            # running for nobody; fetch() swallows its own errors so
-            # nothing logs
-            race.cancel_pending()
+        sp = span("block.gather", hash=hash32, parts=need)
+        async with sp:
+            try:
+                while len(parts) < need and (race.pending
+                                             or i < len(order)):
+                    # one answer for a wave: launching moves a holder
+                    # from "not yet asked" to "in flight", no more
+                    asked, tighten = i, spare()
+                    while i < len(order) \
+                            and len(race.pending) < need - len(parts):
+                        launch_next(tighten)
+                    waves += i > asked
+                    if not race.pending:
+                        break
+                    # when every in-flight shard fetch is past its
+                    # holder's observed p95, the hedge launches the
+                    # next candidate shard instead of waiting out a
+                    # hung holder (exceeds the need-len(parts)
+                    # concurrency cap by design)
+                    done = await race.wait(
+                        can_hedge=i < len(order),
+                        launch_hedge=lambda: launch_next(spare(),
+                                                         hedged=True),
+                        hedge_nodes=[placement[idx]
+                                     for idx, _ in race.pending.values()])
+                    for idx, was_hedged, t in done:
+                        r = t.result()
+                        if r is not None:
+                            parts[idx] = r[0]
+                            lens_by_idx[idx] = r[1]
+                            race.note_success(was_hedged)
+            finally:
+                # cancel stragglers (hedges included) on every exit
+                # path — a client disconnect cancels this coroutine at
+                # the wait above, and the in-flight MiB-scale fetches
+                # must not keep running for nobody; fetch() swallows
+                # its own errors so nothing logs
+                for t in race.pending:
+                    if not t.done():
+                        registry().inc("block_gather_fetches",
+                                       result="cancelled")
+                race.cancel_pending()
+                registry().observe(
+                    "block_gather_seconds", time.perf_counter() - t0,
+                    outcome="ok" if len(parts) >= need else "short")
+                sp.attrs.update(fetches=i, waves=waves,
+                                holders_up=sum(map(is_up, placement)))
         if len(parts) < need:
             return None
         lens = list(lens_by_idx.values())

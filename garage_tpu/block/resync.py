@@ -324,19 +324,23 @@ class BlockResyncManager:
 
     def _pop_due(self) -> Optional[bytes]:
         now = time.time()
-        for k, _ in self.queue.iter():
-            if int.from_bytes(k[:8], "big") > now * 1000:
-                return None
-            self.queue.remove(k)
-            h = k[8:]
-            # skip if errored and not yet due for retry
-            e = self.errors.get(h)
-            if e is not None:
-                _, next_ms = self._parse_err(e)
-                if next_ms > now * 1000:
-                    self.queue.insert(self._qkey(next_ms / 1000, h), b"")
-                    continue
-            return h
+        # the head of the queue, not all of it: iter() materializes
+        # what it is asked for, and a pop that read a backlog of a
+        # thousand entries to take one made draining it quadratic
+        while head := list(self.queue.iter(limit=16)):
+            for k, _ in head:
+                if int.from_bytes(k[:8], "big") > now * 1000:
+                    return None
+                self.queue.remove(k)
+                h = k[8:]
+                # skip if errored and not yet due for retry
+                e = self.errors.get(h)
+                if e is not None:
+                    _, next_ms = self._parse_err(e)
+                    if next_ms > now * 1000:
+                        self.queue.insert(self._qkey(next_ms / 1000, h), b"")
+                        continue
+                return h
         return None
 
     @staticmethod

@@ -50,11 +50,12 @@ async def _run_server_locked(cfg, cfg_path: str) -> None:
         # a node that must have its device does not come up without
         # it: raises with the platform found (block/feeder.py)
         await garage.block_manager.feeder.device_verdict()
-        # and meets none of its PUT programs for the first time inside
-        # a request (seconds each to build, and a stall of every
-        # stream behind the stage thread that builds it)
+        # and meets none of its PUT or decode programs for the first
+        # time inside a request (seconds each to build, and a stall of
+        # every stream behind the stage thread that builds it)
         await garage.block_manager.warm_device(
-            cfg.block_size, cfg.s3_ingest_buffers)
+            cfg.block_size, cfg.s3_ingest_buffers,
+            cfg.s3_get_readahead_blocks)
     admin = AdminRpcHandler(garage)
     otlp = None
     if cfg.admin_trace_sink:

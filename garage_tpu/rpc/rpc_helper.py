@@ -290,9 +290,31 @@ class RpcHelper:
         spare). The one rule for when a call may be cut short: a
         peer's tightened timeout is right while somebody else can be
         asked, and turns a late answer into a lost one when nobody
-        can."""
+        can. Asked at each launch, over the nodes that can still
+        answer then (in flight or not yet asked) and the answers still
+        wanted: a node that has failed in this call is nobody to ask."""
         is_up = getattr(self.system, "is_up", None)
         return is_up is None or sum(map(is_up, nodes)) > need
+
+    def _warn_refused(self, path: str, quorum: int, ok: int,
+                      errors: list, tightened: dict) -> None:
+        """A read quorum is about to be refused: say once, on this
+        node, who failed it and how — the client gets a 503 that is cut
+        short, and a late copy and a dead one want different repairs."""
+        is_up = getattr(self.system, "is_up", None)
+
+        def one(e: Exception) -> str:
+            node = getattr(e, "node", None)
+            cause = e.__cause__ if e.__cause__ is not None else e
+            up = "?" if node is None or is_up is None else (
+                "up" if is_up(node) else "down")
+            timeout = "tightened" if tightened.get(node) else "flat"
+            said = f": {str(cause)[:120]}" if str(cause) else ""
+            return (f"{node.hex()[:8] if node else '?'} ({up}, {timeout} "
+                    f"timeout): {type(cause).__name__}{said}")
+
+        log.warning("%s: quorum %d refused with %d answer(s); %s", path,
+                    quorum, ok, "; ".join(map(one, errors)) or "no error")
 
     # ---- node ordering (ref: rpc_helper.rs:621-660) --------------------
 
@@ -421,25 +443,31 @@ class RpcHelper:
         if quorum > len(nodes):
             raise QuorumError(quorum, 1, 0, len(nodes), ["not enough nodes"])
         order = self.request_order(list(nodes))
-        # one copy of three dead and two wanted: a call cut at its
-        # peer's tightened timeout would be the quorum lost
-        adaptive_timeout = self.has_spare(nodes, quorum)
         race = HedgedRace(
             self.health(), endpoint.path,
             enabled=(False if strategy.send_all_at_once
                      else strategy.hedge))
         successes: list = []
         errors: list[Exception] = []
+        tightened: dict[bytes, bool] = {}
         next_i = 0
 
         def launch_one(hedged: bool = False):
             nonlocal next_i
             node = order[next_i]
+            # the spare rule, asked at THIS launch over the nodes that
+            # can still answer (in flight, this one, not yet asked):
+            # one copy of three dead and two wanted, or three up and
+            # one already cut in this call, and a call cut at its
+            # peer's tightened timeout would be the quorum lost
+            tightened[node] = self.has_spare(
+                [n for n, _ in race.pending.values()] + order[next_i:],
+                quorum - len(successes))
             next_i += 1
             pl = make_payload(node) if make_payload else payload
             race.launch(node, self._tracked_call(
                 endpoint, node, pl, strategy.prio, strategy.timeout,
-                adaptive_timeout=adaptive_timeout),
+                adaptive_timeout=tightened[node]),
                 hedged)
 
         n_initial = len(order) if strategy.send_all_at_once else min(quorum, len(order))
@@ -448,6 +476,8 @@ class RpcHelper:
         try:
             while len(successes) < quorum:
                 if not race.pending:
+                    self._warn_refused(endpoint.path, quorum,
+                                       len(successes), errors, tightened)
                     raise QuorumError(
                         quorum, 1, len(successes), len(nodes), [str(e) for e in errors]
                     )

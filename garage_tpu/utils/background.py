@@ -161,6 +161,12 @@ class BackgroundRunner:
                 state = Throttled(delay)
             if state is WState.DONE:
                 break
+            if state is WState.BUSY:
+                # a work() that never suspends (the resync worker's
+                # local checks of a backlog of a thousand blocks) must
+                # not hold the loop until its queue is empty: every
+                # request of the node waited 1-2 s behind it
+                await asyncio.sleep(0)
             if isinstance(state, Throttled):
                 info.state = "throttled"
                 try:

@@ -3,6 +3,9 @@ paths, refcounts, resync healing, scrub corruption detection."""
 
 import asyncio
 import os
+import time
+
+import pytest
 
 from garage_tpu.block import (
     BlockManager,
@@ -707,3 +710,45 @@ def test_deep_scrub_skips_unreachable_stripes(tmp_path):
             await stop_all(systems, tasks)
 
     run(main())
+
+
+@pytest.mark.parametrize("due,errored,parked", [(1, 0, 0), (40, 7, 0),
+                                                 (300, 20, 500)])
+def test_resync_pop_due_reads_the_head_of_the_queue(tmp_path, due, errored,
+                                                    parked):
+    """_pop_due takes the due entries in order, re-parks those whose
+    error backoff has not run out, leaves the future alone — and reads
+    a bounded head of the queue a call, not all of it (a backlog of a
+    thousand entries made draining it quadratic, PR 37)."""
+    from garage_tpu.block.resync import BlockResyncManager
+    from garage_tpu.db import open_db
+
+    db = open_db(str(tmp_path / "db"), engine="sqlite")
+    r = BlockResyncManager(None, db)
+    now = time.time()
+    hashes = [bytes([i % 256, i // 256]) + bytes(30) for i in range(due)]
+    for i, h in enumerate(hashes):
+        r.push_at(h, now - 10 + i * 1e-3)
+    later = int((now + 3600) * 1000)
+    for h in hashes[:errored]:
+        r.errors.insert(h, (1).to_bytes(4, "big") + later.to_bytes(8, "big"))
+    for i in range(parked):
+        r.push_at(bytes([i % 256, i // 256, 1]) + bytes(29), now + 600 + i)
+    read = []
+    real_iter = r.queue.iter
+
+    def counting_iter(*a, **kw):
+        rows = list(real_iter(*a, **kw))
+        read.append(len(rows))
+        return iter(rows)
+
+    r.queue.iter = counting_iter
+    got = []
+    while (h := r._pop_due()) is not None:
+        got.append(h)
+    assert got == hashes[errored:]
+    assert max(read) <= 16
+    # the errored ones wait at their retry time, the parked ones stay
+    assert r.queue_len() == errored + parked
+    assert r.due_len() == 0
+    db.close()

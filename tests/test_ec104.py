@@ -281,6 +281,72 @@ def test_read_back_with_a_whole_zone_down(tmp_path, zone):
     run(main())
 
 
+def test_range_gets_with_zone_z2_lost_equal_the_reference(tmp_path):
+    """The deployment ec104-14n-z2lost at a small size: objects written
+    with fourteen up, zone z2's four holders stopped, every part read
+    as a Range GET through node 1. Each body is the seeded bytes, and
+    block by block the ten shard files left on disk give the same block
+    through the reference's decode, for every present-set the layout
+    produces (which four indices z2 held differs from block to
+    block)."""
+    objects = {f"o{i}": parts_of(3700 + 10 * i) for i in range(3)}
+    down = zone_members("z2")
+
+    async def main():
+        async with Box(tmp_path, s3_nodes=(0,), device_node=0) as b:
+            for name, parts in objects.items():
+                st, body = await b.upload(0, name, parts)
+                assert st == 200, body
+            lv = b.box.nodes[0].manager.system.layout_helper.current()
+            stripes = {name: [reference_stripe(blk, K, M)
+                              for blk in blocks_of(parts)]
+                       for name, parts in objects.items()}
+            await b.box.wait(lambda: all(
+                nd.manager.local_parts(h) for nd in b.box.nodes
+                for want in stripes.values() for h, _, _ in want),
+                20, "all fourteen shards landed")
+            await b.stop_nodes(down)
+            b.forget_cached_blocks()
+            mgr = b.box.nodes[0].manager
+            before = mgr.feeder.stats["decode_device_items"]
+            for name, parts in objects.items():
+                at = 0
+                for p in parts:
+                    st, _, got = await b.request(
+                        0, "GET", f"/{BUCKET}/{name}", headers={
+                            "range": f"bytes={at}-{at + len(p) - 1}"})
+                    assert st == 206 and got == p, (name, at)
+                    at += len(p)
+            assert mgr.feeder.stats["decode_device_items"] > before
+            assert mgr.feeder.stats["device_errors"] == 0
+            await mgr.feeder.stop()
+            gone = {b.ids[i] for i in down}
+            present_sets = set()
+            for name, parts in objects.items():
+                for blk, (h, _, packed_len) in zip(blocks_of(parts),
+                                                   stripes[name]):
+                    place = shard_nodes_of(lv, h, N)
+                    present = [i for i, n in enumerate(place)
+                               if n not in gone]
+                    assert len(present) == K  # exactly m lost, none spare
+                    files = []
+                    for idx in present:
+                        m = b.box.nodes[b.ids.index(place[idx])].manager
+                        payload, plen = parse_shard_file(
+                            bytes(m.read_local_shard(h, idx)))
+                        assert plen == packed_len
+                        files.append(payload)
+                    assert reference_block(present, files, K, M,
+                                           packed_len) == blk
+                    present_sets.add(tuple(present))
+            return present_sets
+
+    present_sets = run(main(), 240)
+    # more than one pattern, and a data shard among the lost in some
+    assert len(present_sets) > 1
+    assert any(ps[K - 1] >= K for ps in present_sets)
+
+
 def test_five_holders_gone_fails_cleanly(tmp_path):
     """The guarantee's other edge: ten shards are needed and nine are
     left. The block read raises, and the S3 GET answers an error or

@@ -326,3 +326,142 @@ def test_malformed_decode_item_fails_its_caller_only():
         await f.stop()
 
     run(go())
+
+
+# ---------------------------------------------------------------------------
+# the gather, counted once a gather (ISSUE 37): block_gather_seconds,
+# block_gather_fetches, the block.gather span's attrs; rs_decode_patterns
+# ---------------------------------------------------------------------------
+
+# case -> (systematic holders stopped, parity holders stopped,
+#          fetches by result, waves, outcome)
+GATHERS = {
+    "all-up": (0, 0, {"ok": 4}, 1, "ok"),
+    "m-down": (2, 0, {"ok": 4, "refused": 2}, 2, "ok"),
+    "short": (2, 1, {"ok": 3, "refused": 3}, 2, "short"),
+}
+
+
+@pytest.mark.parametrize("case", GATHERS)
+def test_gather_series_and_span(tmp_path, case):
+    """One gather at (4,2) with every holder up, with m down, and one
+    that comes back short: the histogram moves once under its outcome,
+    the fetch counter once a fetch under what became of it, and the
+    span says how many fetches, in how many waves, of how many holders
+    up — under the names the three metric files read."""
+    import json
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from clusterbox import ClusterBox
+
+    from garage_tpu.block.codec import shard_nodes_of
+    from garage_tpu.utils import tracing
+    from garage_tpu.utils.error import MissingBlock
+    from garage_tpu.utils.metrics import registry
+
+    data_down, parity_down, want_fetches, want_waves, outcome = GATHERS[case]
+    k, m = 4, 2
+    reg = registry()
+
+    def counts():
+        return ({o: reg.totals("block_gather_seconds", outcome=o)[0]
+                 for o in ("ok", "short")},
+                {r: reg.totals("block_gather_fetches", result=r)[0]
+                 for r in ("ok", "refused", "failed", "cancelled")})
+
+    async def main():
+        box = await ClusterBox(tmp_path, n=k + m, rf=3, erasure=(k, m),
+                               block_size=20_000).start()
+        try:
+            reader = box.nodes[0]
+            mgr = reader.manager
+            data = np.random.default_rng(37).integers(
+                0, 256, 20_000, dtype=np.uint8).tobytes()
+            h = await mgr.hash_block(data)
+            await mgr.rpc_put_block(h, data)
+            placement = shard_nodes_of(
+                reader.system.layout_helper.current(), h, k + m)
+            by_id = {nd.id: nd for nd in box.nodes}
+            systematic = [by_id[x] for x in placement[:k] if x != reader.id]
+            parity = [by_id[x] for x in placement[k:] if x != reader.id]
+            dead = systematic[:data_down] + parity[:parity_down]
+            await box.wait(
+                lambda: box.resync_backlog() == 0 and not any(
+                    nd.manager.cache_tier._insert_inflight
+                    for nd in box.live()),
+                15, "the box quiet")
+            for nd in dead:
+                await box.stop_node(nd)
+            await box.wait(
+                lambda: not any(reader.system.is_up(nd.id) for nd in dead),
+                15, "the reader sees the dead holders down")
+            before = counts()
+            tracing.tracer.enabled = True
+            tracing.tracer.ring.clear()
+            try:
+                try:
+                    got = await mgr.rpc_get_block(h, cacheable=False)
+                except MissingBlock:
+                    got = None
+                spans = [r for r in tracing.tracer.ring
+                         if r["name"] == "block.gather"]
+            finally:
+                tracing.tracer.enabled = False
+                tracing.tracer.ring.clear()
+            return got == data, before, counts(), spans
+        finally:
+            await box.stop()
+
+    same, before, after, spans = run(asyncio.wait_for(main(), 120))
+    assert same == (outcome == "ok")
+    gathers = {o: after[0][o] - before[0][o] for o in after[0]}
+    fetches = {r: after[1][r] - before[1][r] for r in after[1]}
+    assert gathers == {"ok": int(outcome == "ok"),
+                       "short": int(outcome == "short")}
+    assert {r: n for r, n in fetches.items() if n} == want_fetches
+    (span,) = spans
+    assert span["attrs"] == {
+        "hash": span["attrs"]["hash"], "parts": k,
+        "fetches": sum(want_fetches.values()), "waves": want_waves,
+        "holders_up": k + m - data_down - parity_down}
+    # what the three metric files read is what the registry renders
+    rendered = {line.split("{")[0].split(" ")[0] for line in reg.render()}
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for name in ("gather_ms", "fetches_per_block", "gathered_share"):
+        with open(os.path.join(root, "benchmark", "layer_metrics",
+                               f"{name}.json")) as f:
+            params = json.load(f)["params"]
+        series = {t["series"] for side in ("num", "den")
+                  for t in params[side]}
+        assert series - {"cache_hits", "cache_misses"} <= rendered, name
+
+
+def test_rs_decode_patterns_counts_the_present_sets_launched():
+    """The gauge is the size of decode_bitmat_t's cache, which keeps
+    one expansion a present-set for the life of the process: after a
+    batch of five stripes under three present-sets it reads three."""
+    k, m = 4, 2
+    codec = ErasureCodec(k, m, use_jax=False)
+    f = DeviceFeeder(codec=codec, mode="require")
+    f._device_ok = True
+    block = np.random.default_rng(3).integers(
+        0, 256, 5_000, dtype=np.uint8).tobytes()
+    stripe = _stripe(codec, block)
+    sets = [(0, 1, 2, 4), (1, 2, 3, 5), (0, 2, 4, 5)]
+
+    async def go():
+        rs.decode_bitmat_t.cache_clear()
+        assert f.stats["decode_patterns"] == 0
+        items = [(p, [stripe[i] for i in p], len(block))
+                 for p in sets + sets[:2]]
+        try:
+            got = await f._run_batch_staged(
+                [_Item("decode", it, None) for it in items])
+        finally:
+            await f.stop()
+        assert got == [block] * 5
+        assert f.stats["decode_patterns"] == 3 \
+            == rs.decode_bitmat_t.cache_info().currsize
+
+    run(go())

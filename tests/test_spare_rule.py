@@ -1,9 +1,11 @@
 """One rule for when a call may be cut short (ISSUE 36): a call keeps
 its caller's flat timeout when no live node could be asked in its
 place (`RpcHelper.has_spare`), and is tightened to the peer's observed
-latency only while one could. Read at two sites: `try_call_many` and
-the block manager's `_gather_parts`, which also names in the node's log
-the first error of a gather from a holder that is up.
+latency only while one could. Asked at each launch (ISSUE 37), over the
+nodes that can still answer then, at two sites: `try_call_many`, which
+names in the node's log who failed a quorum it refuses, and the block
+manager's `_gather_parts`, which names there the first error of a
+gather from a holder that is up.
 """
 
 from __future__ import annotations
@@ -32,7 +34,8 @@ from garage_tpu.chaos import FaultSpec, arm, disarm  # noqa: E402
 from garage_tpu.net.message import PRIO_NORMAL  # noqa: E402
 from garage_tpu.net.peering import PeerHealthTracker  # noqa: E402
 from garage_tpu.rpc import RequestStrategy, RpcHelper  # noqa: E402
-from garage_tpu.utils.error import MissingBlock  # noqa: E402
+from garage_tpu.utils.error import MissingBlock, QuorumError  # noqa: E402
+from garage_tpu.utils.metrics import registry  # noqa: E402
 
 BLOCK = 20_000
 PEER = b"\x07" * 32
@@ -186,32 +189,59 @@ def test_tracked_call_keeps_the_flat_timeout_when_told(adaptive, handed):
     assert helper.has_spare([PEER], 1)
 
 
-@pytest.mark.parametrize("copies_down", [0, 1])
-def test_try_call_many_is_adaptive_only_with_a_copy_to_spare(
-        tmp_path, copies_down):
-    """Quorum 2 of 3: three copies up, every call sits at its peer's
-    adaptive value; one copy down, both live ones are needed and keep
-    the strategy's flat timeout."""
+# case -> (copies partitioned away, what the live remote copies do)
+QUORUMS = {
+    "three-up": (0, {}),
+    "one-down": (1, {}),
+    "one-cut-one-answered": (0, {1: "late"}),
+    "one-cut-one-broken": (0, {1: "late", 2: "broken"}),
+}
+LATE_S = 1.3  # past the tightened 1 s, far inside the flat 30 s
+
+
+@pytest.mark.parametrize("case", QUORUMS)
+def test_try_call_many_asks_the_rule_at_each_launch(tmp_path, caplog, case):
+    """Quorum 2 of 3, every peer known as a 20 ms one. Three copies up:
+    both calls sit at their peer's tightened 1 s. One copy down: both
+    live ones are needed and keep the strategy's flat 30 s. Three up
+    and the second asked is silent: it is cut at 1 s, and the third is
+    then the last that can be asked, so its launch is flat. That one
+    broken too: the quorum is refused, and the node says once who
+    failed it and how."""
+    copies_down, faults = QUORUMS[case]
+    caplog.set_level(logging.WARNING, logger="garage_tpu.rpc.helper")
+
     async def main():
         net, systems, tasks = await make_cluster(tmp_path, 3)
         try:
             apply_flat_layout(systems)
-
-            async def h(frm, payload, stream):
-                return {}
-
+            me = systems[0]
+            nodes = [s.id for s in systems]
             for s in systems:
-                s.netapp.endpoint("test/spare").set_handler(h)
                 for peer in systems:
                     for _ in range(8):
                         s.peering.health.record_success(peer.id, 0.02)
-            me = systems[0]
-            nodes = [s.id for s in systems]
+            helper = RpcHelper(me)
+            order = helper.request_order(nodes)
+            assert order[0] == me.id
+            by_id = {s.id: s for s in systems}
+
+            def handler(what):
+                async def h(frm, payload, stream):
+                    if what == "late":
+                        await asyncio.sleep(LATE_S)
+                    if what == "broken":
+                        raise ValueError("no such row")
+                    return {}
+                return h
+
+            for i, node in enumerate(order):
+                by_id[node].netapp.endpoint("test/spare").set_handler(
+                    handler(faults.get(i)))
             if copies_down:
                 net.partition(systems[0].id, systems[2].id)
                 net.partition(systems[1].id, systems[2].id)
                 await _wait(lambda: not me.is_up(systems[2].id), 15)
-            helper = RpcHelper(me)
             assert helper.has_spare(nodes, 2) == (not copies_down)
             ep = me.netapp.endpoint("test/spare")
             seen = []
@@ -223,12 +253,90 @@ def test_try_call_many_is_adaptive_only_with_a_copy_to_spare(
                                   timeout=timeout)
 
             ep.call = call
-            got = await helper.try_call_many(
-                ep, nodes, {}, RequestStrategy(quorum=2, timeout=30.0))
-            assert len(got) == 2
-            return seen
+            try:
+                got = len(await helper.try_call_many(
+                    ep, nodes, {},
+                    RequestStrategy(quorum=2, timeout=30.0, hedge=False)))
+            except QuorumError as e:
+                got = e
+            return seen, got, [n.hex()[:8] for n in order]
         finally:
             await stop_cluster(systems, tasks)
 
-    seen = run(main())
-    assert seen == [30.0 if copies_down else 1.0] * 2
+    seen, got, order = run(main())
+    warnings = [r.getMessage() for r in caplog.records
+                if "quorum 2 refused" in r.getMessage()]
+    if case == "one-cut-one-broken":
+        assert seen == [1.0, 1.0, 30.0]
+        assert isinstance(got, QuorumError) and "1/3 ok" in str(got)
+        assert len(warnings) == 1, warnings
+        assert warnings[0].startswith(
+            "test/spare: quorum 2 refused with 1 answer(s); ")
+        assert f"{order[1]} (up, tightened timeout): TimeoutError" \
+            in warnings[0]
+        assert f"{order[2]} (up, flat timeout): RpcError: " in warnings[0]
+        assert "no such row" in warnings[0]
+        return
+    assert got == 2 and not warnings
+    assert seen == {"three-up": [1.0, 1.0], "one-down": [30.0, 30.0],
+                    "one-cut-one-answered": [1.0, 1.0, 30.0]}[case]
+
+
+def test_gather_whose_spares_are_used_up_launches_flat(tmp_path):
+    """Fourteen holders up, four of the first ten fail their fetch: the
+    first wave is tightened (fourteen up, ten wanted), and by the last
+    launch everybody left is needed, so it keeps the flat 60 s."""
+    k, m = 10, 4
+
+    async def main():
+        box = await ClusterBox(tmp_path, n=k + m, rf=3, erasure=(k, m),
+                               block_size=BLOCK).start()
+        try:
+            reader = box.nodes[0]
+            mgr = reader.manager
+            data = np.random.default_rng(37).integers(
+                0, 256, BLOCK, dtype=np.uint8).tobytes()
+            h = await mgr.hash_block(data)
+            await mgr.rpc_put_block(h, data)
+            placement = shard_nodes_of(
+                reader.system.layout_helper.current(), h, k + m)
+            await box.wait(
+                lambda: box.resync_backlog() == 0 and not any(
+                    nd.manager.cache_tier._insert_inflight
+                    for nd in box.live()),
+                15, "the box quiet")
+            for node in placement:
+                for _ in range(8):
+                    reader.system.peering.health.record_success(node, 0.02)
+            victims = [n for n in placement[:k] if n != reader.id][:m]
+            chaos = arm(seed=37)
+            for v in victims:
+                chaos.add(FaultSpec(kind="rpc_error", peer=v.hex()[:16],
+                                    endpoint="garage_tpu/block", count=1))
+            asked = []
+            real = mgr.endpoint.call
+
+            async def call(node, payload, prio, stream=None, timeout=None):
+                asked.append((placement.index(node), timeout))
+                return await real(node, payload, prio, stream=stream,
+                                  timeout=timeout)
+
+            mgr.endpoint.call = call
+            before = registry().totals("block_gather_fetches")[0]
+            try:
+                got = await mgr.rpc_get_block(h, cacheable=False)
+            finally:
+                disarm()
+            return (got == data, asked,
+                    registry().totals("block_gather_fetches")[0] - before)
+        finally:
+            await box.stop()
+
+    same, asked, fetches = run(main())
+    assert same and fetches == k + m
+    # remote fetches in launch order: (index in the placement, timeout)
+    # (tightened is max(1 s, 4 x p99) of the peer: over 1 s where the
+    # suite's own load made the put's writes slow)
+    first_wave = [t for i, t in asked if i < k]
+    assert first_wave and max(first_wave) < 60.0
+    assert len(asked) == k + m - 1 and asked[-1][1] == 60.0

@@ -184,3 +184,47 @@ def test_lockfile_exclusive(tmp_path):
     lockfile.release(fd)
     r2 = subprocess.run([sys.executable, "-c", child])
     assert r2.returncode == 0  # free after release
+
+
+@pytest.mark.parametrize("items", [1, 50, 1200])
+def test_background_busy_worker_yields_between_items(items):
+    """A worker whose work() never suspends must not hold the loop
+    until its backlog is empty: between two items everybody else gets
+    a turn (a holder's resync backlog stalled every shard fetch of the
+    node for 1-2 s, PR 37)."""
+
+    async def main():
+        runner = background.BackgroundRunner()
+        turns = []
+
+        class Backlog(background.Worker):
+            name = "backlog"
+
+            def __init__(self):
+                self.left = items
+
+            async def work(self):  # no await inside: never suspends
+                self.left -= 1
+                turns.append("w")
+                return (background.WState.BUSY if self.left
+                        else background.WState.DONE)
+
+        async def other():
+            while True:
+                turns.append("o")
+                await asyncio.sleep(0)
+
+        t = asyncio.create_task(other())
+        await asyncio.sleep(0)
+        runner.spawn_worker(Backlog())
+        while turns.count("w") < items:
+            await asyncio.sleep(0)
+        t.cancel()
+        await runner.shutdown()
+        first = turns.index("w")
+        span = turns[first:len(turns) - turns[::-1].index("w")]
+        assert span.count("w") == items
+        # never two items back to back
+        assert "ww" not in "".join(span)
+
+    asyncio.run(main())
