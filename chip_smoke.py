@@ -784,14 +784,11 @@ def _serve(cl: Cluster, cfg: dict, rehearse: bool, seed: int) -> dict:
     res["device_items"] = {
         k.split('op="')[1].split('"')[0]: int(v) for k, v in m.items()
         if k.startswith("feeder_device_op_items")}
-    res["feeder_mbps"] = {
-        k.split("{")[1].rstrip("}").replace('"', ""): v
-        for k, v in m.items() if k.startswith("feeder_throughput_mbps")}
     res["stage_busy_s"] = {
         k.split('stage="')[1].split('"')[0]: v for k, v in m.items()
         if k.startswith("feeder_pipeline_busy_seconds")}
     res["counters"] = {k: m.get(k, 0) for k in (
-        "feeder_device_items", "feeder_device_batches", "feeder_max_batch",
+        "feeder_device_items", "feeder_device_batches",
         "feeder_device_errors", "feeder_host_reruns", "feeder_recompiles",
         "feeder_mesh_batches", "feeder_xla_compile_requests",
         "feeder_xla_compiles", "feeder_xla_cache_hits",

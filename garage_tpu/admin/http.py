@@ -1076,14 +1076,32 @@ class AdminHttpServer:
 
         out.extend(registry().render())
 
-        # device feeder throughput + staged-pipeline observability.
+        # what this node's wire connections did (net/conn.py WireStats,
+        # one a node, live and closed): frames and their bytes each way,
+        # frames the kernel took in part (bytes left in the buffer),
+        # seconds parked in drain() over frames sent, and a message's
+        # wait in its connection's send queue
+        w = g.netapp.wire
+        gauge("rpc_net_frames_count", w.sent, "Wire frames; _sum = bytes",
+              dir="send")
+        gauge("rpc_net_frames_sum", w.sent_bytes, dir="send")
+        gauge("rpc_net_frames_count", w.recv, dir="recv")
+        gauge("rpc_net_frames_sum", w.recv_bytes, dir="recv")
+        gauge("rpc_net_partial_writes_count", w.partial,
+              "Frames the kernel took in part; _sum = bytes left")
+        gauge("rpc_net_partial_writes_sum", w.partial_bytes)
+        gauge("rpc_net_drain_seconds_count", w.sent,
+              "Frames sent; _sum = seconds parked in drain()")
+        gauge("rpc_net_drain_seconds_sum", round(w.drain_s, 6))
+        gauge("rpc_net_send_queue_seconds_count", w.queued,
+              "Messages sent; _sum = seconds from enqueue to first frame")
+        gauge("rpc_net_send_queue_seconds_sum", round(w.queue_s, 6))
+
+        # device feeder and staged-pipeline observability.
         # Names are registered literally (GL07-checkable, and `feeder`
         # is in METRIC_NAME_RE) — the old `gauge(f"feeder_{k}")` loop
         # was a dynamic name no static rule could audit.
         feeder = g.block_manager.feeder
-        for opbe, mbps in feeder.perf_summary().items():
-            op, _, be = opbe.partition("/")
-            gauge("feeder_throughput_mbps", mbps, op=op, backend=be)
         fs = feeder.stats
         gauge("feeder_batches", fs["batches"],
               "Batches dispatched by the device feeder")
@@ -1093,8 +1111,6 @@ class AdminHttpServer:
               "Items that actually ran on the device path (the live "
               "TPU-engagement proof metric)")
         gauge("feeder_device_bytes", fs["device_bytes"])
-        gauge("feeder_inline_items", fs["inline_items"])
-        gauge("feeder_max_batch", fs["max_batch"])
         gauge("feeder_pad_waste_bytes", fs["pad_waste_bytes"],
               "Zero-padding bytes added by fixed-shape bucket launches")
         gauge("feeder_recompiles", fs["recompiles"],
@@ -1108,9 +1124,6 @@ class AdminHttpServer:
               "Decode/repair items that ran on the device path (the "
               "read-side engagement proof metric)")
         gauge("feeder_decode_device_bytes", fs["decode_device_bytes"])
-        gauge("rs_decode_patterns", fs["decode_patterns"],
-              "Erasure patterns whose decode matrix the process keeps "
-              "(one 8k x 8k expansion each, never evicted)")
         for op, n in sorted(feeder.device_items_by_op.items()):
             gauge("feeder_device_op_items", n, op=op)
         gauge("feeder_device_errors", fs["device_errors"],
@@ -1169,6 +1182,8 @@ class AdminHttpServer:
         if prof is not None:
             gauge("loop_profile_samples", prof["samples"],
                   "Samples of the loop thread's stack taken so far")
+            gauge("node_loop_turns", prof["turns"],
+                  "Turns of the event loop (calls of its selector)")
             for family in ("root", "leaf"):
                 name = f"loop_profile_{family}_seconds"
                 out.append(f"# HELP {name} Seconds of the loop's thread by "

@@ -482,11 +482,6 @@ class JaxDeviceBackend:
                              if op == "decode"
                              else rs.repair_bitmat_t(k, m, present,
                                                      tuple(it[1])))
-            if op == "decode":
-                # one (8k, 8k) expansion is kept a present-set met, for
-                # the life of the process: /metrics rs_decode_patterns
-                self.stats["decode_patterns"] = \
-                    rs.decode_bitmat_t.cache_info().currsize
             waste = bpad * k * smax - sum(
                 len(b) for i in idxs for b in shards_of(items[i]))
             self._note_shape((op, k, rows, bpad, smax, mesh is not None),

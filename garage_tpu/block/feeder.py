@@ -205,11 +205,7 @@ class DeviceFeeder:
                       # ran on the device path (the degraded-GET /
                       # rebuild twin of device_items)
                       "decode_items": 0, "decode_device_items": 0,
-                      "decode_device_bytes": 0,
-                      # present-sets whose decode matrix this process
-                      # keeps (ops/rs.py decode_bitmat_t's cache), as of
-                      # the last decode leg staged
-                      "decode_patterns": 0}
+                      "decode_device_bytes": 0}
         # staged pipeline state: the current executor generation, the
         # batches in flight, per-stage busy seconds and the wall-clock
         # union of windows with >= 1 device leg in flight (overlap
@@ -231,7 +227,7 @@ class DeviceFeeder:
         self.device_items_by_op: dict[str, int] = {}
 
     def perf_summary(self) -> dict[str, float]:
-        """Observed MB/s per (op, backend) — /metrics + bench surface."""
+        """Observed MB/s per (op, backend), for bench.py."""
         with self._perf_lock:
             return {f"{op}/{be}": round(b / t / 1e6, 1)
                     for (op, be), (b, t) in self._perf.items() if t > 0}

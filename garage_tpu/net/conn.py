@@ -35,6 +35,13 @@ full-payload copies per block RPC vs msgpack-embedding the bytes
 Concurrency invariant: ALL outgoing records flow through _send_loop (the
 single writer) — the AEAD nonce counter and frame ordering both depend
 on it. CANCEL/CREDIT are enqueued control items, never written directly.
+
+What a node's wire connections do is counted in plain integers on one
+`WireStats` that its NetApp hands to every channel it builds: frames
+and their bytes each way, records the kernel took in part, time parked
+in `drain()`, and how long a message waited in the send queue before its
+first frame went. /metrics renders it as `rpc_net_*`. LocalChannel
+frames are no wire frames and are not counted.
 """
 
 from __future__ import annotations
@@ -44,6 +51,7 @@ import hashlib
 import hmac as hmac_mod
 import logging
 import struct
+from time import perf_counter
 from typing import Awaitable, Callable, Optional
 
 try:
@@ -173,6 +181,48 @@ class HandshakeError(RpcError):
     pass
 
 
+class WireStats:
+    """What a node's wire connections did, live and closed, as plain
+    integers and seconds: the hot path pays additions."""
+
+    __slots__ = ("sent", "sent_bytes", "recv", "recv_bytes", "partial",
+                 "partial_bytes", "drain_s", "queued", "queue_s")
+
+    def __init__(self):
+        self.sent = self.sent_bytes = 0  # frames written, wire bytes
+        self.recv = self.recv_bytes = 0  # frames read, wire bytes
+        # frames the kernel took only in part when write() sent them
+        # (the transport's buffer was empty before, not after), and the
+        # bytes left for the loop's writer callback on a later turn
+        self.partial = self.partial_bytes = 0
+        self.drain_s = 0.0  # seconds parked in drain(), over `sent`
+        self.queued = 0  # messages (not control frames) sent
+        self.queue_s = 0.0  # enqueue -> first frame handed to send_frame
+
+
+async def _write_record(writer, stats: WireStats, record: bytes) -> None:
+    """One record to the transport, then drain() — counted: a record
+    the kernel took only in part leaves bytes in the transport's
+    buffer, and drain() parks the connection's single send loop (and
+    every message queued behind it) while that buffer is over its
+    high-water mark. A record written while an earlier one's rest is
+    still buffered is only appended to it (the kernel is not asked), so
+    it is no partial write."""
+    transport = writer.transport
+    before = transport.get_write_buffer_size()
+    writer.write(record)
+    stats.sent += 1
+    stats.sent_bytes += len(record)
+    if not before:
+        left = transport.get_write_buffer_size()
+        if left:
+            stats.partial += 1
+            stats.partial_bytes += left
+    t0 = perf_counter()
+    await writer.drain()
+    stats.drain_s += perf_counter() - t0
+
+
 class PlainChannel:
     """No-crypto record layer for the `cryptography`-less fallback:
     [u32 len][u32 req_id][u32 field][payload]. Cluster membership is
@@ -182,22 +232,24 @@ class PlainChannel:
 
     max_chunk = MAX_CHUNK
 
-    def __init__(self, reader, writer):
+    def __init__(self, reader, writer, stats: WireStats):
         self.reader = reader
         self.writer = writer
+        self.stats = stats
 
     async def send_frame(self, req_id: int, field: int,
                          parts: list = ()) -> None:
         payload = b"".join(
             p if isinstance(p, (bytes, bytearray)) else bytes(p)
             for p in parts)
-        self.writer.write(struct.pack("<III", len(payload) + 8,
-                                      req_id, field) + payload)
-        await self.writer.drain()
+        await _write_record(self.writer, self.stats, struct.pack(
+            "<III", len(payload) + 8, req_id, field) + payload)
 
     async def recv_frame(self) -> tuple[int, int, list]:
         (n,) = struct.unpack("<I", await self.reader.readexactly(4))
         body = await self.reader.readexactly(n)
+        self.stats.recv += 1
+        self.stats.recv_bytes += 4 + n
         req_id, field = struct.unpack_from("<II", body)
         return req_id, field, [memoryview(body)[8:]]
 
@@ -208,7 +260,8 @@ class PlainChannel:
             pass  # lint: ignore[GL05] transport close is best-effort
 
 
-async def _plain_client_handshake(reader, writer, netid: bytes, privkey
+async def _plain_client_handshake(reader, writer, netid: bytes, privkey,
+                                  stats: WireStats
                                   ) -> tuple[bytes, PlainChannel]:
     """Initiator side of the no-crypto fallback: three messages so BOTH
     directions prove LIVE knowledge of the cluster secret (each side's
@@ -240,10 +293,11 @@ async def _plain_client_handshake(reader, writer, netid: bytes, privkey
     # confirm over the server's fresh nonce (inside head): we are live
     writer.write(_hmac(netid, b"cli-plain", hello, head))
     await writer.drain()
-    return srv_pub, PlainChannel(reader, writer)
+    return srv_pub, PlainChannel(reader, writer, stats)
 
 
-async def _plain_server_handshake(reader, writer, netid: bytes, privkey
+async def _plain_server_handshake(reader, writer, netid: bytes, privkey,
+                                  stats: WireStats
                                   ) -> tuple[bytes, PlainChannel]:
     """Acceptor side of the no-crypto fallback. The hello MAC alone is
     replayable (it covers only client-chosen bytes), so the channel is
@@ -269,7 +323,7 @@ async def _plain_server_handshake(reader, writer, netid: bytes, privkey
     if not hmac_mod.compare_digest(
             confirm, _hmac(netid, b"cli-plain", hello, head)):
         raise HandshakeError("peer failed the liveness confirm")
-    return cli_pub, PlainChannel(reader, writer)
+    return cli_pub, PlainChannel(reader, writer, stats)
 
 
 async def client_handshake(
@@ -277,11 +331,13 @@ async def client_handshake(
     writer: asyncio.StreamWriter,
     netid: bytes,
     privkey: Ed25519PrivateKey,
+    stats: WireStats,
 ) -> tuple[bytes, "SecureChannel"]:
-    """Initiator side. Returns (peer node id, channel)."""
+    """Initiator side. Returns (peer node id, channel); the channel
+    counts into `stats`."""
     if not HAVE_CRYPTO:
         return await _plain_client_handshake(reader, writer, netid,
-                                             privkey)
+                                             privkey, stats)
     pub = privkey.public_key().public_bytes_raw()
     eph = X25519PrivateKey.generate()
     eph_pub = eph.public_key().public_bytes_raw()
@@ -306,7 +362,8 @@ async def client_handshake(
 
     shared = eph.exchange(X25519PublicKey.from_public_bytes(srv_eph))
     secret = _hkdf(shared, b"garage-tpu-channel" + eph_pub + srv_eph)
-    chan = SecureChannel(reader, writer, send_key=_hkdf(secret, b"c2s"), recv_key=_hkdf(secret, b"s2c"))
+    chan = SecureChannel(reader, writer, send_key=_hkdf(secret, b"c2s"), recv_key=_hkdf(secret, b"s2c"),
+                         stats=stats)
     return srv_pub, chan
 
 
@@ -315,11 +372,13 @@ async def server_handshake(
     writer: asyncio.StreamWriter,
     netid: bytes,
     privkey: Ed25519PrivateKey,
+    stats: WireStats,
 ) -> tuple[bytes, "SecureChannel"]:
-    """Acceptor side. Returns (peer node id, channel)."""
+    """Acceptor side. Returns (peer node id, channel); the channel
+    counts into `stats`."""
     if not HAVE_CRYPTO:
         return await _plain_server_handshake(reader, writer, netid,
-                                             privkey)
+                                             privkey, stats)
     hello = await reader.readexactly(len(MAGIC) + 32 + 32)
     mac = await reader.readexactly(32)
     if hello[: len(MAGIC)] != MAGIC:
@@ -344,7 +403,8 @@ async def server_handshake(
 
     shared = eph.exchange(X25519PublicKey.from_public_bytes(cli_eph))
     secret = _hkdf(shared, b"garage-tpu-channel" + cli_eph + eph_pub)
-    chan = SecureChannel(reader, writer, send_key=_hkdf(secret, b"s2c"), recv_key=_hkdf(secret, b"c2s"))
+    chan = SecureChannel(reader, writer, send_key=_hkdf(secret, b"s2c"), recv_key=_hkdf(secret, b"c2s"),
+                         stats=stats)
     return cli_pub, chan
 
 
@@ -354,13 +414,15 @@ class SecureChannel:
 
     max_chunk = MAX_CHUNK
 
-    def __init__(self, reader, writer, send_key: bytes, recv_key: bytes):
+    def __init__(self, reader, writer, send_key: bytes, recv_key: bytes,
+                 stats: WireStats):
         self.reader = reader
         self.writer = writer
         self._send = ChaCha20Poly1305(send_key)
         self._recv = ChaCha20Poly1305(recv_key)
         self._send_ctr = 0
         self._recv_ctr = 0
+        self.stats = stats
 
     def _nonce(self, ctr: int) -> bytes:
         return ctr.to_bytes(12, "little")
@@ -372,12 +434,14 @@ class SecureChannel:
             for p in parts)
         ct = self._send.encrypt(self._nonce(self._send_ctr), pt, None)
         self._send_ctr += 1
-        self.writer.write(struct.pack("<I", len(ct)) + ct)
-        await self.writer.drain()
+        await _write_record(self.writer, self.stats,
+                            struct.pack("<I", len(ct)) + ct)
 
     async def recv_frame(self) -> tuple[int, int, list]:
         (n,) = struct.unpack("<I", await self.reader.readexactly(4))
         ct = await self.reader.readexactly(n)
+        self.stats.recv += 1
+        self.stats.recv_bytes += 4 + n
         pt = self._recv.decrypt(self._nonce(self._recv_ctr), ct, None)
         self._recv_ctr += 1
         req_id, field = struct.unpack_from("<II", pt)
@@ -401,7 +465,7 @@ class _SendItem:
     __slots__ = (
         "req_id", "prio", "body", "buf_idx", "pos", "body_done", "stream",
         "is_error", "done", "kind", "next_chunk", "chunk_state", "prefetch",
-        "window", "order_clock",
+        "window", "order_clock", "t_enq",
     )
 
     def __init__(self, req_id, prio, body, stream, is_error, kind="msg"):
@@ -419,6 +483,9 @@ class _SendItem:
         self.prefetch: Optional[asyncio.Task] = None
         self.window = STREAM_WINDOW
         self.order_clock = 0
+        # perf_counter() at Conn.enqueue until the first frame is
+        # handed to the channel, then 0.0 (control items: never set)
+        self.t_enq = 0.0
         self.done = asyncio.get_event_loop().create_future()
 
 
@@ -456,6 +523,7 @@ class Conn:
         # BOTH endpoints of a frame; defaulted empty for bare tests)
         self.local_id = local_id
         self.chan = channel
+        self.wire: Optional[WireStats] = channel.stats  # None: LocalChannel
         self.handler = handler  # (peer_id, path, prio, order, payload, stream)
         self._next_id = 2 if initiator else 3
         self._send_items: dict[int, _SendItem] = {}
@@ -494,6 +562,8 @@ class Conn:
         is_error: bool = False,
     ) -> _SendItem:
         item = _SendItem(req_id, prio, body, stream, is_error)
+        if self.wire is not None:
+            item.t_enq = perf_counter()
         self._send_items[req_id] = item
         self._send_wakeup.set()
         return item
@@ -648,6 +718,10 @@ class Conn:
         item.order_clock = self._send_clock
         flags_base = F_ERROR if item.is_error else 0
         if not item.body_done:
+            if item.t_enq:  # its first frame: the wait behind the others
+                self.wire.queued += 1
+                self.wire.queue_s += perf_counter() - item.t_enq
+                item.t_enq = 0.0
             parts, n = self._next_body_parts(item, self.chan.max_chunk)
             item.body_done = item.buf_idx >= len(item.body)
             flags = flags_base | (0 if item.body_done else F_CONT)

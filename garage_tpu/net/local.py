@@ -23,6 +23,7 @@ class LocalChannel:
     whole message is one queue item (there is no wire to preempt)."""
 
     max_chunk = 1 << 27
+    stats = None  # no wire frames: the rpc_net_* series count none of these
 
     def __init__(self, tx: asyncio.Queue, rx: asyncio.Queue):
         self.tx = tx

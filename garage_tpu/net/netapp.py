@@ -22,7 +22,8 @@ except ModuleNotFoundError:
 
 from ..utils.background import spawn
 from ..utils.error import RpcError
-from .conn import Conn, SecureChannel, client_handshake, server_handshake
+from .conn import (Conn, SecureChannel, WireStats, client_handshake,
+                   server_handshake)
 from .endpoint import Endpoint
 from .stream import ByteStream
 
@@ -149,6 +150,8 @@ class NetApp:
         self.public_addr = public_addr or bind_addr
         self.endpoints: dict[str, Endpoint] = {}
         self.conns: dict[bytes, Conn] = {}
+        # what every wire connection of this node does (/metrics rpc_net_*)
+        self.wire = WireStats()
         self.on_connected: list[Callable[[bytes, bool], None]] = []
         self.on_disconnected: list[Callable[[bytes], None]] = []
         self._server: Optional[asyncio.base_events.Server] = None
@@ -184,7 +187,8 @@ class NetApp:
     async def _accept(self, reader, writer) -> None:
         try:
             peer_id, chan = await asyncio.wait_for(
-                server_handshake(reader, writer, self.netid, self.privkey), 10.0
+                server_handshake(reader, writer, self.netid, self.privkey,
+                                 self.wire), 10.0
             )
         except Exception as e:
             log.debug("handshake failed from %s: %s", writer.get_extra_info("peername"), e)
@@ -209,7 +213,8 @@ class NetApp:
         try:
             reader, writer = await asyncio.wait_for(asyncio.open_connection(*addr), 10.0)
             peer_id, chan = await asyncio.wait_for(
-                client_handshake(reader, writer, self.netid, self.privkey), 10.0
+                client_handshake(reader, writer, self.netid, self.privkey,
+                                 self.wire), 10.0
             )
             if expected_id is not None and peer_id != expected_id:
                 chan.close()

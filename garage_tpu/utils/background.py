@@ -14,7 +14,14 @@ from dataclasses import dataclass, field
 from enum import Enum
 from typing import Any, Callable, Dict, Optional
 
+from . import tracing
+
 logger = logging.getLogger("garage.background")
+
+# a work() call this long or longer is a span `bg.work` when tracing is
+# on, over its wall time, awaits included; shorter ones, a worker
+# polling an empty queue, cost two clock reads
+BG_SPAN_MIN_S = 0.001
 
 # fire-and-forget tasks retained here until done: asyncio keeps only a
 # weak reference to running tasks, so an un-retained task can be
@@ -68,6 +75,12 @@ class Throttled:
 
 
 WorkerState = Any  # WState | Throttled
+
+
+def _state_name(state: WorkerState) -> str:
+    if isinstance(state, Throttled):
+        return "throttled"
+    return state.value if isinstance(state, WState) else str(state)
 
 
 @dataclass
@@ -142,8 +155,13 @@ class BackgroundRunner:
         return dict(self._infos)
 
     async def _run_worker(self, wid: str, worker: Worker) -> None:
+        # a worker spawned inside a request must not parent its spans to
+        # that request for ever
+        tracing.detach()
         info = self._infos[wid]
         while not self._stopping.is_set():
+            failed = False
+            t0 = time.perf_counter()
             try:
                 info.state = "busy"
                 state = await worker.work()
@@ -151,6 +169,7 @@ class BackgroundRunner:
             except asyncio.CancelledError:
                 raise
             except Exception as e:  # noqa: BLE001 — worker errors backoff+retry
+                failed = True
                 info.errors += 1
                 info.consecutive_errors += 1
                 info.last_error = f"{type(e).__name__}: {e}"
@@ -159,6 +178,11 @@ class BackgroundRunner:
                 # exponential backoff 1s → ~60s, ref worker.rs:206-215
                 delay = min(60.0, 1.0 * (2 ** min(info.consecutive_errors - 1, 6)))
                 state = Throttled(delay)
+            t1 = time.perf_counter()
+            if t1 - t0 >= BG_SPAN_MIN_S:
+                # lint: ignore[GL10] emit buffers; the open+write is one amortized page-cache append per _FLUSH_EVERY spans on an already-open file
+                tracing.record("bg.work", t0, t1, worker=wid,
+                               state="error" if failed else _state_name(state))
             if state is WState.DONE:
                 break
             if state is WState.BUSY:

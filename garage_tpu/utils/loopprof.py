@@ -38,6 +38,8 @@ loop's thread); otherwise there is no handler and no timer.
   asleep, waking) is wall time of `idle` and CPU of whoever is sampled
   next: where the loop sleeps thousands of times a second, `blocked`
   reads low by that much.
+- The same wrapper counts the loop's turns: `_run_once` calls `select`
+  once a turn, zero-timeout polls included (/metrics `node_loop_turns`).
 - busy wall - CPU = the loop's thread had work and was
   not on a core: it waited for the interpreter lock, for the db lock,
   or in a blocking call. Rendered as `clock="blocked"`.
@@ -150,6 +152,7 @@ class LoopProfiler:
     def __init__(self):
         self.running = False
         self.samples = 0
+        self.turns = 0  # calls of select: one a turn of the loop
         self.faults = 0  # samples lost to an error of the handler's own
         # label -> [cpu seconds, wall seconds]
         self.by_root = {r: [0.0, 0.0] for r in ROOTS if r != "idle"}
@@ -211,6 +214,7 @@ class LoopProfiler:
     # -- on the loop's thread ------------------------------------------
 
     def _timed_select(self, timeout=None):
+        self.turns += 1
         if timeout is not None and timeout <= 0:
             return self._select(timeout)
         self._sel_t0 = time.perf_counter()
@@ -281,8 +285,9 @@ class LoopProfiler:
     # -- on any thread ---------------------------------------------------
 
     def snapshot(self) -> Optional[dict]:
-        """{"samples", "root": {label: (cpu, wall)}, "leaf": {...}} —
-        None while the profiler is off (absent, not 0, on /metrics).
+        """{"samples", "turns", "root": {label: (cpu, wall)}, "leaf":
+        {...}} — None while the profiler is off (absent, not 0, on
+        /metrics).
         The label sets never change and `tuple(v)` is one step for the
         interpreter, so a sample taken meanwhile tears no pair."""
         if not self.running:
@@ -290,7 +295,8 @@ class LoopProfiler:
         root = {k: tuple(v) for k, v in self.by_root.items()}
         leaf = {k: tuple(v) for k, v in self.by_leaf.items()}
         root["idle"] = leaf["idle"] = (0.0, self._idle_wall)
-        return {"samples": self.samples, "root": root, "leaf": leaf}
+        return {"samples": self.samples, "turns": self.turns, "root": root,
+                "leaf": leaf}
 
     def write_folded(self, path: str) -> None:
         def name(code, line=None):

@@ -330,7 +330,7 @@ def test_malformed_decode_item_fails_its_caller_only():
 
 # ---------------------------------------------------------------------------
 # the gather, counted once a gather (ISSUE 37): block_gather_seconds,
-# block_gather_fetches, the block.gather span's attrs; rs_decode_patterns
+# block_gather_fetches, the block.gather span's attrs
 # ---------------------------------------------------------------------------
 
 # case -> (systematic holders stopped, parity holders stopped,
@@ -436,32 +436,3 @@ def test_gather_series_and_span(tmp_path, case):
                   for t in params[side]}
         assert series - {"cache_hits", "cache_misses"} <= rendered, name
 
-
-def test_rs_decode_patterns_counts_the_present_sets_launched():
-    """The gauge is the size of decode_bitmat_t's cache, which keeps
-    one expansion a present-set for the life of the process: after a
-    batch of five stripes under three present-sets it reads three."""
-    k, m = 4, 2
-    codec = ErasureCodec(k, m, use_jax=False)
-    f = DeviceFeeder(codec=codec, mode="require")
-    f._device_ok = True
-    block = np.random.default_rng(3).integers(
-        0, 256, 5_000, dtype=np.uint8).tobytes()
-    stripe = _stripe(codec, block)
-    sets = [(0, 1, 2, 4), (1, 2, 3, 5), (0, 2, 4, 5)]
-
-    async def go():
-        rs.decode_bitmat_t.cache_clear()
-        assert f.stats["decode_patterns"] == 0
-        items = [(p, [stripe[i] for i in p], len(block))
-                 for p in sets + sets[:2]]
-        try:
-            got = await f._run_batch_staged(
-                [_Item("decode", it, None) for it in items])
-        finally:
-            await f.stop()
-        assert got == [block] * 5
-        assert f.stats["decode_patterns"] == 3 \
-            == rs.decode_bitmat_t.cache_info().currsize
-
-    run(go())

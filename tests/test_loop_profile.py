@@ -316,6 +316,26 @@ def test_toy_loop_reads_busy_idle_and_blocked(traced):
                for ln in lines)
 
 
+def test_turns_count_the_loops_selects(traced):
+    """Every turn of `_run_once` calls `select` once, zero-timeout polls
+    included: a hundred `sleep(0)` are at least a hundred turns, and
+    the count is gone with the profile."""
+    p = LoopProfiler()
+
+    async def go():
+        assert p.start(asyncio.get_running_loop()) is True
+        t0 = p.snapshot()["turns"]
+        for _ in range(100):
+            await asyncio.sleep(0)
+        await asyncio.sleep(0.01)  # and a sleep is a turn too
+        t1 = p.snapshot()["turns"]
+        p.stop()
+        return t1 - t0
+
+    assert 101 <= asyncio.run(go()) < 1000
+    assert p.snapshot() is None
+
+
 # ---------------------------------------------------------------------------
 # /metrics of a real server process, traced and untraced, and the
 # benchmark's eight metric files on it
@@ -416,6 +436,32 @@ def test_series_render_when_traced_and_are_absent_when_not(served):
     assert lines[0].startswith("idle ") and len(lines) > 10
     assert any("garage_tpu/api/" in ln for ln in lines)
     assert not os.path.exists(off["folded"])
+
+
+WIRE_FAMILIES = ("rpc_net_frames", "rpc_net_partial_writes",
+                 "rpc_net_drain_seconds", "rpc_net_send_queue_seconds")
+GONE = ("feeder_throughput_mbps", "feeder_max_batch", "feeder_inline_items",
+        "rs_decode_patterns")
+
+
+def test_loop_turns_and_wire_series_on_metrics(served):
+    """Traced and untraced, /metrics carries the four rpc_net_* families
+    (a lone node has no wire connection: all 0) and none of the gauges
+    that nothing read; node_loop_turns only where the profile runs."""
+    scrape = _bench("lib", "scrape")
+    for how in ("traced", "untraced"):
+        m0, m1 = (scrape.parse_metrics(served[how][k]) for k in ("m0", "m1"))
+        names = {n for n, _ in m1}
+        for fam in WIRE_FAMILIES:
+            assert {fam + "_count", fam + "_sum"} <= names, (how, fam)
+        assert {ls["dir"] for ls in scrape.labels_of(
+            m1, "rpc_net_frames_count")} == {"send", "recv"}
+        assert not names & set(GONE), how
+        turns = scrape.delta(m0, m1, "node_loop_turns")
+        if how == "traced":
+            assert turns > 100
+        else:
+            assert turns is None
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS)

@@ -197,3 +197,36 @@ def test_otlp_collector_down_never_blocks(tmp_path):
                   "name": f"s{i}", "start_us": 1, "dur_us": 1})
     exp.stop()
     assert exp.sent_spans == 0 and exp.failed_posts >= 1
+
+
+@pytest.mark.parametrize("work_s,spans", [(0.005, 1), (0.0001, 0)])
+def test_background_work_item_span(ring_tracer, work_s, spans):
+    """A background work item of 1 ms or more is one `bg.work` span with
+    its worker id and what work() returned, a trace of its own though
+    the worker was spawned inside a request; a shorter one is none."""
+    import time
+
+    from garage_tpu.utils import background
+
+    class W(background.Worker):
+        name = "sleeper"
+
+        async def work(self):
+            time.sleep(work_s)  # holds the loop, as a resync item does
+            return background.WState.DONE
+
+    async def main():
+        runner = background.BackgroundRunner()
+        async with span("http.request") as req:
+            runner.spawn_worker(W())
+        await asyncio.sleep(0.05)  # the worker runs its one item
+        await runner.shutdown()
+        return req.ids[0]
+
+    request_trace = asyncio.run(main())
+    got = [r for r in tracer.ring if r["name"] == "bg.work"]
+    assert len(got) == spans
+    for r in got:
+        assert r["attrs"] == {"worker": "1:sleeper", "state": "done"}
+        assert r["parent"] is None and r["trace"] != request_trace
+        assert r["dur_us"] >= 1000
