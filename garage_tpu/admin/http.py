@@ -1156,6 +1156,9 @@ class AdminHttpServer:
         ps = feeder.pipeline_stats()
         gauge("feeder_inflight", ps["inflight"],
               "Batches currently in flight through the staged pipeline")
+        gauge("feeder_loop_crossings", ps["loop_crossings"],
+              "Callbacks the stage threads made to the event loop: one "
+              "a device leg, when it is done")
         gauge("feeder_pipeline_wall_seconds", ps["wall_s"],
               "Wall-clock union of windows with a device leg in flight")
         gauge("feeder_overlap_efficiency", ps["overlap_efficiency"],

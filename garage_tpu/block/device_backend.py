@@ -11,10 +11,12 @@ returned. This module is the staged replacement:
   thread, so stage N of batch B+1 runs WHILE stage N+1 of batch B runs:
   with the feeder's bounded in-flight depth that is classic
   double-buffering (batch N computes while N+1's bytes move h2d and
-  N-1's results read back). Threads are daemon and generations are
-  disposable: a hung device call is ABANDONED (the feeder marks the
-  generation dead) instead of joined — a stuck non-daemon pool thread
-  would wedge interpreter exit.
+  N-1's results read back). A device leg is one `StageJob` that the
+  threads hand on h2d -> compute -> d2h among themselves; it crosses
+  back to the event loop once, when it is done. Threads are daemon and
+  generations are disposable: a hung device call is ABANDONED (the
+  feeder marks the generation dead) instead of joined — a stuck
+  non-daemon pool thread would wedge interpreter exit.
 
 - `JaxDeviceBackend`: the real accelerator route, split into the three
   stages, with **fixed-shape padded launches**: item counts are padded
@@ -108,104 +110,161 @@ def group_bytes(op: str, blobs: list) -> int:
 
 
 class StageJob:
-    """One submitted stage execution. `claimed` flips True (worker
-    thread, GIL-atomic) the instant the fn starts running — the feeder
-    uses it to tell "queued, safely skippable" from "already executing,
-    must be waited out" when a watchdog/abort cancels the future. A job
-    cancelled BEFORE it is claimed is never executed at all: stage fns
-    can carry side effects (the d2h MD5 lane advance), and running one
-    after its batch already failed over to the host path would apply
-    those effects twice. `busy` is the fn's exclusive execution time —
-    what the feeder records as device time, NOT the pipeline wall
-    (which includes queue wait behind sibling batches and would
-    understate device throughput by up to the in-flight depth).
-    `t_sub`, `t_claim`, `t_done` are `perf_counter` stamps of submit,
-    claim and fn return (0.0 = not reached); the thread only writes its
-    own two, and the coroutine that waits for the job observes them on
-    the loop."""
+    """One device leg on its way through the stages. `steps` is
+    [(stage, fn)]: each fn runs on its stage's thread with what the one
+    before it returned (the first with None), and the stage threads hand
+    the job on among themselves. When the last step returns, or any
+    step raises, the thread that ran it makes ONE
+    `loop.call_soon_threadsafe(done, job)`: the leg's only crossing back
+    to the event loop.
 
-    __slots__ = ("loop", "fut", "fn", "claimed", "busy",
-                 "t_sub", "t_claim", "t_done")
+    `state`, under `lock`, is the hand-shake between the threads and the
+    loop: "queued" at step `at`, "running" it, "sent" (the callback is
+    on its way) or "abandoned". A job abandoned before a stage claims it
+    never runs there: stage fns carry side effects (the d2h MD5 lane
+    advance), and running one after its leg already failed over to the
+    host would apply them twice. A claimed step runs to its end; if the
+    job was let go of meanwhile, nothing more runs and nothing crosses.
+    Per step, `t_sub`, `t_claim`, `t_done` are `perf_counter` stamps of
+    queued, claimed and returned (0.0 = not reached) and `busy` its
+    execution time — the device time the feeder records, not the wall,
+    which includes queueing behind sibling legs. The threads write
+    them; the loop reads them once the job is back or let go of."""
 
-    def __init__(self, loop, fn):
+    __slots__ = ("loop", "steps", "done", "lock", "state", "at", "value",
+                 "error", "t_sub", "t_claim", "t_done", "busy")
+
+    def __init__(self, loop, steps, done):
         self.loop = loop
-        self.fut = loop.create_future()
-        self.fn = fn
-        self.claimed = False
-        self.busy = 0.0
-        self.t_sub = time.perf_counter()
-        self.t_claim = self.t_done = 0.0
+        self.steps = steps
+        self.done = done
+        self.lock = threading.Lock()
+        self.state = "queued"
+        self.at = 0
+        self.value = self.error = None
+        n = len(steps)
+        self.t_sub = [0.0] * n
+        self.t_claim = [0.0] * n
+        self.t_done = [0.0] * n
+        self.busy = [0.0] * n
+
+    @property
+    def stage(self) -> str:
+        return self.steps[self.at][0]
+
+    def finished(self) -> bool:
+        """Every step returned."""
+        return self.error is None and self.t_done[-1] > 0.0
+
+    def abandon(self, running: bool = False) -> bool:
+        """Let go of the job from the loop: a queued job never runs
+        again, and with `running` neither does one whose step is running
+        now (the step ends, its result is dropped). A job whose callback
+        is on its way is kept. -> True if the job is let go of."""
+        with self.lock:
+            if self.state == "queued" or (running
+                                          and self.state == "running"):
+                self.state = "abandoned"
+            return self.state == "abandoned"
 
 
 class StageExecutor:
-    """One daemon worker thread running one pipeline stage's jobs in
-    submission order. Results are delivered to the submitting event
-    loop via call_soon_threadsafe; a job whose future was cancelled
-    before execution is skipped entirely, one cancelled mid-execution
-    completes silently. Busy seconds accumulate into the shared
-    per-stage dict — the numerator of the overlap-efficiency metric."""
+    """One daemon worker thread running one pipeline stage's steps in
+    the order they were queued, and handing each job on (`hand_on`).
+    Busy seconds accumulate into the shared per-stage dict — the
+    numerator of the overlap-efficiency metric."""
 
-    def __init__(self, name: str, busy: dict):
+    def __init__(self, name: str, busy: dict, hand_on):
         self.name = name
         self._busy = busy
+        self._hand_on = hand_on
         self._jobs: queue.SimpleQueue = queue.SimpleQueue()
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name=f"feeder-{name}")
         self._thread.start()
 
-    def submit(self, loop, fn) -> StageJob:
-        job = StageJob(loop, fn)
+    def put(self, job: StageJob) -> None:
+        job.t_sub[job.at] = time.perf_counter()
         self._jobs.put(job)
-        return job
+
+    def close(self) -> None:
+        """The thread ends once it reaches this in its queue."""
+        self._jobs.put(None)
 
     def _loop(self) -> None:
         while True:
             job = self._jobs.get()
-            if job.fut.cancelled():
-                continue  # abandoned while queued: never execute
-            job.claimed = True
-            job.t_claim = time.perf_counter()
+            if job is None:
+                return
+            with job.lock:
+                if job.state == "abandoned":
+                    continue  # let go of while queued: never run
+                job.state = "running"
+            i = job.at
+            job.t_claim[i] = time.perf_counter()
             try:
-                res, err = job.fn(), None
+                job.value = job.steps[i][1](job.value)
             except BaseException as e:
-                res, err = None, e
-            job.t_done = time.perf_counter()
-            job.busy = job.t_done - job.t_claim
-            self._busy[self.name] += job.busy
-
-            def deliver(fut=job.fut, res=res, err=err):
-                if fut.cancelled():
-                    return  # abandoned by the watchdog mid-execution
-                if err is not None:
-                    fut.set_exception(err)
-                else:
-                    fut.set_result(res)
-
-            try:
-                job.loop.call_soon_threadsafe(deliver)
-            except RuntimeError:
-                # loop already closed (feeder stopped mid-batch):
-                # the caller's future is moot, nothing to deliver to
-                pass
+                job.error = e
+            job.t_done[i] = time.perf_counter()
+            job.busy[i] = job.t_done[i] - job.t_claim[i]
+            self._busy[self.name] += job.busy[i]
+            self._hand_on(self, job)
 
 
 class DevicePipeline:
-    """One GENERATION of the three stage executors plus its abort
-    event. On a hang the feeder marks the generation dead and sets
-    `aborted` so every sibling in-flight batch bails to the host path
-    immediately instead of each waiting out its own full watchdog; the
-    next device batch gets a fresh generation (fresh threads — the
-    stuck ones are abandoned)."""
+    """One GENERATION of the three stage executors. A job goes from
+    stage to stage on the threads and crosses to its loop once, at the
+    end (`crossings[stage]` counts the crossings each stage's thread
+    made). On a hang the feeder marks the generation `dead`: its threads
+    then hand no job on — a job whose step returns crosses back where it
+    is — and the feeder lets go of every job still queued; the next
+    device batch gets a fresh generation (fresh threads — the stuck ones
+    are abandoned, never joined)."""
 
-    def __init__(self, busy: dict):
-        import asyncio
-
+    def __init__(self, busy: dict, crossings: dict):
         self.dead = False
-        self.aborted = asyncio.Event()
-        self._execs = {s: StageExecutor(s, busy) for s in STAGES}
+        self._crossings = crossings
+        self._execs = {s: StageExecutor(s, busy, self._hand_on)
+                       for s in STAGES}
 
-    def submit(self, stage: str, loop, fn) -> StageJob:
-        return self._execs[stage].submit(loop, fn)
+    def submit(self, loop, steps: list, done) -> StageJob:
+        job = StageJob(loop, steps, done)
+        self._execs[job.stage].put(job)
+        return job
+
+    def close(self) -> None:
+        """End the generation's threads when they next turn to their
+        queues: a job that is still on its way through the stages is not
+        run further and never crosses back, so a generation is closed
+        once nothing waits for its jobs — when it is dead, or its
+        feeder stopped. A thread stuck in a step ends when it returns."""
+        for ex in self._execs.values():
+            ex.close()
+
+    def _hand_on(self, ex: StageExecutor, job: StageJob) -> None:
+        """On a stage thread, after a step: on to the next stage, or
+        back to the loop in one callback."""
+        last = (job.error is not None or self.dead
+                or job.at + 1 == len(job.steps))
+        with job.lock:
+            if job.state == "abandoned":
+                return  # let go of while this step ran
+            if last:
+                job.state = "sent"
+            else:
+                job.state = "queued"
+                job.at += 1
+        if not last:
+            self._execs[job.stage].put(job)
+            return
+        self._crossings[ex.name] += 1
+        try:
+            job.loop.call_soon_threadsafe(job.done, job)
+        except RuntimeError:
+            # loop already closed (feeder stopped mid-batch): the
+            # callers' futures are moot, nothing to deliver to
+            pass
 
 
 # ---------------------------------------------------------------------------

@@ -41,19 +41,23 @@ The device route is a STAGED PIPELINE (block/device_backend.py): each
 batch flows h2d -> compute -> d2h through three dedicated worker
 threads, and the dispatcher keeps up to `[tpu] inflight_batches`
 batches in flight — while batch N computes, batch N+1's bytes are
-already moving h2d and batch N-1's results are reading back. Launch
-shapes are padded to a small bucket set so XLA compiles a handful of
-programs (`feeder_pad_waste_bytes` / `feeder_recompiles` price that
-trade; `feeder_xla_compiles` is JAX's own count), and batches of
->= `[tpu] mesh_min_items` items shard across every visible chip via
-parallel/mesh.py. The watchdog (`[tpu] batch_timeout_s`) covers every
-in-flight stage: a hang abandons the stage threads and disables the
-device path.
+already moving h2d and batch N-1's results are reading back. A batch's
+device leg (one op group) is one job the stage threads hand on among
+themselves; it crosses back to the event loop once, in a callback that
+resolves that leg's items there and then, whatever its sibling legs
+are doing. Launch shapes are padded to a small bucket set so XLA
+compiles a handful of programs (`feeder_pad_waste_bytes` /
+`feeder_recompiles` price that trade; `feeder_xla_compiles` is JAX's
+own count), and batches of >= `[tpu] mesh_min_items` items shard
+across every visible chip via parallel/mesh.py. One watchdog timer a
+batch (`[tpu] batch_timeout_s`) covers every stage of its legs: a hang
+abandons the stage threads and disables the device path.
 """
 
 from __future__ import annotations
 
 import asyncio
+import functools
 import logging
 import os
 import threading
@@ -91,8 +95,8 @@ _DEVICE_PLATFORM = "tpu"
 
 
 class _DeviceHang(Exception):
-    """A device pipeline stage hung (or a sibling batch's stage did and
-    aborted the generation)."""
+    """A sibling's hang killed the pipeline generation before the job
+    reached its last stage."""
 
 
 class _Item:
@@ -119,6 +123,51 @@ class _Item:
             self.future.set_exception(res)
         else:
             self.future.set_result(res)
+
+
+class _Batch:
+    """A batch the dispatcher handed on. It owns its items' futures and
+    holds an in-flight slot until every part of it is back: its device
+    legs (`legs`) and, where the host runs some, one worker-thread task
+    (`tasks`, with any host re-runs); `left` counts the parts still out.
+    `timer` is its watchdog, `span` is `feeder.batch`."""
+
+    __slots__ = ("items", "legs", "tasks", "left", "timer", "span")
+
+    def __init__(self, items: list):
+        self.items = items
+        self.legs: list = []
+        self.tasks: list = []
+        self.left = 0
+        self.timer = None
+        self.span = tracing.open_span(
+            "feeder.batch", items=len(items),
+            ops=",".join(sorted({it.op for it in items})))
+
+
+class _Leg:
+    """One op group of a batch on the device: `job` goes down the stage
+    threads and comes back in one loop callback, which resolves the
+    leg's own items (`idxs` into the batch's). `back`: the loop has
+    taken its outcome or let go of it."""
+
+    __slots__ = ("batch", "op", "perf_op", "idxs", "nbytes", "job", "back")
+
+    def __init__(self, batch: _Batch, op: str, perf_op: str, idxs: list,
+                 nbytes: int):
+        self.batch = batch
+        self.op = op
+        self.perf_op = perf_op
+        self.idxs = idxs
+        self.nbytes = nbytes
+        self.job = None
+        self.back = False
+
+
+def _wake(fut, _job) -> None:
+    """A chain's callback for a coroutine that waits for it."""
+    if not fut.done():
+        fut.set_result(None)
 
 
 class DeviceFeeder:
@@ -207,15 +256,19 @@ class DeviceFeeder:
                       "decode_items": 0, "decode_device_items": 0,
                       "decode_device_bytes": 0}
         # staged pipeline state: the current executor generation, the
-        # batches in flight, per-stage busy seconds and the wall-clock
-        # union of windows with >= 1 device leg in flight (overlap
-        # efficiency = sum(busy) / wall; > 1.0 means stages overlap)
+        # batches in flight (and the dispatcher's wait for a slot),
+        # per-stage busy seconds, the crossings back to the loop each
+        # stage's thread made, and the wall-clock union of windows with
+        # >= 1 device leg in flight (overlap efficiency = sum(busy) /
+        # wall; > 1.0 means stages overlap)
         self._pl: Optional[DevicePipeline] = None
         self._pl_busy: dict[str, float] = {s: 0.0 for s in STAGES}
+        self._pl_crossings: dict[str, int] = {s: 0 for s in STAGES}
         self._pl_wall = 0.0
         self._win_open = 0
         self._win_t0 = 0.0
-        self._inflight_tasks: set = set()
+        self._inflight: set = set()
+        self._slot_free: Optional[asyncio.Future] = None
         # PUT streams currently inside read_and_put_blocks: sizes the
         # hash_md5 gather window (one block hash in flight per stream)
         self.active_streams = 0
@@ -325,9 +378,9 @@ class DeviceFeeder:
             return
         try:
             info = await asyncio.wait_for(
-                self._stage_call(self._pipeline(), "h2d",
-                                 lambda: self._get_backend().verdict(), [],
-                                 "verdict"),
+                self._await_chain(
+                    [("h2d", lambda _: self._get_backend().verdict())],
+                    "verdict"),
                 self.batch_timeout)
         except (asyncio.TimeoutError, _DeviceHang):
             self._on_device_hang(f"jax.devices() did not return within "
@@ -386,8 +439,10 @@ class DeviceFeeder:
             n = 1  # the fewest items that launch the next bucket
             while n <= max_items:
                 for op, item in legs:
-                    await asyncio.wait_for(self._staged_op(op, [item] * n),
-                                           self.batch_timeout)
+                    await asyncio.wait_for(
+                        self._await_chain(self._leg_steps(op, [item] * n),
+                                          op, n),
+                        self.batch_timeout)
                 n = bucket_items(n, self.pad_buckets) + 1
             after = jaxenv.compile_stats()
             said.append("%s to %d items in %.1f s (%d requested, %d built)" % (
@@ -424,8 +479,9 @@ class DeviceFeeder:
         # reads self._q between suspension points; a respawn swaps in
         # a fresh queue object, so draining the snapshot can never
         # touch the respawn's submissions
-        inflight = list(self._inflight_tasks)
-        self._inflight_tasks.clear()
+        inflight = list(self._inflight)
+        self._inflight.clear()
+        pl, self._pl = self._pl, None  # a respawn starts a generation
         for bg in (t, vt):
             if bg is not None:
                 bg.cancel()
@@ -433,15 +489,29 @@ class DeviceFeeder:
                     await bg
                 except (asyncio.CancelledError, Exception):
                     pass
-        # cancel every in-flight pipelined batch THIS stop snapshotted:
-        # each _finish_batch fails its items' futures on the way out,
-        # so no caller hangs on a batch that was mid-stage
-        for bt in inflight:
-            bt.cancel()
-            try:
-                await bt
-            except (asyncio.CancelledError, Exception):
-                pass
+        # every in-flight batch THIS stop snapshotted: its items fail (a
+        # result already given stands), its legs are let go of and its
+        # worker-thread tasks cancelled, so no caller hangs on a batch
+        # that was mid-stage
+        stopped = RuntimeError("feeder stopped")
+        for b in inflight:
+            if b.timer is not None:
+                b.timer.cancel()
+            for item in b.items:
+                item.resolve(stopped)
+            for leg in b.legs:
+                if not leg.back:
+                    leg.back = True
+                    leg.job.abandon(running=True)
+                    self._leg_done(leg)
+            for task in list(b.tasks):
+                task.cancel()
+                try:
+                    await task
+                except (asyncio.CancelledError, Exception):
+                    pass
+        if pl is not None:
+            pl.close()  # every job of it was let go of above
         # fail anything still queued on the OLD queue so no caller
         # awaits forever
         if q is not None:
@@ -802,17 +872,17 @@ class DeviceFeeder:
                 # batch to the staged pipeline and goes straight back
                 # to draining the queue / forming the next batch —
                 # while batch N computes, batch N+1 stages h2d and
-                # batch N-1 reads back. Depth is live-tunable
+                # batch N-1 reads back. A slot is free again when a
+                # batch's last part is back. Depth is live-tunable
                 # ([tpu] inflight_batches via /v1/s3/tuning).
-                if len(self._inflight_tasks) >= max(
-                        1, self.inflight_batches):
+                if len(self._inflight) >= max(1, self.inflight_batches):
                     with tracing.span("feeder.slot_wait", op=first.op,
-                                      inflight=len(self._inflight_tasks)):
-                        while len(self._inflight_tasks) >= max(
+                                      inflight=len(self._inflight)):
+                        while len(self._inflight) >= max(
                                 1, self.inflight_batches):
-                            await asyncio.wait(
-                                self._inflight_tasks,
-                                return_when=asyncio.FIRST_COMPLETED)
+                            free = asyncio.get_running_loop().create_future()
+                            self._slot_free = free
+                            await free
                 # hand-off 1 ends here: drain, linger and slot wait
                 now = time.perf_counter()
                 reg = registry()
@@ -820,10 +890,7 @@ class DeviceFeeder:
                     item.t_disp = now
                     reg.observe("feeder_queue_wait_seconds",
                                 now - item.t_sub, op=item.op)
-                t = asyncio.create_task(self._finish_batch(batch),
-                                        name="feeder-batch")
-                self._inflight_tasks.add(t)
-                t.add_done_callback(self._inflight_tasks.discard)
+                self._dispatch(batch)
             except BaseException as e:
                 self._fail_batch(batch, e)
                 if isinstance(e, asyncio.CancelledError):
@@ -836,95 +903,74 @@ class DeviceFeeder:
         for item in batch:
             item.resolve(err)
 
-    async def _finish_batch(self, batch: list) -> None:
-        """Run one batch through plan + execution and resolve every
-        item future — the one owner of a batch's futures, whatever the
-        route (host thread, staged device pipeline, hang fallback)."""
-        try:
-            with tracing.span("feeder.batch", items=len(batch),
-                              ops=",".join(sorted({it.op for it in batch}))):
-                results = await self._run_batch_staged(batch)
-            for item, res in zip(batch, results):
-                item.resolve(res)
-        except BaseException as e:
-            self._fail_batch(batch, e)
-            if isinstance(e, asyncio.CancelledError):
-                raise
-
-    async def _run_batch_staged(self, batch: list) -> list:
-        """Plan the batch, then execute host legs in a worker thread
-        and device legs through the staged pipeline, concurrently."""
+    def _dispatch(self, items: list) -> None:
+        """Plan a batch and send it on. Each device leg goes down the
+        stage threads as one job and, when it is back, resolves its own
+        items in its one loop callback (_leg_back): no task, and no
+        wait for its sibling legs. Host legs (mode "off", "auto" before
+        or without a chip) run in one worker thread. The batch holds
+        its in-flight slot until its last part is back."""
         self.stats["batches"] += 1
-        self.stats["items"] += len(batch)
+        self.stats["items"] += len(items)
         self.stats["decode_items"] += sum(
-            1 for it in batch if it.op in ("decode", "repair"))
-        self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
-        results: list = [None] * len(batch)
-        on_device, on_host = self._plan_batch(batch)
-        if not on_device:
-            # pure host batch: exactly the pre-pipeline behavior (one
-            # thread hop), still bounded so a stalled host path fails
-            # the batch instead of wedging a pipeline slot forever
-            await asyncio.wait_for(
-                asyncio.to_thread(self._exec_legs, batch, on_host, results),
-                self.batch_timeout)
-            return results
-        tasks = [asyncio.create_task(
-            self._exec_device_leg(op, perf_op, batch, idxs, results))
-            for op, perf_op, idxs in on_device]
+            1 for it in items if it.op in ("decode", "repair"))
+        self.stats["max_batch"] = max(self.stats["max_batch"], len(items))
+        on_device, on_host = self._plan_batch(items)
+        # whatever can raise comes before the batch takes its slot: the
+        # dispatcher then fails the items, and no slot is held for ever
+        legs = []
+        for op, perf_op, idxs in on_device:
+            blobs = [items[i].data for i in idxs]
+            legs.append((op, perf_op, idxs, blobs, group_bytes(op, blobs)))
+        pl = self._pipeline() if legs else None
+        b = _Batch(items)
+        b.left = len(legs) + (1 if on_host else 0)
+        self._inflight.add(b)
         if on_host:
-            tasks.append(asyncio.create_task(asyncio.wait_for(
-                asyncio.to_thread(self._exec_legs, batch, on_host,
-                                  results),
-                self.batch_timeout)))
-        try:
-            await asyncio.gather(*tasks)
-        except BaseException:
-            for t in tasks:
-                t.cancel()
-            await asyncio.gather(*tasks, return_exceptions=True)
-            raise
-        return results
+            b.tasks.append(asyncio.create_task(self._on_host(b, on_host),
+                                               name="feeder-host"))
+        if not legs:
+            return
+        loop = asyncio.get_running_loop()
+        b.timer = loop.call_later(self.batch_timeout, self._timed_out, b)
+        for op, perf_op, idxs, blobs, nbytes in legs:
+            leg = _Leg(b, op, perf_op, idxs, nbytes)
+            b.legs.append(leg)
+            self._window_open()
+            leg.job = pl.submit(loop, self._leg_steps(op, blobs),
+                                functools.partial(self._leg_back, leg))
 
-    async def _exec_device_leg(self, op: str, perf_op: str, batch: list,
-                               idxs: list, results: list) -> None:
-        """One device-routed op group: staged h2d -> compute -> d2h
-        with the watchdog over ALL stages. A leg that raises (compile
-        error, out of memory, refused kernel) or hangs is a device
-        error: under "require" its items fail with it, under "auto"
-        the group re-runs host-side (_device_leg_failed)."""
-        blobs = [batch[i].data for i in idxs]
-        total = group_bytes(op, blobs)
-        self._window_open()
-        try:
-            try:
-                out, busy = await asyncio.wait_for(
-                    self._staged_op(op, blobs), self.batch_timeout)
-            except (asyncio.TimeoutError, _DeviceHang):
-                # hung device stage: abandon the stuck stage threads
-                # and shut the device path — the abort event makes
-                # sibling batches take this same branch immediately
-                # instead of each waiting out its own watchdog
-                reason = (f"device {op} batch stuck "
-                          f">{self.batch_timeout:g}s")
-                self._on_device_hang(reason)
-                await self._device_leg_failed(
-                    op, perf_op, batch, idxs, results, TimeoutError(reason))
-                return
-            except Exception as e:
-                await self._device_leg_failed(op, perf_op, batch, idxs,
-                                              results, e)
-                return
-            for i, o in zip(idxs, out):
-                results[i] = o
-            # the EXCLUSIVE stage execution time, not this coroutine's
-            # wall: wall includes queue wait behind sibling batches in
-            # the single-thread stage executors, which would understate
-            # device throughput by up to the in-flight depth
-            self._record(perf_op, "device", total, busy)
-            self._credit_device(op, len(idxs), total)
-        finally:
-            self._window_close()
+    def _leg_steps(self, op: str, blobs: list) -> list:
+        """A device leg's three steps, each fed what the one before it
+        returned."""
+        be = self._get_backend
+        return [("h2d", lambda _: be().stage(op, blobs)),
+                ("compute", lambda staged: be().compute(op, staged)),
+                ("d2h", lambda handle: be().readback(op, handle))]
+
+    def _leg_back(self, leg: _Leg, job) -> None:
+        """A device leg's one crossing, a loop callback: its items are
+        resolved now, whatever its sibling legs are doing. A leg that
+        raised (compile error, out of memory, refused kernel), or that
+        a dead generation stopped short, is a device error."""
+        if leg.back:
+            return  # the loop let go of it already (watchdog, stop)
+        leg.back = True
+        self._observe(job, leg.op, len(leg.idxs), leg.batch.span)
+        if not job.finished():
+            self._leg_failed(leg, job.error
+                             or TimeoutError(self._stuck(leg.op)))
+            return
+        items = leg.batch.items
+        for i, res in zip(leg.idxs, job.value):
+            items[i].resolve(res)
+        # the EXCLUSIVE stage execution time, not the leg's wall: wall
+        # includes queueing behind sibling legs in the single-thread
+        # stage executors, which would understate device throughput by
+        # up to the in-flight depth
+        self._record(leg.perf_op, "device", leg.nbytes, sum(job.busy))
+        self._credit_device(leg.op, len(leg.idxs), leg.nbytes)
+        self._leg_done(leg)
 
     def _credit_device(self, op: str, n: int, total: int) -> None:
         self.stats["device_batches"] += 1
@@ -935,109 +981,164 @@ class DeviceFeeder:
             self.stats["decode_device_items"] += n
             self.stats["decode_device_bytes"] += total
 
-    async def _device_leg_failed(self, op: str, perf_op: str, batch: list,
-                                 idxs: list, results: list,
-                                 err: BaseException) -> None:
+    def _leg_failed(self, leg: _Leg, err: BaseException) -> None:
         """A device leg raised or hung. "require": the items fail with
         the device's own error — no host result may stand in for it.
-        "auto": re-run the group on the host, counted in host_reruns."""
+        "auto": the group re-runs on the host, counted in host_reruns."""
         self.stats["device_errors"] += 1
+        items = leg.batch.items
         if self.mode == "require":
             log.error("device %s batch failed (%s: %s); device is "
-                      "required, failing %d item(s)", op,
-                      type(err).__name__, err, len(idxs))
-            for i in idxs:
-                results[i] = err
+                      "required, failing %d item(s)", leg.op,
+                      type(err).__name__, err, len(leg.idxs))
+            for i in leg.idxs:
+                items[i].resolve(err)
+            self._leg_done(leg)
             return
         log.warning("device %s batch failed (%s: %s); re-running on "
-                    "the host", op, type(err).__name__, err)
+                    "the host", leg.op, type(err).__name__, err)
         self.stats["host_reruns"] += 1
-        await asyncio.wait_for(
-            asyncio.to_thread(self._exec_group, op, perf_op, batch, idxs,
-                              results),
-            self.batch_timeout)
+        leg.batch.tasks.append(asyncio.create_task(
+            self._on_host(leg.batch, [(leg.op, leg.perf_op, leg.idxs)],
+                          leg), name="feeder-rerun"))
 
-    async def _staged_op(self, op: str, blobs: list) -> tuple[list, float]:
-        """h2d -> compute -> d2h through the current pipeline
-        generation's stage threads; -> (results, exclusive device
-        seconds). Each stage of THIS batch runs serially, but the
-        single-thread-per-stage executors let a different batch occupy
-        every other stage at the same time."""
-        pl = self._pipeline()
-        be = self._get_backend
-        busy: list[float] = []
-        n = len(blobs)
-        staged = await self._stage_call(
-            pl, "h2d", lambda: be().stage(op, blobs), busy, op, n)
-        handle = await self._stage_call(
-            pl, "compute", lambda: be().compute(op, staged), busy, op, n)
-        out = await self._stage_call(
-            pl, "d2h", lambda: be().readback(op, handle), busy, op, n)
-        return out, sum(busy)
-
-    async def _stage_call(self, pl: DevicePipeline, stage: str, fn,
-                          busy: list, op: str, items: int = 0):
-        """Run `fn` on the stage's thread and wait for it. Hand-offs 2
-        and 3 are observed here, on the loop, once per job and as far
-        as the job got: a job abandoned in the queue observes nothing,
-        one that hangs only its wait for the thread."""
-        if pl.dead:
-            raise _DeviceHang("pipeline aborted")
-        loop = asyncio.get_running_loop()
-        job = pl.submit(stage, loop, fn)
-        abort = asyncio.create_task(pl.aborted.wait())
+    async def _on_host(self, b: _Batch, legs: list,
+                       leg: Optional[_Leg] = None) -> None:
+        """Op groups of a batch on the host in one worker-thread hop,
+        bounded so a stalled host path fails them instead of wedging a
+        slot: the batch's host legs, or (`leg`) a device leg's re-run."""
+        results: list = [None] * len(b.items)
+        mine = [b.items[i] for _op, _perf_op, idxs in legs for i in idxs]
         try:
-            await asyncio.wait({job.fut, abort},
-                               return_when=asyncio.FIRST_COMPLETED)
-            if not job.fut.done() and job.claimed:
-                # the stage thread is ALREADY EXECUTING this job (the
-                # hung job never yields its thread, so ours is live):
-                # wait it out instead of racing a host re-run against
-                # its side effects — d2h advances the serial MD5 ETag
-                # chains, and abandoning it mid-flight would apply
-                # them twice. Still bounded by the caller's watchdog.
-                await asyncio.wait({job.fut})
-            if job.fut.done():
-                busy.append(job.busy)
-                return job.fut.result()
-            raise _DeviceHang("pipeline aborted by a sibling batch hang")
+            await asyncio.wait_for(
+                asyncio.to_thread(self._exec_legs, b.items, legs, results),
+                self.batch_timeout)
+            for _op, _perf_op, idxs in legs:
+                for i in idxs:
+                    b.items[i].resolve(results[i])
+        except BaseException as e:
+            self._fail_batch(mine, e)
+            if isinstance(e, asyncio.CancelledError):
+                raise
         finally:
-            abort.cancel()
-            now = time.perf_counter()
-            t_claim, t_done = job.t_claim, job.t_done
-            if t_claim:
-                wait = t_claim - job.t_sub
-                reg = registry()
-                reg.observe("feeder_stage_wait_seconds", wait, stage=stage)
-                if t_done and job.fut.done():
-                    reg.observe("feeder_resume_lag_seconds", now - t_done,
-                                hop=stage)
-                    # lint: ignore[GL10] emit buffers; the open+write is one amortized page-cache append per _FLUSH_EVERY spans on an already-open file
-                    tracing.record(f"dev.{stage}", t_claim, t_done, op=op,
-                                   items=items, wait_us=int(wait * 1e6))
-            if not job.fut.done():
-                # abandon: a queued job is skipped outright by the
-                # stage thread (never executed), a claimed one
-                # completes silently with its delivery dropped
-                job.fut.cancel()
+            if leg is None:
+                self._part_done(b)
+            else:
+                self._leg_done(leg)
+
+    def _leg_done(self, leg: _Leg) -> None:
+        leg.job = None  # job -> its callback -> leg: no cycle outlives it
+        self._window_close()
+        self._part_done(leg.batch)
+
+    def _part_done(self, b: _Batch) -> None:
+        """A part of the batch is back; the last one ends its watchdog
+        and its span and frees its slot."""
+        b.left -= 1
+        if b.left:
+            return
+        # a batch and its legs point at each other; the cycle would keep
+        # its items' blocks and results for the cyclic collector
+        b.legs.clear()
+        b.tasks.clear()
+        if b.timer is not None:
+            b.timer.cancel()
+        b.span.close()
+        self._inflight.discard(b)
+        if self._slot_free is not None and not self._slot_free.done():
+            self._slot_free.set_result(None)
+
+    def _stuck(self, op: str) -> str:
+        return f"device {op} batch stuck >{self.batch_timeout:g}s"
+
+    def _timed_out(self, b: _Batch) -> None:
+        """A batch's watchdog fired: the legs it still has out are
+        stuck. The device path is shut (_on_device_hang) and each such
+        leg is let go of and failed over; one whose callback is already
+        on its way is left to it."""
+        b.timer = None
+        out = [leg for leg in b.legs if not leg.back]
+        if not out:
+            return
+        self._on_device_hang(self._stuck(out[0].op))
+        for leg in out:
+            if not leg.back and leg.job.abandon(running=True):
+                self._let_go(leg)
+
+    def _let_go(self, leg: _Leg) -> None:
+        leg.back = True
+        self._observe(leg.job, leg.op, len(leg.idxs), leg.batch.span)
+        self._leg_failed(leg, TimeoutError(self._stuck(leg.op)))
+
+    async def _await_chain(self, steps: list, op: str, items: int = 0):
+        """Send `steps` down the stage threads and wait for what the
+        last one returns: the device verdict and the boot's warm-up,
+        which a coroutine waits for (a batch's legs resolve their items
+        in their own callback instead). Cancelled by its caller's
+        watchdog, the job is let go of."""
+        loop = asyncio.get_running_loop()
+        back = loop.create_future()
+        job = self._pipeline().submit(loop, steps,
+                                      functools.partial(_wake, back))
+        try:
+            await back
+        finally:
+            job.abandon(running=True)  # kept once its callback is sent
+            self._observe(job, op, items)
+        if job.finished():
+            return job.value
+        if job.error is not None:
+            raise job.error
+        raise _DeviceHang("pipeline aborted")
+
+    @staticmethod
+    def _observe(job, op: str, items: int, span=None) -> None:
+        """What a job reached, observed on the loop once, when it is
+        back or let go of: each claimed step's wait in its stage's
+        queue, a `dev.<stage>` record for each step that returned, and
+        the lag of its one crossing. A job let go of while queued
+        observes nothing."""
+        reg = registry()
+        emit = tracing.record if span is None else span.child
+        for i, (stage, _fn) in enumerate(job.steps):
+            t_claim, t_done = job.t_claim[i], job.t_done[i]
+            if not t_claim:
+                break
+            wait = t_claim - job.t_sub[i]
+            reg.observe("feeder_stage_wait_seconds", wait, stage=stage)
+            if t_done:
+                emit(f"dev.{stage}", t_claim, t_done, op=op, items=items,
+                     wait_us=int(wait * 1e6))
+        if job.state == "sent":
+            reg.observe("feeder_resume_lag_seconds",
+                        time.perf_counter() - job.t_done[job.at],
+                        hop=job.stage)
 
     # ---- pipeline lifecycle + overlap accounting (loop thread) ---------
 
     def _pipeline(self) -> DevicePipeline:
         if self._pl is None or self._pl.dead:
-            self._pl = DevicePipeline(self._pl_busy)
+            if self._pl is not None:
+                self._pl.close()  # dead: it hands no job on
+            self._pl = DevicePipeline(self._pl_busy, self._pl_crossings)
         return self._pl
 
     def _on_device_hang(self, reason: str) -> None:
         """First watchdog to fire wins: mark the generation dead (the
-        stuck daemon threads are abandoned, never joined), wake every
-        sibling batch via the abort event and shut the device path."""
+        stuck daemon threads are abandoned, never joined), shut the
+        device path, and let go of every leg still queued in the
+        generation — those fail over at once instead of each waiting
+        out its own batch's watchdog. A leg that a stage is running now
+        runs to its end and crosses back as it is."""
         pl = self._pl
         if pl is None or pl.dead:
             return  # a sibling already handled this hang
         pl.dead = True
-        pl.aborted.set()
         self._close_route(reason)
+        for b in list(self._inflight):
+            for leg in b.legs:
+                if not leg.back and leg.job.abandon():
+                    self._let_go(leg)
 
     def _window_open(self) -> None:
         if self._win_open == 0:
@@ -1053,7 +1154,8 @@ class DeviceFeeder:
         """Overlap observability (admin /metrics + bench): per-stage
         busy seconds, the wall-clock union of in-flight windows, and
         busy/wall — > 1.0 means stages of different batches really ran
-        concurrently (the double-buffering proof)."""
+        concurrently (the double-buffering proof); and the crossings
+        back to the event loop the stage threads made, one a leg."""
         busy = {k: round(v, 6) for k, v in self._pl_busy.items()}
         wall = self._pl_wall
         if self._win_open > 0:
@@ -1062,7 +1164,8 @@ class DeviceFeeder:
         return {"busy_s": busy, "wall_s": round(wall, 6),
                 "overlap_efficiency": round(total / wall, 3) if wall > 0
                 else 0.0,
-                "inflight": len(self._inflight_tasks)}
+                "inflight": len(self._inflight),
+                "loop_crossings": sum(self._pl_crossings.values())}
 
     # ---- the plan, and the host legs (worker thread) -------------------
 

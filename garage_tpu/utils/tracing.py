@@ -216,6 +216,36 @@ def record(name: str, t0: float, t1: float, **attrs) -> None:
     _emit((trace_id, _span_id(), parent_id), name, t0, t1, attrs)
 
 
+class open_span:
+    """A span opened in one loop callback and closed in another, which
+    no context variable can follow (the feeder's batch: dispatched by
+    one callback, ended by its last leg's). It never becomes the
+    current context: its children are recorded with `child`."""
+
+    __slots__ = ("name", "attrs", "t0", "ids")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.ids = None
+        if tracer.enabled:
+            trace_id, parent_id = _ctx.get() or (_trace_id(), None)
+            self.ids = (trace_id, _span_id(), parent_id)
+            self.t0 = time.perf_counter()
+
+    def child(self, name: str, t0: float, t1: float, **attrs) -> None:
+        """`record` under this span."""
+        if self.ids is not None:
+            _emit((self.ids[0], _span_id(), self.ids[1]), name, t0, t1,
+                  attrs)
+
+    def close(self) -> None:
+        if self.ids is not None:
+            _emit(self.ids, self.name, self.t0, time.perf_counter(),
+                  self.attrs)
+            self.ids = None
+
+
 class span:
     """with span("table.insert", table=name): ...  (sync or async)."""
 

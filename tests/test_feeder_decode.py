@@ -19,8 +19,10 @@ import pytest
 
 from garage_tpu.block.codec import ErasureCodec
 from garage_tpu.block.device_backend import StubDeviceBackend
-from garage_tpu.block.feeder import DeviceFeeder, _Item
+from garage_tpu.block.feeder import DeviceFeeder
 from garage_tpu.ops import rs
+
+from feeder_batch import run_batch
 
 
 def run(coro):
@@ -62,9 +64,7 @@ def test_decode_byte_parity_all_patterns_and_buckets():
                                for s in shards])
                 want.append(rs.join_stripe(
                     rs.decode_np(k, m, present, st), blen))
-        batch = [_Item("decode", it, asyncio.get_running_loop()
-                       .create_future()) for it in items]
-        res = await f._run_batch_staged(batch)
+        res = await run_batch(f, "decode", items)
         for got, exp, it in zip(res, want, items):
             assert not isinstance(got, BaseException), (it[0], got)
             assert got == exp, f"pattern {it[0]} len {it[2]}"
@@ -94,9 +94,7 @@ def test_repair_byte_parity_mixed_missing_sizes():
         items.append((present, missing, [stripe[i] for i in present]))
 
     async def go():
-        batch = [_Item("repair", it, asyncio.get_running_loop()
-                       .create_future()) for it in items]
-        res = await f._run_batch_staged(batch)
+        res = await run_batch(f, "repair", items)
         for (present, missing, _s), got in zip(items, res):
             assert not isinstance(got, BaseException), (missing, got)
             assert sorted(got) == sorted(missing)
@@ -133,10 +131,8 @@ def test_recompiles_flat_across_mixed_erasure_patterns():
         rc_after_first = None
         for present in patterns:
             shards = [stripe[i] for i in present]
-            batch = [_Item("decode", (present, shards, len(block)),
-                           asyncio.get_running_loop().create_future())
-                     for _ in range(4)]
-            res = await f._run_batch_staged(batch)
+            res = await run_batch(f, "decode",
+                                  [(present, shards, len(block))] * 4)
             st = np.stack([np.frombuffer(s, dtype=np.uint8)
                            for s in shards])
             want = rs.join_stripe(rs.decode_np(k, m, present, st),

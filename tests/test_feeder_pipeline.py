@@ -20,10 +20,12 @@ import pytest
 from garage_tpu.block import feeder as fmod
 from garage_tpu.block import host_legs
 from garage_tpu.block.codec import ErasureCodec
-from garage_tpu.block.device_backend import (StubDeviceBackend,
+from garage_tpu.block.device_backend import (STAGES, StubDeviceBackend,
                                              bucket_items, bucket_len)
 from garage_tpu.block.feeder import DeviceFeeder, _Item
 from garage_tpu.utils.data import blake3sum
+
+from feeder_batch import run_batch
 
 
 def run(coro):
@@ -236,9 +238,7 @@ def test_require_mesh_failure_is_an_error(monkeypatch):
     blocks = [os.urandom(3000) for _ in range(f.mesh_min_items)]
 
     async def go():
-        batch = [_Item("encode", b, asyncio.get_running_loop()
-                       .create_future()) for b in blocks]
-        res = await f._run_batch_staged(batch)
+        res = await run_batch(f, "encode", blocks)
         await f.stop()
         return res
 
@@ -249,25 +249,334 @@ def test_require_mesh_failure_is_an_error(monkeypatch):
 
 
 def test_stage_executor_never_runs_cancelled_queued_jobs():
-    """A job cancelled while still QUEUED behind a slow sibling must
+    """A job let go of while still QUEUED behind a slow sibling must
     never execute — stage fns carry side effects (the d2h MD5 lane
-    advance), and running one after its batch already failed over to
-    the host path would apply them twice (review finding: silent ETag
-    corruption)."""
-    from garage_tpu.block.device_backend import StageExecutor
+    advance), and running one after its leg already failed over to the
+    host path would apply them twice (a silent ETag corruption). Nor
+    does it cross back to the loop."""
+    from garage_tpu.block.device_backend import DevicePipeline
 
     async def go():
         loop = asyncio.get_running_loop()
-        ex = StageExecutor("d2h", {"d2h": 0.0})
-        ran = []
-        slow = ex.submit(loop, lambda: time.sleep(0.15))
-        victim = ex.submit(loop, lambda: ran.append("side-effect"))
-        victim.fut.cancel()  # abandoned while queued
-        await asyncio.wait({slow.fut})
-        assert slow.claimed and slow.busy >= 0.1
-        await asyncio.sleep(0.1)  # give the worker time to (not) run it
-        assert ran == [], "cancelled queued job executed its side effect"
-        assert victim.claimed is False
+        pl = DevicePipeline({s: 0.0 for s in STAGES},
+                            {s: 0 for s in STAGES})
+        ran, back = [], []
+        done = asyncio.Event()
+
+        def slow_back(job):
+            back.append(job)
+            done.set()
+
+        slow = pl.submit(loop, [("d2h", lambda _: time.sleep(0.15))],
+                         slow_back)
+        victim = pl.submit(loop, [("d2h", lambda _: ran.append("effect"))],
+                           back.append)
+        assert victim.abandon()  # let go of while queued
+        try:
+            await done.wait()
+            assert back == [slow] and slow.busy[0] >= 0.1
+            await asyncio.sleep(0.1)  # give the worker time to (not) run it
+            assert ran == [], "abandoned queued job executed its side effect"
+            assert back == [slow]
+            assert victim.state == "abandoned" and victim.t_claim == [0.0]
+        finally:
+            pl.close()
+
+    run(go())
+
+
+def test_abandoned_chain_never_runs_its_next_stage():
+    """A chain let go of while a stage runs it: that step runs to its
+    end, the next stage never runs it, and nothing crosses back."""
+    from garage_tpu.block.device_backend import DevicePipeline
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        crossings = {s: 0 for s in STAGES}
+        pl = DevicePipeline({s: 0.0 for s in STAGES}, crossings)
+        ran, back = [], []
+        job = pl.submit(loop, [
+            ("h2d", lambda _: (time.sleep(0.1), ran.append("h2d"))),
+            ("compute", lambda _: ran.append("compute")),
+            ("d2h", lambda _: ran.append("d2h"))], back.append)
+        try:
+            await asyncio.sleep(0.03)
+            assert job.state == "running"
+            assert not job.abandon()  # running: kept unless asked for
+            assert job.abandon(running=True)
+            await asyncio.sleep(0.2)
+            assert ran == ["h2d"] and back == []
+            assert job.t_done[0] > 0 and job.t_claim[1:] == [0.0, 0.0]
+            assert crossings == {s: 0 for s in STAGES}
+        finally:
+            pl.close()
+
+    run(go())
+
+
+def test_chain_hand_shake_under_contention():
+    """Stage threads hand jobs on while the loop lets go of some of
+    them, three pipelines' threads and a short switch interval: every
+    job is either called back once or let go of, never both and never
+    neither; a job let go of while queued runs no step after that; and
+    the crossings counted equal the callbacks."""
+    import random
+    import sys
+
+    from garage_tpu.block.device_backend import DevicePipeline
+
+    crossings = {s: 0 for s in STAGES}
+    pls = [DevicePipeline({s: 0.0 for s in STAGES}, crossings)
+           for _ in range(3)]
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        ran: dict[int, int] = {}
+        back: dict[int, int] = {}
+        let_go: dict[int, int] = {}  # job -> steps claimed when let go
+        jobs = []
+        rng = random.Random(41)
+
+        def step(k):
+            def fn(_):
+                ran[k] = ran.get(k, 0) + 1
+            return fn
+
+        def called(job):
+            back[id(job)] = back.get(id(job), 0) + 1
+
+        for k in range(600):
+            job = rng.choice(pls).submit(
+                loop, [(s, step(k)) for s in STAGES], called)
+            jobs.append((k, job))
+            if k % 3 == 0:
+                await asyncio.sleep(0)
+                if job.abandon():
+                    let_go[k] = sum(1 for t in job.t_claim if t)
+        deadline = time.monotonic() + 5
+        while time.monotonic() < deadline:
+            if len(back) + len(let_go) >= len(jobs):
+                break
+            await asyncio.sleep(0.01)
+        await asyncio.sleep(0.05)  # nothing more may arrive
+        for k, job in jobs:
+            assert (id(job) in back) != (k in let_go), k
+            if k in let_go:
+                assert ran.get(k, 0) == let_go[k], k
+            else:
+                assert back[id(job)] == 1 and ran[k] == 3, k
+        assert sum(crossings.values()) == len(back)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        run(asyncio.wait_for(go(), 10))
+    finally:
+        sys.setswitchinterval(old)
+        for pl in pls:
+            pl.close()
+
+
+def test_leg_crosses_to_the_loop_once():
+    """A leg of n items goes h2d -> compute -> d2h on the stage threads
+    and costs the loop exactly one callback from them, counted once in
+    `feeder_loop_crossings`; its launch is one device batch."""
+    n = 5
+    stub = StubDeviceBackend(None, h2d_gbps=1e6, compute_gbps=1e6,
+                             d2h_gbps=1e6, fixed_s=0.005)
+    f = DeviceFeeder(mode="require", max_batch=n, backend=stub)
+    f._device_ok = True
+    blobs = [os.urandom(2048) for _ in range(n)]
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        real = loop.call_soon_threadsafe
+        calls = []
+
+        def counted(cb, *a, **kw):
+            calls.append(cb)
+            return real(cb, *a, **kw)
+
+        loop.call_soon_threadsafe = counted
+        try:
+            c0 = f.pipeline_stats()["loop_crossings"]
+            b0 = f.stats["device_batches"]
+            res = await run_batch(f, "hash", blobs)
+            assert res == [blake3sum(b) for b in blobs]
+            assert len(calls) == 1
+            assert f.pipeline_stats()["loop_crossings"] - c0 == 1
+            assert f.stats["device_batches"] - b0 == 1
+            assert f.stats["device_items"] == n
+        finally:
+            del loop.call_soon_threadsafe
+            await f.stop()
+
+    run(go())
+
+
+def test_a_batch_is_freed_without_the_cyclic_collector():
+    """A batch, its legs and their jobs hold every item's block and
+    result: once the batch is answered no reference cycle may keep them
+    for the cyclic collector, which on a large heap is a long pause of
+    the event loop."""
+    import gc
+
+    from garage_tpu.block import device_backend as dbe
+    from garage_tpu.block import feeder as feeder_mod
+
+    codec = ErasureCodec(4, 2, use_jax=False)
+    stub = StubDeviceBackend(codec, h2d_gbps=1e6, compute_gbps=1e6,
+                             d2h_gbps=1e6)
+    f = DeviceFeeder(codec=codec, mode="require", backend=stub)
+    f._device_ok = True
+    kinds = (feeder_mod._Batch, feeder_mod._Leg, dbe.StageJob)
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        for _ in range(3):
+            items = [_Item("hash", os.urandom(4096), loop.create_future()),
+                     _Item("encode", os.urandom(4096), loop.create_future())]
+            f._dispatch(items)
+            await asyncio.gather(*[it.future for it in items])
+        await asyncio.sleep(0.05)  # the threads drop their last job
+        await f.stop()
+
+    gc.collect()
+    gc.disable()
+    gc.set_debug(gc.DEBUG_SAVEALL)
+    try:
+        run(go())
+        gc.collect()
+        cyclic = [o for o in gc.garbage if isinstance(o, kinds)]
+    finally:
+        gc.set_debug(0)
+        gc.garbage.clear()
+        gc.enable()
+    assert cyclic == []
+
+
+class _SlowEncodeStub(StubDeviceBackend):
+    """The encode leg's compute waits for `release`; the hash leg runs
+    at once."""
+
+    def __init__(self, codec):
+        super().__init__(codec, h2d_gbps=1e6, compute_gbps=1e6,
+                         d2h_gbps=1e6)
+        import threading
+
+        self.in_compute = threading.Event()
+        self.release = threading.Event()
+
+    def compute(self, op, staged):
+        if op == "encode":
+            self.in_compute.set()
+            self.release.wait(10)
+        return super().compute(op, staged)
+
+
+def test_fast_leg_resolves_while_slow_sibling_computes():
+    """In a two-leg batch each leg resolves its own items when it is
+    back: the hash items are answered while the encode leg of the same
+    batch is still in compute, and the batch's slot is held until the
+    encode leg is back too."""
+    codec = ErasureCodec(4, 2, use_jax=False)
+    stub = _SlowEncodeStub(codec)
+    f = DeviceFeeder(codec=codec, mode="require", backend=stub)
+    f._device_ok = True
+    blocks = [os.urandom(4096) for _ in range(2)]
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        items = [_Item("hash", blocks[0], loop.create_future()),
+                 _Item("encode", blocks[1], loop.create_future())]
+        try:
+            f._dispatch(items)
+            assert await asyncio.wait_for(items[0].future, 5) \
+                == blake3sum(blocks[0])
+            assert stub.in_compute.is_set()
+            assert not items[1].future.done()
+            assert f.pipeline_stats()["inflight"] == 1
+            stub.release.set()
+            parts = await asyncio.wait_for(items[1].future, 5)
+            assert [bytes(p) for p in parts] == [
+                bytes(p) for p in codec.encode(blocks[1])]
+            assert f.pipeline_stats()["inflight"] == 0
+        finally:
+            stub.release.set()
+            await f.stop()
+
+    run(go())
+
+
+def test_a_batch_that_fails_to_dispatch_frees_its_slot(monkeypatch):
+    """A batch whose second leg cannot be built (its item is malformed)
+    fails its items and holds no in-flight slot: with one slot, the
+    next batch still goes through, and no leg of the failed batch was
+    sent down the stages."""
+    stub = StubDeviceBackend(None, h2d_gbps=1e6, compute_gbps=1e6,
+                             d2h_gbps=1e6)
+    f = DeviceFeeder(mode="require", backend=stub)
+    f._device_ok = True
+    f.inflight_batches = 1
+    real = fmod.group_bytes
+
+    def group_bytes(op, blobs):
+        if op == "encode":
+            raise TypeError("malformed item")
+        return real(op, blobs)
+
+    monkeypatch.setattr(fmod, "group_bytes", group_bytes)
+
+    async def go():
+        loop = asyncio.get_running_loop()
+        f._ensure_started()
+        items = [_Item("hash", os.urandom(2048), loop.create_future()),
+                 _Item("encode", os.urandom(2048), loop.create_future())]
+        for it in items:
+            f._q.put_nowait(it)  # one batch, greedily drained
+        try:
+            for it in items:
+                with pytest.raises(TypeError, match="malformed"):
+                    await asyncio.wait_for(it.future, 5)
+            assert f.pipeline_stats()["inflight"] == 0
+            assert f.stats["device_batches"] == 0
+            data = os.urandom(3000)
+            assert await asyncio.wait_for(f.hash(data), 5) \
+                == blake3sum(data)
+        finally:
+            await f.stop()
+
+    run(go())
+
+
+def test_stop_ends_the_stage_threads():
+    """A stopped feeder leaves no stage thread behind, and a feeder
+    used again after stop() gets a fresh generation."""
+    import threading
+
+    stub = StubDeviceBackend(None, h2d_gbps=1e6, compute_gbps=1e6,
+                             d2h_gbps=1e6)
+    f = DeviceFeeder(mode="require", backend=stub)
+    f._device_ok = True
+    blobs = [os.urandom(2048) for _ in range(3)]
+
+    def stage_threads():
+        return [t for t in threading.enumerate()
+                if t.name.startswith("feeder-") and t.is_alive()]
+
+    async def go():
+        before = set(stage_threads())
+        assert await run_batch(f, "hash", blobs) \
+            == [blake3sum(b) for b in blobs]
+        mine = [t for t in stage_threads() if t not in before]
+        assert len(mine) == 3
+        await f.stop()
+        for t in mine:
+            t.join(5)
+            assert not t.is_alive(), t.name
+        assert await run_batch(f, "hash", blobs) \
+            == [blake3sum(b) for b in blobs]
+        await f.stop()
 
     run(go())
 
@@ -493,14 +802,6 @@ def test_bucket_helpers():
     assert bucket_len(262144) == 262144
 
 
-def mk_batch(op, datas):
-    loop = asyncio.get_event_loop_policy().new_event_loop()
-    try:
-        return [_Item(op, d, loop.create_future()) for d in datas]
-    finally:
-        loop.close()
-
-
 def test_padded_launches_correct_and_shape_stable():
     """The staged jax route pads items to bucket shapes: results stay
     byte-identical to the host path, pad waste is accounted, and a
@@ -521,10 +822,9 @@ def test_padded_launches_correct_and_shape_stable():
         from garage_tpu.block.manager import unpack_shard
 
         # wave 1: 5 encode_put items -> bucket 8, padded shard len
-        batch = [_Item("encode_put", it, asyncio.get_running_loop()
-                       .create_future()) for it in items(5, 65536)]
-        res = await f._run_batch_staged(batch)
-        host = host_legs.encode_put(codec, [it.data for it in batch])
+        wave = items(5, 65536)
+        res = await run_batch(f, "encode_put", wave)
+        host = host_legs.encode_put(codec, wave)
         for pa, pb in zip(res, host):
             for sa, sb in zip(pa, pb):
                 da, la = unpack_shard(bytes(sa))
@@ -535,10 +835,9 @@ def test_padded_launches_correct_and_shape_stable():
         assert waste1 > 0  # 5 -> 8 items plus shard-len rounding
         assert rc1 >= 1
         # wave 2: 6 items, same sizes -> same bucket -> zero recompiles
-        batch2 = [_Item("encode_put", it, asyncio.get_running_loop()
-                        .create_future()) for it in items(6, 65536)]
-        res2 = await f._run_batch_staged(batch2)
-        host2 = host_legs.encode_put(codec, [it.data for it in batch2])
+        wave2 = items(6, 65536)
+        res2 = await run_batch(f, "encode_put", wave2)
+        host2 = host_legs.encode_put(codec, wave2)
         for pa, pb in zip(res2, host2):
             for sa, sb in zip(pa, pb):
                 da, la = unpack_shard(bytes(sa))
@@ -565,24 +864,19 @@ def test_padded_hash_and_verify_and_parity_staged():
              for n in (1024, 5000, 65536)]
 
     async def go():
-        batch = [_Item("hash", b, asyncio.get_running_loop()
-                       .create_future()) for b in blobs]
-        digs = await f._run_batch_staged(batch)
+        digs = await run_batch(f, "hash", blobs)
         assert digs == [blake3sum(b) for b in blobs]
 
         items = [(blake3sum(blobs[0]), blobs[0]),
                  (b"\x00" * 32, blobs[1])]
-        vb = [_Item("verify", it, asyncio.get_running_loop()
-                    .create_future()) for it in items]
-        assert await f._run_batch_staged(vb) == [True, False]
+        assert await run_batch(f, "verify", items) == [True, False]
 
         stripes = [codec.encode(b) for b in blobs]
         s = list(stripes[1])
         s[2] = bytes(x ^ 1 for x in s[2])
         stripes[1] = s
-        pb = [_Item("parity_check", st, asyncio.get_running_loop()
-                    .create_future()) for st in stripes]
-        assert await f._run_batch_staged(pb) == [True, False, True]
+        assert await run_batch(f, "parity_check", stripes) \
+            == [True, False, True]
         await f.stop()
 
     run(go())
@@ -608,9 +902,7 @@ def test_mesh_sharded_encode_matches_host():
               for i in range(8)]
 
     async def go():
-        batch = [_Item("encode", b, asyncio.get_running_loop()
-                       .create_future()) for b in blocks]
-        res = await f._run_batch_staged(batch)
+        res = await run_batch(f, "encode", blocks)
         host = host_legs.encode(codec, blocks)
         for a, b in zip(res, host):
             assert [bytes(x) for x in a] == [bytes(x) for x in b]
@@ -621,9 +913,7 @@ def test_mesh_sharded_encode_matches_host():
         bad = list(stripes[3])
         bad[5] = bytes(x ^ 0xFF for x in bad[5])
         stripes[3] = bad
-        pb = [_Item("parity_check", st, asyncio.get_running_loop()
-                    .create_future()) for st in stripes]
-        verdicts = await f._run_batch_staged(pb)
+        verdicts = await run_batch(f, "parity_check", stripes)
         assert verdicts == [i != 3 for i in range(8)]
         assert f.stats["mesh_batches"] >= 2
         await f.stop()

@@ -240,9 +240,10 @@ def test_hop_counters_move_with_tracing_off(series):
     run(go())
     assert not tracer.ring
     n, secs = delta(before, series)
-    # one item, one leg: three stage waits, four resumes, one of the rest
+    # one item, one leg: three stage waits, two resumes (the leg's one
+    # crossing after d2h, and the item's), one of the rest
     assert n == {"feeder_stage_wait_seconds": 3,
-                 "feeder_resume_lag_seconds": 4}.get(series, 1)
+                 "feeder_resume_lag_seconds": 2}.get(series, 1)
     assert secs > 0
 
 
@@ -251,6 +252,7 @@ def test_hop_covers_its_parts():
     hops sum to at least queue wait + stage waits + stage busy."""
     f = stub_feeder(fixed_s=0.004, max_batch=1)
     names = HOP_SERIES
+    b_hops = {}
 
     async def go():
         b0 = {n: counts(n) for n in names}
@@ -261,6 +263,8 @@ def test_hop_covers_its_parts():
         assert (one["feeder_hop_seconds"][1]
                 >= one["feeder_queue_wait_seconds"][1])
         b1 = {n: counts(n) for n in names}
+        b_hops.update({h: counts("feeder_resume_lag_seconds", hop=h)
+                       for h in ("h2d", "compute", "d2h", "item")})
         busy0 = sum(f._pl_busy.values())
         await asyncio.gather(*[f.hash(os.urandom(2048)) for _ in range(6)])
         many = {n: delta(b1[n], n) for n in names}
@@ -271,7 +275,11 @@ def test_hop_covers_its_parts():
     many, busy = run(go())
     assert many["feeder_hop_seconds"][0] == 6
     assert many["feeder_stage_wait_seconds"][0] == 18
-    assert many["feeder_resume_lag_seconds"][0] == 24
+    # a leg crosses back once, after d2h: h2d and compute observe none
+    assert many["feeder_resume_lag_seconds"][0] == 12
+    for hop, want in (("h2d", 0), ("compute", 0), ("d2h", 6), ("item", 6)):
+        assert delta(b_hops[hop], "feeder_resume_lag_seconds",
+                     hop=hop)[0] == want, hop
     assert busy >= 6 * 3 * 0.004
     parts = (many["feeder_queue_wait_seconds"][1]
              + many["feeder_stage_wait_seconds"][1] + busy)
@@ -282,9 +290,10 @@ def test_hop_covers_its_parts():
 
 @pytest.mark.parametrize("how", ["cancelled_in_queue", "hung_in_compute"])
 def test_stage_job_observes_what_it_reached_once(how):
-    """A job abandoned while queued observes nothing; a leg that hangs
-    observes the hand-offs it got through, each once, and its item's
-    hop once (the item fails with the device's error)."""
+    """A job let go of while queued observes nothing; a leg that hangs
+    observes the stage waits it got through, each once, no crossing
+    (it never came back), and its item's hop once (the item fails with
+    the device's error)."""
     stages = ("h2d", "compute", "d2h")
     before = {(n, s): counts(n, **{k: s})
               for n, k in (("feeder_stage_wait_seconds", "stage"),
@@ -299,11 +308,10 @@ def test_stage_job_observes_what_it_reached_once(how):
         f = stub_feeder()
 
         async def go():
-            pl = f._pipeline()
-            first = asyncio.create_task(f._stage_call(
-                pl, "h2d", lambda: time.sleep(0.15), [], "t"))
-            second = asyncio.create_task(f._stage_call(
-                pl, "h2d", lambda: None, [], "t"))
+            first = asyncio.create_task(f._await_chain(
+                [("h2d", lambda _: time.sleep(0.15))], "t"))
+            second = asyncio.create_task(f._await_chain(
+                [("h2d", lambda _: None)], "t"))
             await asyncio.sleep(0.03)  # second sits behind first
             second.cancel()
             await asyncio.gather(first, second, return_exceptions=True)
@@ -326,8 +334,8 @@ def test_stage_job_observes_what_it_reached_once(how):
     run(go())
     waits = [got("feeder_stage_wait_seconds", "stage", s) for s in stages]
     lags = [got("feeder_resume_lag_seconds", "hop", s) for s in stages]
-    assert waits == [1, 1, 0]  # compute was claimed, d2h never submitted
-    assert lags == [1, 0, 0]   # compute never came back
+    assert waits == [1, 1, 0]  # compute was claimed, d2h never queued
+    assert lags == [0, 0, 0]   # the leg never crossed back
     assert got("feeder_resume_lag_seconds", "hop", "item") == 1
     assert delta(hop0, "feeder_hop_seconds")[0] == 1
 
@@ -494,12 +502,12 @@ def test_metrics_carry_loop_cpu_clock(tmp_path, monkeypatch, busy):
 
 
 # ---------------------------------------------------------------------------
-# the benchmark's eight metric files read series this program exports
+# the benchmark's metric files of the hop read series this program exports
 # ---------------------------------------------------------------------------
 
 HOP_METRICS = ("feeder_hop_ms", "feeder_wait_ms", "stage_wait_ms",
                "stage_h2d_busy", "loop_resume_ms", "loop_cpu_share",
-               "node_cpu_cores", "s3_body_ms")
+               "node_cpu_cores", "s3_body_ms", "loop_crossings_per_leg")
 
 
 @pytest.fixture(scope="module")

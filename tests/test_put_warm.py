@@ -21,10 +21,11 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import ec_reference  # noqa: E402
 from clusterbox import ClusterBox  # noqa: E402
+from feeder_batch import run_batch  # noqa: E402
 
 from garage_tpu.block.codec import ErasureCodec  # noqa: E402
 from garage_tpu.block.device_backend import JaxDeviceBackend  # noqa: E402
-from garage_tpu.block.feeder import DeviceFeeder, _Item  # noqa: E402
+from garage_tpu.block.feeder import DeviceFeeder  # noqa: E402
 from garage_tpu.block.hostbuf import HostBufPool  # noqa: E402
 from garage_tpu.ops import jaxenv  # noqa: E402
 from garage_tpu.utils.config import TpuConfig  # noqa: E402
@@ -210,8 +211,7 @@ def test_after_warm_device_a_decode_batch_builds_nothing(tmp_path, k, m):
                 seen = requests()
                 for n in (1, 3, 8, 32):
                     items = [item() for _ in range(n)]
-                    got = await f._run_batch_staged(
-                        [_Item("decode", it, None) for it in items])
+                    got = await run_batch(f, "decode", items)
                     assert requests() == seen, n
                     for it, packed in zip(items, got):
                         assert packed[1:] == ec_reference.reference_block(
@@ -219,8 +219,8 @@ def test_after_warm_device_a_decode_batch_builds_nothing(tmp_path, k, m):
                 # and a resync rebuild of one shard: the repair leg
                 it = item()
                 lost = next(i for i in range(k + m) if i not in it[0])
-                (got,) = await f._run_batch_staged(
-                    [_Item("repair", (it[0], (lost,), it[1]), None)])
+                (got,) = await run_batch(
+                    f, "repair", [(it[0], (lost,), it[1])])
                 assert requests() == seen and got == {lost: stripe[lost]}
                 assert f.stats["decode_device_items"] == 45
                 assert f.stats["device_errors"] == 0
