@@ -36,6 +36,9 @@ SUFFIX_OF = {COMPRESSION_NONE: "", COMPRESSION_ZLIB: ".zlib",
              COMPRESSION_ZSTD: ".zst"}
 COMP_OF_SUFFIX = {v: k for k, v in SUFFIX_OF.items()}
 BLOCK_SUFFIXES = list(SUFFIX_OF.values())
+# scheme -> the `scheme` label of the block manager's series and spans
+SCHEME_NAME = {COMPRESSION_NONE: "none", COMPRESSION_ZLIB: "zlib",
+               COMPRESSION_ZSTD: "zstd"}
 
 
 class MissingCodec(RuntimeError):
@@ -108,17 +111,19 @@ class DataBlock:
             return zstandard.ZstdDecompressor().decompress(self.bytes)
         return zlib.decompress(self.bytes)
 
-    def verify(self, hash32: bytes) -> None:
+    def verify(self, hash32: bytes) -> bytes:
         """ref: block.rs:69-83 (plain: content-hash check; compressed:
         integrity of the decompression stream + content hash of the
         result). Content hash is BLAKE3 by default (utils/data.py),
-        blake2 accepted for stores migrated from the legacy algo."""
+        blake2 accepted for stores migrated from the legacy algo.
+        -> the plain bytes, so a reader decompresses once."""
         try:
             plain = self.plain_bytes()
         except (zlib.error, _ZstdError) as e:
             raise CorruptData(hash32) from e
         if not content_hash_matches(plain, hash32):
             raise CorruptData(hash32)
+        return plain
 
     # wire format: 1 header byte + payload
     def pack(self) -> bytes:

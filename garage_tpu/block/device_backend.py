@@ -292,6 +292,10 @@ class JaxDeviceBackend:
         self._shapes_seen: set = set()
         self._zero_stripes: dict = {}  # (k, slen) -> device zeros
         self._mesh = None
+        # shard length of the node's full block (the feeder's boot
+        # warm-up sets it; 0 = not known): every RS launch of blocks up
+        # to that size is staged at the programs the warm-up built
+        self.stripe_len = 0
 
     # ---- shape accounting ------------------------------------------------
 
@@ -300,6 +304,25 @@ class JaxDeviceBackend:
             self._shapes_seen.add(key)
             self.stats["recompiles"] += 1
         self.stats["pad_waste_bytes"] += int(waste)
+
+    def _shard_bucket(self, slen: int) -> int:
+        """The shard-length bucket of a launch whose longest shard is
+        `slen`: never shorter than a full block's, so a compressed block
+        or a short tail takes the program the boot built instead of one
+        of its own (zero columns encode and decode to zero columns)."""
+        return bucket_len(max(slen, self.stripe_len))
+
+    def _stripe_row(self, blocks: list, slens: list):
+        """The row length at which every item of an encode leg can be
+        staged as a (k, row) stripe: a lease's own shard length, else a
+        full block's (`stripe_len`). None when leases disagree, when an
+        item is longer than the row, or when no row is known."""
+        row = next((sl for b, sl in zip(blocks, slens)
+                    if hasattr(b, "stripe")), self.stripe_len)
+        if not row or any(sl != row if hasattr(b, "stripe") else sl > row
+                          for b, sl in zip(blocks, slens)):
+            return None
+        return row
 
     def verdict(self) -> dict:
         """{"platform", "device_kind", "count"} of this process, from
@@ -410,38 +433,47 @@ class JaxDeviceBackend:
 
         slens = [b.slen if hasattr(b, "slen") else rs.shard_len(len(b), k)
                  for b in blocks]
-        smax = bucket_len(max(slens))
         bpad = bucket_items(len(blocks), self.pad_buckets)
         mesh = (self._get_mesh()
                 if len(blocks) >= self.mesh_min_items else None)
+        row = self._stripe_row(blocks, slens) if mesh is None else None
+        smax = self._shard_bucket(row or max(slens))
         if mesh is not None:
             dp, tp = mesh.shape["dp"], mesh.shape["tp"]
             bpad = ((bpad + dp - 1) // dp) * dp
             smax = ((smax + tp - 1) // tp) * tp
         waste = bpad * k * smax - sum(blen(b) for b in blocks)
         self._note_shape((tag, k, m, bpad, smax, mesh is not None), waste)
-        if mesh is None and blocks \
-                and all(hasattr(b, "stripe") for b in blocks) \
-                and len(set(slens)) == 1:
-            # all-lease leg: the pool buffer IS the stripe layout, so
-            # h2d reads it directly — no host-side re-pack copy. The
-            # pad to (bpad, k, smax) happens on-device; batch=None
-            # tells readback to slice the data shards straight from
-            # the leases (host memory) instead of a staging array.
-            # Missing items are zero stripes stacked in, so the stack
-            # and the pad are one program an item BUCKET, as the
-            # kernels are, and not one an item count.
+        if row:
+            # stripe leg: every item is a (k, row) stripe — a lease's
+            # pool buffer IS that layout, so h2d reads it directly (no
+            # host-side re-pack copy), and a block that came as bytes
+            # (compressed, or a short tail) is split into a zero-tailed
+            # stripe of the same shape. The pad to (bpad, k, smax)
+            # happens on-device; readback slices each item's own shard
+            # length straight from these host stripes. Missing items
+            # are zero stripes stacked in, so the stack and the pad are
+            # one program an item BUCKET, as the kernels are, and not
+            # one an item count or a data-dependent length.
             import jax.numpy as jnp
 
-            devs = [jax.device_put(b.stripe()) for b in blocks]
+            stripes = []
+            for b, sl in zip(blocks, slens):
+                if hasattr(b, "stripe"):
+                    stripes.append(b.stripe())
+                else:
+                    st = np.zeros((k, row), dtype=np.uint8)
+                    st[:, :sl] = rs.split_stripe(b, k)
+                    stripes.append(st)
+            devs = [jax.device_put(st) for st in stripes]
             zero = self._zero_stripes.get(devs[0].shape)
             if zero is None:
                 zero = self._zero_stripes[devs[0].shape] = \
                     jnp.zeros_like(devs[0])
             dev = jnp.stack(devs + [zero] * (bpad - len(blocks)))
-            if smax > slens[0]:
-                dev = jnp.pad(dev, ((0, 0), (0, 0), (0, smax - slens[0])))
-            return (blocks, slens, None, dev, None, smax)
+            if smax > row:
+                dev = jnp.pad(dev, ((0, 0), (0, 0), (0, smax - row)))
+            return (blocks, slens, stripes, dev, None, smax)
         batch = np.zeros((bpad, k, smax), dtype=np.uint8)
         copied = 0
         for i, b in enumerate(blocks):
@@ -452,8 +484,8 @@ class JaxDeviceBackend:
                 sh = rs.split_stripe(b, k)
             batch[i, :, : sh.shape[1]] = sh
         if copied and tag == "encode":
-            # a lease fell off the zero-copy leg (mesh round-up or a
-            # mixed-shape batch): the pad copy is real data-plane
+            # a lease fell off the stripe leg (mesh round-up, or an item
+            # longer than a lease's stripe): the pad copy is real data-plane
             # bytes, so the wire->device copy audit must see it
             from ..utils.metrics import registry
 
@@ -470,7 +502,7 @@ class JaxDeviceBackend:
         import jax
 
         k, m = self.codec.k, self.codec.m
-        smax = bucket_len(max(len(s[0]) for s in stripes))
+        smax = self._shard_bucket(max(len(s[0]) for s in stripes))
         bpad = bucket_items(len(stripes), self.pad_buckets)
         mesh = (self._get_mesh()
                 if len(stripes) >= self.mesh_min_items else None)
@@ -519,7 +551,7 @@ class JaxDeviceBackend:
         staged = []
         for rows, idxs in groups.items():
             slens = [len(shards_of(items[i])[0]) for i in idxs]
-            smax = bucket_len(max(slens))
+            smax = self._shard_bucket(max(slens))
             bpad = bucket_items(len(idxs), self.pad_buckets)
             mesh = (self._get_mesh()
                     if len(idxs) >= self.mesh_min_items else None)
@@ -661,10 +693,10 @@ class JaxDeviceBackend:
             out = []
             for i in range(len(blocks)):
                 sl = slens[i]
-                # batch=None: all-lease leg — the data shards live in
-                # the lease buffers (still held by the PUT tasks, which
-                # await this op before releasing), no staging array
-                src = blocks[i].stripe() if batch is None else batch[i]
+                # `batch` is the staging array, or the stripe leg's host
+                # stripes: lease buffers, still held by the PUT tasks,
+                # which await this op before releasing
+                src = batch[i]
                 out.append([bytes(src[j, :sl]) for j in range(k)]
                            + [bytes(par[i, j, :sl]) for j in range(m)])
             if op == "encode_put":

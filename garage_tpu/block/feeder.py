@@ -405,16 +405,23 @@ class DeviceFeeder:
         quorum write never landed. A node that serves from its device
         then meets no program for the first time inside a request: they
         are built (or loaded from the compile cache) here, at boot, on
-        the stage threads and under the batch watchdog like any leg. A
-        wave of rebuilds, or a stripe missing several shards, still
-        builds its program when it comes. Results are thrown away and no
-        item is counted; a failure is the caller's to raise."""
+        the stage threads and under the batch watchdog like any leg.
+        From here on an RS launch of shorter blocks — compressed ones,
+        a part's tail — is staged at the full block's shard length too
+        (JaxDeviceBackend.stripe_len), so it takes these programs as
+        well. A wave of rebuilds, or a stripe missing several shards,
+        still builds its program when it comes. Results are thrown away
+        and no item is counted; a failure is the caller's to raise."""
         await self.device_verdict()
         if not self._device_ok or self._backend_is_stub():
             return
         from ..ops import jaxenv
         from ..utils import data as _data
         from .hostbuf import stripe_shard_len
+
+        if self.codec is not None:
+            slen = self._get_backend().stripe_len = stripe_shard_len(
+                1 + block_len, self.codec.k)
 
         put_legs = []
         if _data._content_algo == "blake3":  # blake2 never leaves the host
@@ -425,7 +432,7 @@ class DeviceFeeder:
         if self.codec is not None and decode_items > 0:
             k = self.codec.k
             first_k = tuple(range(k))
-            shards = [bytes(stripe_shard_len(1 + block_len, k))] * k
+            shards = [bytes(slen)] * k
             decode_legs.append(("decode", (first_k, shards, 1 + block_len)))
             if self.codec.m > 0:
                 repair_legs.append(("repair", (first_k, (k,), shards)))

@@ -37,7 +37,8 @@ from ..utils.data import blake2sum
 from ..utils.metrics import registry
 from ..utils.error import CorruptData, MissingBlock, QuorumError, RpcError
 from ..utils.tracing import span
-from .block import BLOCK_SUFFIXES, COMPRESSION_NONE, DataBlock, comp_of_path
+from .block import (BLOCK_SUFFIXES, COMPRESSION_NONE, SCHEME_NAME, DataBlock,
+                    comp_of_path)
 from .codec import BlockCodec, ErasureCodec, ReplicateCodec, shard_nodes_of
 from .layout import DataLayout
 from .rc import BlockRc
@@ -435,40 +436,30 @@ class BlockManager:
             async with span("block.put", size=len(data), hash=hash32):
                 do_compress = (self.compression if compress is None
                                else compress)
-                if lease is not None:
-                    blk = (await asyncio.to_thread(
-                        DataBlock.compress, lease.view())
-                        if do_compress else None)
-                    if blk is None or blk.compression == COMPRESSION_NONE:
-                        # zero-copy leg: scheme byte lands in the
-                        # lease's header slot and the feeder stages the
-                        # prefilled stripe directly — no pack, no pad
-                        lease.set_scheme(COMPRESSION_NONE)
-                        await self._put_erasure(
-                            hash32, bytes([COMPRESSION_NONE]), lease)
-                    else:
-                        # the body shrank: the compressed copy is a NEW
-                        # (smaller) buffer, so the classic path costs
-                        # nothing extra
-                        await self._put_erasure(hash32,
-                                                bytes([blk.compression]),
-                                                blk.bytes)
+                blk = (await self._compress(
+                    lease.view() if lease is not None else data)
+                    if do_compress else None)
+                scheme, body = ((COMPRESSION_NONE, data) if blk is None
+                                else (blk.compression, blk.bytes))
+                registry().inc("block_put_blocks", scheme=SCHEME_NAME[scheme])
+                if lease is not None and scheme == COMPRESSION_NONE:
+                    # zero-copy leg: scheme byte lands in the lease's
+                    # header slot and the feeder stages the prefilled
+                    # stripe directly — no pack, no pad
+                    lease.set_scheme(COMPRESSION_NONE)
+                    await self._put_erasure(
+                        hash32, bytes([COMPRESSION_NONE]), lease)
                 elif self.erasure:
-                    blk = (await asyncio.to_thread(DataBlock.compress, data)
-                           if do_compress else DataBlock.plain(data))
                     # the 1-byte DataBlock header travels as a prefix so
-                    # the megabyte payload is never concat-copied
-                    await self._put_erasure(hash32,
-                                            bytes([blk.compression]),
-                                            blk.bytes)
+                    # the megabyte payload is never concat-copied; a
+                    # body that shrank is a NEW (smaller) buffer, which
+                    # the feeder stages into a lease-shaped stripe
+                    await self._put_erasure(hash32, bytes([scheme]), body)
                 else:
-                    blk = (await asyncio.to_thread(DataBlock.compress, data)
-                           if do_compress else DataBlock.plain(data))
                     # scheme byte travels as its own field: the
                     # megabyte payload is never concat-copied into a
                     # packed buffer (same trick as the erasure prefix)
-                    await self._put_replicate(hash32, blk.compression,
-                                              blk.bytes)
+                    await self._put_replicate(hash32, scheme, body)
             # write-through: freshly written blocks are the hottest
             # reads (read-after-write). `data` is exactly the decoded
             # payload rpc_get_block returns. SSE-C callers pass
@@ -507,6 +498,24 @@ class BlockManager:
                     self.cache.insert(hash32, data)
         finally:
             self._ram_sem.release(len(data))
+
+    async def _compress(self, data) -> DataBlock:
+        """A PUT's compress step, on a worker thread: span
+        `block.compress` (attrs `size`, `out` = bytes stored, `scheme`),
+        `block_compress_seconds{scheme}` = the await as the request
+        waits it, `block_compress_bytes{dir="in"|"out"}`. A block that
+        stays plain counts its own size out."""
+        async with span("block.compress", size=len(data)) as sp:
+            t0 = time.perf_counter()
+            blk = await asyncio.to_thread(DataBlock.compress, data)
+            waited = time.perf_counter() - t0
+            scheme = SCHEME_NAME[blk.compression]
+            sp.attrs.update(out=len(blk.bytes), scheme=scheme)
+        reg = registry()
+        reg.observe("block_compress_seconds", waited, scheme=scheme)
+        reg.inc("block_compress_bytes", len(data), dir="in")
+        reg.inc("block_compress_bytes", len(blk.bytes), dir="out")
+        return blk
 
     async def _put_replicate(self, hash32: bytes, comp: int,
                              payload: bytes) -> None:
@@ -813,9 +822,7 @@ class BlockManager:
 
         def unpack_verify() -> bytes:
             blk = DataBlock.unpack(packed)
-            if not verified:
-                blk.verify(hash32)
-            return blk.plain_bytes()
+            return blk.plain_bytes() if verified else blk.verify(hash32)
 
         # MiB-scale decompress+hash release the GIL: run them in a
         # worker thread so the GET readahead pipeline's prefetches
@@ -933,9 +940,7 @@ class BlockManager:
                 registry().inc("cache_packed_local_hit")
 
                 def unpack_cached() -> bytes:
-                    blk = DataBlock.unpack(cached)
-                    blk.verify(hash32)
-                    return blk.plain_bytes()
+                    return DataBlock.unpack(cached).verify(hash32)
 
                 try:
                     if len(cached) >= 64 * 1024:
@@ -968,9 +973,7 @@ class BlockManager:
                     packed = await self._decode_parts(parts, packed_len)
 
                     def unpack_verify(packed=packed) -> bytes:
-                        blk = DataBlock.unpack(packed)
-                        blk.verify(hash32)
-                        return blk.plain_bytes()
+                        return DataBlock.unpack(packed).verify(hash32)
 
                     # MiB-scale decompress+verify off the event loop,
                     # same rule as the replicate read path

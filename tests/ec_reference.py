@@ -13,6 +13,11 @@ checksum zlib's or the pure-Python CRC32C.
                    order (index i goes to the i-th node `shard_nodes_of`
                    gives), the packed length
     any k shards -> the plain block
+    a stored stripe -> its scheme, checked against the plain block
+
+A block stored compressed (scheme byte 2) is checked by decompressing
+its frame with `zstandard` itself, so the reference does not depend on
+the compressor's exact bytes.
 """
 
 from __future__ import annotations
@@ -21,11 +26,13 @@ import functools
 import zlib
 
 import numpy as np
+import zstandard
 
 from garage_tpu import native
 from garage_tpu.ops import treehash
 
 SCHEME_PLAIN = 0  # DataBlock's header byte of an uncompressed block
+SCHEME_ZSTD = 2  # ... and of a block stored as a zstd frame
 POLY = 0x11D
 
 
@@ -117,8 +124,32 @@ def reference_block(present, shards, k: int, m: int,
     assert len(present) == len(shards) == k
     rows = np.stack([np.frombuffer(s, dtype=np.uint8) for s in shards])
     packed = decode(k, m, present, rows).reshape(-1)[:packed_len].tobytes()
+    return plain_of(packed)
+
+
+def plain_of(packed: bytes) -> bytes:
+    """A packed block (scheme byte + payload) -> the plain block: the
+    payload itself, or what zstandard makes of its frame."""
+    if packed[0] == SCHEME_ZSTD:
+        return zstandard.ZstdDecompressor().decompress(packed[1:])
     assert packed[0] == SCHEME_PLAIN
     return packed[1:]
+
+
+def check_stored_stripe(block: bytes, payloads: list[bytes], k: int,
+                        m: int, packed_len: int) -> int:
+    """The k+m shard payloads a PUT of `block` stored, in shard-index
+    order: the data shards joined and trimmed to the packed length are
+    the scheme byte and a payload that gives `block` back, and the
+    parity shards are the reference's encode of those data shards.
+    -> the scheme."""
+    assert len(payloads) == k + m
+    rows = np.stack([np.frombuffer(p, dtype=np.uint8) for p in payloads])
+    assert np.array_equal(rows[k:], encode(k, m, rows[:k]))
+    packed = rows[:k].reshape(-1)[:packed_len].tobytes()
+    assert not rows[:k].reshape(-1)[packed_len:].any()  # the zero tail
+    assert plain_of(packed) == block
+    return packed[0]
 
 
 def parse_shard_file(raw: bytes):

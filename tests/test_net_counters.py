@@ -290,7 +290,7 @@ def test_tcp_node_counts_all_its_connections_on_one_wire_stats(
 # ---------------------------------------------------------------------------
 
 PUT_CELLS = ["ec42-put-mp16", "ec42-put-1stream", "rep3-put-mp16",
-             "ec104-put-mp16"]
+             "ec104-put-mp16", "ec42-put-zstd"]
 # name -> (unit, better, layer, moves, workloads, value on SCRAPES)
 NEW_METRICS = {
     "net_frames_per_block": ("count", "lower", "store", "put_MiBps",
