@@ -1078,9 +1078,11 @@ class AdminHttpServer:
 
         # what this node's wire connections did (net/conn.py WireStats,
         # one a node, live and closed): frames and their bytes each way,
-        # frames the kernel took in part (bytes left in the buffer),
-        # seconds parked in drain() over frames sent, and a message's
-        # wait in its connection's send queue
+        # frames the kernel took in part in their sender's first send
+        # (bytes that send left), seconds the send loop parked for room
+        # in its sender thread's queue over frames sent, a message's
+        # wait in its connection's send queue, and the records the
+        # sender threads wrote with their lag from append to the kernel
         w = g.netapp.wire
         gauge("rpc_net_frames_count", w.sent, "Wire frames; _sum = bytes",
               dir="send")
@@ -1091,11 +1093,17 @@ class AdminHttpServer:
               "Frames the kernel took in part; _sum = bytes left")
         gauge("rpc_net_partial_writes_sum", w.partial_bytes)
         gauge("rpc_net_drain_seconds_count", w.sent,
-              "Frames sent; _sum = seconds parked in drain()")
+              "Frames sent; _sum = seconds parked for room in the sender")
         gauge("rpc_net_drain_seconds_sum", round(w.drain_s, 6))
         gauge("rpc_net_send_queue_seconds_count", w.queued,
               "Messages sent; _sum = seconds from enqueue to first frame")
         gauge("rpc_net_send_queue_seconds_sum", round(w.queue_s, 6))
+        gauge("rpc_net_sender_frames_count", w.sender_frames,
+              "Frames written by a connection's sender thread")
+        gauge("rpc_net_sender_lag_seconds_count", w.sender_frames,
+              "Frames written by a sender thread; _sum = seconds from "
+              "append to the last byte taken by the kernel")
+        gauge("rpc_net_sender_lag_seconds_sum", round(w.sender_lag_s, 6))
 
         # device feeder and staged-pipeline observability.
         # Names are registered literally (GL07-checkable, and `feeder`

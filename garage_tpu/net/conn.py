@@ -33,24 +33,38 @@ full-payload copies per block RPC vs msgpack-embedding the bytes
 (r4 profile: the copies were a top-3 cost on the PUT path).
 
 Concurrency invariant: ALL outgoing records flow through _send_loop (the
-single writer) — the AEAD nonce counter and frame ordering both depend
-on it. CANCEL/CREDIT are enqueued control items, never written directly.
+single writer), which builds, encrypts and numbers them on the event
+loop — the AEAD nonce counter and frame ordering both depend on it.
+CANCEL/CREDIT are enqueued control items, never written directly. Each
+TCP channel's records are then written to its socket by one sender
+thread that the channel owns (`_Sender`), strictly in the order they
+were appended; the loop's thread makes no `send` for a wire frame.
 
 What a node's wire connections do is counted in plain integers on one
 `WireStats` that its NetApp hands to every channel it builds: frames
-and their bytes each way, records the kernel took in part, time parked
-in `drain()`, and how long a message waited in the send queue before its
-first frame went. /metrics renders it as `rpc_net_*`. LocalChannel
-frames are no wire frames and are not counted.
+and their bytes each way (counted on the loop when a record is
+appended), records the kernel took in part, time the send loop parked
+for room in its sender's queue, how long a message waited in the send
+queue before its first frame went, and, counted by the sender threads
+under one lock, the records they wrote and each one's lag from append
+to its last byte taken by the kernel. /metrics renders it as
+`rpc_net_*`. LocalChannel frames are no wire frames and are not
+counted.
 """
 
 from __future__ import annotations
 
 import asyncio
+import collections
 import hashlib
 import hmac as hmac_mod
+import itertools
 import logging
+import os
+import select
+import socket
 import struct
+import threading
 from time import perf_counter
 from typing import Awaitable, Callable, Optional
 
@@ -165,6 +179,21 @@ def parse_body(parts: list):
 STREAM_WINDOW = 4 << 20
 CREDIT_BATCH = 1 << 20  # grant credits in chunks this big
 
+# Bytes a connection's sender thread may hold unwritten before the send
+# loop parks: eight full frames. A 1 MiB block's copy to a peer is five
+# records, so one whole block and the start of the next fit, and on
+# loopback, where the kernel takes a record as fast as the thread hands
+# it over, the send loop does not park; it parks where the socket itself
+# stops taking bytes (a peer that stops reading), and stream credits stay
+# the end-to-end flow control.
+SEND_QUEUE_BYTES = 8 * (MAX_CHUNK + 64)
+# records one `sendmsg` may carry (two buffers each; Linux's IOV_MAX
+# is 1,024)
+_BATCH = 256
+# a send on a socket that close() shut down fails with EPIPE and raises
+# no SIGPIPE, which the CLI restores to its default (kill the process)
+_NOSIGNAL = getattr(socket, "MSG_NOSIGNAL", 0)
+
 
 def _hmac(key: bytes, *parts: bytes) -> bytes:
     return hmac_mod.new(key, b"".join(parts), hashlib.blake2b).digest()[:32]
@@ -186,41 +215,221 @@ class WireStats:
     integers and seconds: the hot path pays additions."""
 
     __slots__ = ("sent", "sent_bytes", "recv", "recv_bytes", "partial",
-                 "partial_bytes", "drain_s", "queued", "queue_s")
+                 "partial_bytes", "drain_s", "queued", "queue_s",
+                 "sender_frames", "sender_lag_s")
+    # the sender threads of every channel add under this lock (the
+    # loop's own counters above need none: one thread writes them)
+    thread_lock = threading.Lock()
 
     def __init__(self):
-        self.sent = self.sent_bytes = 0  # frames written, wire bytes
+        # records appended to a sender, their wire bytes (on the loop)
+        self.sent = self.sent_bytes = 0
         self.recv = self.recv_bytes = 0  # frames read, wire bytes
-        # frames the kernel took only in part when write() sent them
-        # (the transport's buffer was empty before, not after), and the
-        # bytes left for the loop's writer callback on a later turn
+        # records the kernel took only in part in the sender's first
+        # send of them, and the bytes that send left (sender threads)
         self.partial = self.partial_bytes = 0
-        self.drain_s = 0.0  # seconds parked in drain(), over `sent`
+        # seconds the send loop parked for room in a sender's queue,
+        # over `sent` (0 for a record that did not park)
+        self.drain_s = 0.0
         self.queued = 0  # messages (not control frames) sent
         self.queue_s = 0.0  # enqueue -> first frame handed to send_frame
+        # records a sender thread wrote whole, and the seconds from each
+        # one's append to its last byte taken by the kernel
+        self.sender_frames = 0
+        self.sender_lag_s = 0.0
 
 
-async def _write_record(writer, stats: WireStats, record: bytes) -> None:
-    """One record to the transport, then drain() — counted: a record
-    the kernel took only in part leaves bytes in the transport's
-    buffer, and drain() parks the connection's single send loop (and
-    every message queued behind it) while that buffer is over its
-    high-water mark. A record written while an earlier one's rest is
-    still buffered is only appended to it (the kernel is not asked), so
-    it is no partial write."""
-    transport = writer.transport
-    before = transport.get_write_buffer_size()
-    writer.write(record)
+def _wake(fut: asyncio.Future) -> None:
+    if not fut.done():
+        fut.set_result(None)
+
+
+class _Sender:
+    """The thread that writes one TCP channel's records to its socket,
+    in the order they were appended, so the event loop's thread pays
+    for no `send` (on loopback the kernel copies the record and runs the
+    whole TCP path inside it). It writes through its own duplicate of
+    the transport's socket, non-blocking like asyncio's, so asyncio's
+    reads on that socket are untouched: the records waiting go out in
+    one `sendmsg`, each as two buffers, its length prefix and its body,
+    and while the kernel's buffer is full the thread waits in `poll`.
+    The thread takes the interpreter lock back after every system call,
+    so one call a batch, not a record, is what keeps it from falling
+    behind a busy loop. Records are owned `bytes`, never views of
+    buffers that are recycled. The send loop parks only while the queue
+    holds more than SEND_QUEUE_BYTES, and the thread wakes it with one
+    `call_soon_threadsafe`; a send error reaches the loop the same way,
+    through `on_error`. `close()` shuts the socket down, which also
+    returns a send that waits on a full buffer; the thread then closes
+    its descriptor and ends."""
+
+    def __init__(self, writer, stats: WireStats, name: str):
+        self.stats = stats
+        self.on_error: Optional[Callable[[Exception], None]] = None
+        self.error: Optional[Exception] = None
+        self._loop = asyncio.get_running_loop()
+        fd = writer.get_extra_info("socket").fileno()
+        self._sock = socket.socket(fileno=os.dup(fd))
+        self._sock.setblocking(False)
+        self._cond = threading.Condition(threading.Lock())
+        self._queue: collections.deque = collections.deque()
+        self._queued = 0  # bytes in _queue, the batch being written too
+        self._room: Optional[asyncio.Future] = None  # the parked loop's
+        self._closing = False
+        self.thread = threading.Thread(target=self._run, name=name,
+                                       daemon=True)
+        self.thread.start()
+
+    def put_record(self, head: bytes, body: bytes
+                   ) -> Optional[asyncio.Future]:
+        """Hand a record to the thread (on the loop). Returns a future
+        to park on while the queue is over its bound, else None."""
+        with self._cond:
+            if self._closing or self.error is not None:
+                raise self.error or ConnectionResetError("channel closed")
+            self._queue.append((head, body, perf_counter()))
+            self._queued += len(head) + len(body)
+            self._cond.notify()
+            if self._queued <= SEND_QUEUE_BYTES:
+                return None
+            if self._room is None or self._room.done():
+                self._room = self._loop.create_future()
+            return self._room
+
+    def close(self) -> None:
+        """On the loop: stop the thread, dropping what it has not
+        written. Idempotent."""
+        with self._cond:
+            self._closing = True
+            room, self._room = self._room, None
+            self._cond.notify()
+            try:
+                self._sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass  # already closed by the thread, or never connected
+        if room is not None and not room.done():
+            room.set_exception(ConnectionResetError("channel closed"))
+
+    def _failed(self, exc: Exception) -> None:
+        """On the loop: the thread stopped on a send error."""
+        with self._cond:
+            self.error = exc
+            room, self._room = self._room, None
+        if room is not None and not room.done():
+            room.set_exception(exc)
+        if self.on_error is not None:
+            self.on_error(exc)
+
+    def _run(self) -> None:
+        try:
+            while True:
+                with self._cond:
+                    while not self._queue and not self._closing:
+                        self._cond.wait()
+                    if self._closing:
+                        return
+                    batch = list(itertools.islice(self._queue, _BATCH))
+                lag = self._write(batch)
+                if lag is None:
+                    return  # closed while it waited for room
+                with WireStats.thread_lock:
+                    self.stats.sender_frames += len(batch)
+                    self.stats.sender_lag_s += lag
+                with self._cond:
+                    for head, body, _ in batch:
+                        self._queue.popleft()
+                        self._queued -= len(head) + len(body)
+                    room = None
+                    if self._room is not None \
+                            and self._queued <= SEND_QUEUE_BYTES:
+                        room, self._room = self._room, None
+                if room is not None:
+                    self._loop.call_soon_threadsafe(_wake, room)
+        except OSError as e:
+            if not self._closing:
+                try:
+                    self._loop.call_soon_threadsafe(self._failed, e)
+                except RuntimeError:
+                    pass  # the loop is closed: nobody is left to tell
+        finally:
+            with self._cond:
+                self._sock.close()
+
+    def _write(self, batch: list) -> Optional[float]:
+        """The batch's records, whole and in order, through as few
+        `sendmsg` calls as the kernel allows: each interpreter-lock
+        acquisition of the thread carries every record waiting. Returns
+        the sum of their lags (append → the send that took a record's
+        last byte), or None when the channel closed first. A record is a
+        partial write when the send that took its first bytes did not
+        take its last."""
+        bufs = [b for head, body, _ in batch for b in (head, body)]
+        i = took = 0  # record i is the first not taken whole: `took` of it
+        lag = 0.0
+        while i < len(batch):
+            try:
+                sent = self._sock.sendmsg(bufs, (), _NOSIGNAL)
+            except BlockingIOError:
+                if not self._wait_writable():
+                    return None
+                continue
+            now = perf_counter()
+            fresh = not took  # this send took the first bytes of record i
+            n = sent
+            while i < len(batch):
+                size = len(batch[i][0]) + len(batch[i][1])
+                if took + n < size:
+                    break
+                n -= size - took
+                lag += now - batch[i][2]
+                i, took, fresh = i + 1, 0, True
+            if n:  # the send ended inside record i
+                if fresh:
+                    with WireStats.thread_lock:
+                        self.stats.partial += 1
+                        self.stats.partial_bytes += size - n
+                took += n
+            bufs = _advance(bufs, sent)
+        return lag
+
+    def _wait_writable(self) -> bool:
+        """Wait for room in the kernel's buffer (close() ends the wait:
+        a socket shut down polls ready). False: the channel closed."""
+        poller = select.poll()
+        poller.register(self._sock, select.POLLOUT)
+        poller.poll()
+        return not self._closing
+
+
+def _advance(bufs: list, n: int) -> list:
+    """The buffers left once the first n bytes of bufs went."""
+    out = []
+    for b in bufs:
+        if n >= len(b):
+            n -= len(b)
+        else:
+            out.append(memoryview(b)[n:] if n else b)
+            n = 0
+    return out
+
+
+def _sender_name(writer, peer: bytes) -> str:
+    return "net-send " + (peer.hex()[:16] if peer
+                          else str(writer.get_extra_info("peername")))
+
+
+async def _write_record(sender: _Sender, head: bytes, body: bytes) -> None:
+    """One record to the channel's sender thread — counted: the frame
+    and its bytes now, and the time the send loop (and every message
+    queued behind it) parked for room in the sender's queue."""
+    room = sender.put_record(head, body)
+    stats = sender.stats
     stats.sent += 1
-    stats.sent_bytes += len(record)
-    if not before:
-        left = transport.get_write_buffer_size()
-        if left:
-            stats.partial += 1
-            stats.partial_bytes += left
-    t0 = perf_counter()
-    await writer.drain()
-    stats.drain_s += perf_counter() - t0
+    stats.sent_bytes += len(head) + len(body)
+    if room is not None:
+        t0 = perf_counter()
+        await room
+        stats.drain_s += perf_counter() - t0
 
 
 class PlainChannel:
@@ -232,18 +441,20 @@ class PlainChannel:
 
     max_chunk = MAX_CHUNK
 
-    def __init__(self, reader, writer, stats: WireStats):
+    def __init__(self, reader, writer, stats: WireStats,
+                 peer: bytes = b""):
         self.reader = reader
         self.writer = writer
         self.stats = stats
+        self.sender = _Sender(writer, stats, _sender_name(writer, peer))
 
     async def send_frame(self, req_id: int, field: int,
                          parts: list = ()) -> None:
         payload = b"".join(
             p if isinstance(p, (bytes, bytearray)) else bytes(p)
             for p in parts)
-        await _write_record(self.writer, self.stats, struct.pack(
-            "<III", len(payload) + 8, req_id, field) + payload)
+        await _write_record(self.sender, struct.pack(
+            "<III", len(payload) + 8, req_id, field), payload)
 
     async def recv_frame(self) -> tuple[int, int, list]:
         (n,) = struct.unpack("<I", await self.reader.readexactly(4))
@@ -254,6 +465,7 @@ class PlainChannel:
         return req_id, field, [memoryview(body)[8:]]
 
     def close(self) -> None:
+        self.sender.close()
         try:
             self.writer.close()
         except Exception:
@@ -293,7 +505,7 @@ async def _plain_client_handshake(reader, writer, netid: bytes, privkey,
     # confirm over the server's fresh nonce (inside head): we are live
     writer.write(_hmac(netid, b"cli-plain", hello, head))
     await writer.drain()
-    return srv_pub, PlainChannel(reader, writer, stats)
+    return srv_pub, PlainChannel(reader, writer, stats, srv_pub)
 
 
 async def _plain_server_handshake(reader, writer, netid: bytes, privkey,
@@ -323,7 +535,7 @@ async def _plain_server_handshake(reader, writer, netid: bytes, privkey,
     if not hmac_mod.compare_digest(
             confirm, _hmac(netid, b"cli-plain", hello, head)):
         raise HandshakeError("peer failed the liveness confirm")
-    return cli_pub, PlainChannel(reader, writer, stats)
+    return cli_pub, PlainChannel(reader, writer, stats, cli_pub)
 
 
 async def client_handshake(
@@ -363,7 +575,7 @@ async def client_handshake(
     shared = eph.exchange(X25519PublicKey.from_public_bytes(srv_eph))
     secret = _hkdf(shared, b"garage-tpu-channel" + eph_pub + srv_eph)
     chan = SecureChannel(reader, writer, send_key=_hkdf(secret, b"c2s"), recv_key=_hkdf(secret, b"s2c"),
-                         stats=stats)
+                         stats=stats, peer=srv_pub)
     return srv_pub, chan
 
 
@@ -404,7 +616,7 @@ async def server_handshake(
     shared = eph.exchange(X25519PublicKey.from_public_bytes(cli_eph))
     secret = _hkdf(shared, b"garage-tpu-channel" + cli_eph + eph_pub)
     chan = SecureChannel(reader, writer, send_key=_hkdf(secret, b"s2c"), recv_key=_hkdf(secret, b"c2s"),
-                         stats=stats)
+                         stats=stats, peer=cli_pub)
     return cli_pub, chan
 
 
@@ -415,7 +627,7 @@ class SecureChannel:
     max_chunk = MAX_CHUNK
 
     def __init__(self, reader, writer, send_key: bytes, recv_key: bytes,
-                 stats: WireStats):
+                 stats: WireStats, peer: bytes = b""):
         self.reader = reader
         self.writer = writer
         self._send = ChaCha20Poly1305(send_key)
@@ -423,6 +635,7 @@ class SecureChannel:
         self._send_ctr = 0
         self._recv_ctr = 0
         self.stats = stats
+        self.sender = _Sender(writer, stats, _sender_name(writer, peer))
 
     def _nonce(self, ctr: int) -> bytes:
         return ctr.to_bytes(12, "little")
@@ -434,8 +647,7 @@ class SecureChannel:
             for p in parts)
         ct = self._send.encrypt(self._nonce(self._send_ctr), pt, None)
         self._send_ctr += 1
-        await _write_record(self.writer, self.stats,
-                            struct.pack("<I", len(ct)) + ct)
+        await _write_record(self.sender, struct.pack("<I", len(ct)), ct)
 
     async def recv_frame(self) -> tuple[int, int, list]:
         (n,) = struct.unpack("<I", await self.reader.readexactly(4))
@@ -448,6 +660,7 @@ class SecureChannel:
         return req_id, field, [memoryview(pt)[8:]]
 
     def close(self) -> None:
+        self.sender.close()
         try:
             self.writer.close()
         except Exception:
@@ -524,6 +737,9 @@ class Conn:
         self.local_id = local_id
         self.chan = channel
         self.wire: Optional[WireStats] = channel.stats  # None: LocalChannel
+        sender = getattr(channel, "sender", None)  # TCP channels own one
+        if sender is not None:
+            sender.on_error = self._fail
         self.handler = handler  # (peer_id, path, prio, order, payload, stream)
         self._next_id = 2 if initiator else 3
         self._send_items: dict[int, _SendItem] = {}

@@ -439,21 +439,24 @@ def test_series_render_when_traced_and_are_absent_when_not(served):
 
 
 WIRE_FAMILIES = ("rpc_net_frames", "rpc_net_partial_writes",
-                 "rpc_net_drain_seconds", "rpc_net_send_queue_seconds")
+                 "rpc_net_drain_seconds", "rpc_net_send_queue_seconds",
+                 "rpc_net_sender_lag_seconds")
 GONE = ("feeder_throughput_mbps", "feeder_max_batch", "feeder_inline_items",
         "rs_decode_patterns")
 
 
 def test_loop_turns_and_wire_series_on_metrics(served):
-    """Traced and untraced, /metrics carries the four rpc_net_* families
-    (a lone node has no wire connection: all 0) and none of the gauges
-    that nothing read; node_loop_turns only where the profile runs."""
+    """Traced and untraced, /metrics carries the five rpc_net_* families
+    and the sender threads' frame count (a lone node has no wire
+    connection: all 0) and none of the gauges that nothing read;
+    node_loop_turns only where the profile runs."""
     scrape = _bench("lib", "scrape")
     for how in ("traced", "untraced"):
         m0, m1 = (scrape.parse_metrics(served[how][k]) for k in ("m0", "m1"))
         names = {n for n, _ in m1}
         for fam in WIRE_FAMILIES:
             assert {fam + "_count", fam + "_sum"} <= names, (how, fam)
+        assert "rpc_net_sender_frames_count" in names, how
         assert {ls["dir"] for ls in scrape.labels_of(
             m1, "rpc_net_frames_count")} == {"send", "recv"}
         assert not names & set(GONE), how

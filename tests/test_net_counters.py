@@ -12,13 +12,15 @@ import json
 import os
 import socket
 import struct
+from time import perf_counter
 
 import pytest
 
 from garage_tpu.net import LocalNetwork, NetApp
 from garage_tpu.net import conn as conn_mod
-from garage_tpu.net.conn import (MAX_CHUNK, Conn, PlainChannel,
-                                 SecureChannel, WireStats, pack_body)
+from garage_tpu.net.conn import (MAX_CHUNK, SEND_QUEUE_BYTES, Conn,
+                                 PlainChannel, SecureChannel, WireStats,
+                                 pack_body)
 from garage_tpu.net.message import PRIO_HIGH, PRIO_NORMAL
 from test_loop_profile import _bench
 
@@ -86,129 +88,172 @@ def test_a_262145_byte_body_is_two_frames_on_the_wire():
     assert queued == 1  # one message, whatever its frames
 
 
-class _Transport:
-    def __init__(self):
-        self.left = 0
-
-    def get_write_buffer_size(self) -> int:
-        return self.left
-
-
-class _SlowWriter:
-    """A writer whose kernel, asked with an empty buffer, takes 1,000
-    bytes less than the record; with bytes already buffered the record
-    is appended whole, as asyncio's transport does. drain() parks 20 ms
-    until the rest is out, or (`parks` False: the buffer is under its
-    high-water mark) returns at once and leaves it."""
-
-    def __init__(self, parks: bool = True):
-        self.transport = _Transport()
-        self.records: list[bytes] = []
-        self.parks = parks
-
-    def write(self, data) -> None:
-        self.records.append(bytes(data))
-        self.transport.left += len(data) if self.transport.left else 1000
-
-    async def drain(self) -> None:
-        if self.parks:
-            await asyncio.sleep(0.02)
-            self.transport.left = 0
+def _small_buffers(*socks) -> None:
+    """The kernel's least buffers, so a record of MAX_CHUNK never fits."""
+    for s in socks:
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        s.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
 
 
-def _channel(kind, w):
-    return (PlainChannel(None, w, WireStats()) if kind == "plain"
-            else SecureChannel(None, w, KEY_AB, KEY_BA, WireStats()))
+async def _stalled_channel(kind):
+    """A channel over a socketpair with the least buffers, and its peer's
+    raw socket, which nobody reads until the test says."""
+    s, peer = socket.socketpair()
+    _small_buffers(s, peer)
+    r, w = await asyncio.open_connection(sock=s)
+    chan = (PlainChannel(r, w, WireStats()) if kind == "plain"
+            else SecureChannel(r, w, KEY_AB, KEY_BA, WireStats()))
+    return chan, peer
+
+
+async def _read_all(peer, nbytes: int) -> bytes:
+    def read():
+        peer.settimeout(10)
+        got = bytearray()
+        while len(got) < nbytes:
+            chunk = peer.recv(1 << 20)
+            assert chunk, "EOF before every record"
+            got += chunk
+        return bytes(got)
+
+    return await asyncio.to_thread(read)
+
+
+def _records(kind, wire: bytes) -> list:
+    """The (req_id, field, payload) of each record on the wire, in order;
+    a secure record is opened with the nonce that order gives it."""
+    out, off, ctr = [], 0, 0
+    while off < len(wire):
+        (n,) = struct.unpack_from("<I", wire, off)
+        rec = wire[off + 4:off + 4 + n]
+        off += 4 + n
+        if kind == "secure":
+            from cryptography.hazmat.primitives.ciphers.aead import \
+                ChaCha20Poly1305
+            rec = ChaCha20Poly1305(KEY_AB).decrypt(
+                ctr.to_bytes(12, "little"), rec, None)
+            ctr += 1
+        req_id, field = struct.unpack_from("<II", rec)
+        out.append((req_id, field, rec[8:]))
+    return out
+
+
+async def _until(cond, timeout=5.0):
+    deadline = asyncio.get_running_loop().time() + timeout
+    while not cond():
+        assert asyncio.get_running_loop().time() < deadline, "timed out"
+        await asyncio.sleep(0.005)
 
 
 @pytest.mark.parametrize("kind", ["plain", "secure"])
 def test_a_partial_write_is_counted_and_the_drain_timed(kind):
-    w = _SlowWriter()
-    chan = _channel(kind, w)
-    run(chan.send_frame(6, 0x80000003, [b"abc"]))
-    (record,) = w.records
-    st = chan.stats
-    assert (st.sent, st.sent_bytes) == (1, len(record))
-    assert (st.partial, st.partial_bytes) == (1, 1000)
-    assert 0.015 <= st.drain_s < 1.0
-    # what goes on the wire is as it was
+    """Records larger than the socket's buffer, to a peer that reads
+    only after 30 ms: the first one's first send is a partial write,
+    counted with the bytes it left; the send loop parks once the sender
+    holds more than SEND_QUEUE_BYTES, and the park is timed; what goes
+    on the wire is as it was, in order."""
+    n = SEND_QUEUE_BYTES // (MAX_CHUNK + 64) + 2
+
+    async def main():
+        chan, peer = await _stalled_channel(kind)
+
+        async def send_loop():  # one writer, as Conn._send_loop is
+            for i in range(n):
+                await chan.send_frame(2 * i, 0x80000000 | MAX_CHUNK,
+                                      [bytes([i]) * MAX_CHUNK])
+
+        try:
+            send = asyncio.ensure_future(send_loop())
+            await asyncio.sleep(0.03)
+            assert not send.done()  # parked for room
+            wire = await _read_all(peer, n * (
+                secure_record_len(MAX_CHUNK) if kind == "secure"
+                else 12 + MAX_CHUNK))
+            await send
+            await _until(lambda: chan.stats.sender_frames == n)
+            return wire, chan.stats
+        finally:
+            chan.close()
+            peer.close()
+
+    wire, st = run(main())
+    assert (st.sent, st.sent_bytes) == (n, len(wire))
+    # the first went before the peer read at all; later ones may go whole
+    # while the peer reads beside the send
+    assert 1 <= st.partial <= n
+    assert 0 < st.partial_bytes < st.sent_bytes
+    assert 0.015 <= st.drain_s < 5.0
+    assert st.sender_frames == n and st.sender_lag_s > 0.015
+    assert _records(kind, wire) == [
+        (2 * i, 0x80000000 | MAX_CHUNK, bytes([i]) * MAX_CHUNK)
+        for i in range(n)]
     if kind == "plain":
-        assert record == struct.pack("<III", 3 + 8, 6, 0x80000003) + b"abc"
+        assert wire[:12] == struct.pack("<III", MAX_CHUNK + 8, 0,
+                                        0x80000000 | MAX_CHUNK)
     else:
-        from cryptography.hazmat.primitives.ciphers.aead import \
-            ChaCha20Poly1305
-        (n,) = struct.unpack_from("<I", record)
-        pt = ChaCha20Poly1305(KEY_AB).decrypt(bytes(12), record[4:], None)
-        assert n == len(record) - 4
-        assert pt == struct.pack("<II", 6, 0x80000003) + b"abc"
+        assert struct.unpack_from("<I", wire)[0] == 8 + MAX_CHUNK + TAG
 
 
 @pytest.mark.parametrize("kind", ["plain", "secure"])
 def test_a_frame_appended_to_a_buffered_rest_is_no_partial_write(kind):
-    """The second frame goes into a buffer that still holds the first
-    one's rest: the kernel is not asked, so only the first counts, and
-    its 1,000 bytes left are not counted again."""
-    w = _SlowWriter(parks=False)
-    chan = _channel(kind, w)
+    """The second record is appended while the first one's rest still
+    waits for room: the first counts once, though it took many sends,
+    and the second, sent whole by its first send, not at all."""
 
     async def main():
-        await chan.send_frame(6, 0x80000003, [b"abc"])
-        await chan.send_frame(8, 0x80000003, [b"defg"])
+        chan, peer = await _stalled_channel(kind)
+        try:
+            await chan.send_frame(6, 0x80000003, [bytes(MAX_CHUNK)])
+            await _until(lambda: chan.stats.partial == 1)
+            await asyncio.sleep(0.01)
+            await chan.send_frame(8, 0x80000003, [b"defg"])
+            wire = await _read_all(peer, chan.stats.sent_bytes)
+            await _until(lambda: chan.stats.sender_frames == 2)
+            return wire, chan.stats
+        finally:
+            chan.close()
+            peer.close()
 
-    run(main())
-    st = chan.stats
-    assert (st.sent, st.sent_bytes) == (2, sum(map(len, w.records)))
-    assert (st.partial, st.partial_bytes) == (1, 1000)
-    assert w.transport.left == 1000 + len(w.records[1])
-
-
-class _ParkingChannel:
-    """A channel whose first frame parks until released; the others go
-    at once. Nothing ever arrives."""
-
-    max_chunk = MAX_CHUNK
-
-    def __init__(self):
-        self.stats = WireStats()
-        self.release = asyncio.Event()
-        self.frames: list[int] = []
-
-    async def send_frame(self, req_id, field, parts=()):
-        self.frames.append(req_id)
-        if len(self.frames) == 1:
-            await self.release.wait()
-
-    async def recv_frame(self):
-        await asyncio.Event().wait()
-
-    def close(self) -> None:
-        pass
+    wire, st = run(main())
+    assert (st.sent, st.sent_bytes) == (2, len(wire))
+    first = len(wire) - (secure_record_len(4) if kind == "secure" else 16)
+    assert st.partial == 1 and 0 < st.partial_bytes < first
+    assert st.drain_s == 0.0  # two records: far under the bound
+    assert [r[:2] for r in _records(kind, wire)] == [(6, 0x80000003),
+                                                      (8, 0x80000003)]
 
 
-def test_a_message_queued_behind_a_parked_one_counts_its_wait():
+@pytest.mark.parametrize("kind", ["plain", "secure"])
+def test_a_message_queued_behind_a_parked_one_counts_its_wait(kind):
+    """A message that fills the sender's queue parks the send loop; a
+    second one, enqueued meanwhile, waits out the park before its first
+    frame goes (a control frame is not counted)."""
+
     async def main():
-        chan = _ParkingChannel()
+        chan, peer = await _stalled_channel(kind)
         conn = Conn(b"P" * 32, chan, None, initiator=True)
         conn.start()
         try:
-            first = conn.enqueue(2, PRIO_NORMAL, [b"first"])
-            await asyncio.sleep(0.01)  # its frame is handed on, and parks
-            assert chan.frames == [2] and conn.wire.queued == 1
+            first = conn.enqueue(2, PRIO_NORMAL, [bytes(3 << 20)])
+            await _until(lambda: chan.sender._room is not None)
+            assert conn.wire.queued == 1
             second = conn.enqueue(4, PRIO_NORMAL, [b"second"])
             conn._enqueue_ctl("cancel", 6)  # control frames: not counted
             await asyncio.sleep(0.05)
-            chan.release.set()
+            assert conn.wire.queued == 1  # the second still waits
+            read = asyncio.ensure_future(_read_all(peer, 3 << 20))
             await asyncio.wait_for(asyncio.gather(first.done, second.done),
-                                   5)
-            await asyncio.sleep(0.01)
-            return chan.frames, conn.wire.queued, conn.wire.queue_s
+                                   10)
+            await read
+            return conn.wire.queued, conn.wire.queue_s, conn.wire.drain_s
         finally:
             await conn.close()
+            peer.close()
 
-    frames, queued, queue_s = run(main())
-    assert sorted(frames) == [2, 4, 6]
+    queued, queue_s, drain_s = run(main())
     assert queued == 2
-    assert 0.045 <= queue_s < 1.0  # the second waited out the park
+    assert 0.045 <= queue_s < 5.0  # the second waited out the park
+    assert drain_s >= 0.045
 
 
 def test_local_channel_frames_are_not_counted():
@@ -269,9 +314,17 @@ def test_tcp_node_counts_all_its_connections_on_one_wire_stats(
             assert all(conn.wire is a.wire for conn in a.conns.values())
             live = _snap(a.wire)
         finally:
+            senders = [conn.chan.sender for app in (a, b, c)
+                       for conn in app.conns.values()]
             for app in (a, b, c):
                 await app.shutdown()
         await asyncio.sleep(0.05)  # the closed callbacks run
+        # a stopped node leaves no sender thread and no descriptor
+        for s in senders:
+            await asyncio.to_thread(s.thread.join, 5)
+        assert len(senders) == 4
+        assert not any(s.thread.is_alive() for s in senders)
+        assert all(s._sock.fileno() == -1 for s in senders)
         return live, _snap(a.wire), _snap(b.wire), _snap(c.wire)
 
     live, gone, b, c = run(main())
@@ -280,6 +333,8 @@ def test_tcp_node_counts_all_its_connections_on_one_wire_stats(
     # the connections are closed: what they did stays in the node's totals
     for k in WireStats.__slots__:
         assert gone[k] >= live[k], k
+    # every frame handed on was written by a sender thread
+    assert live["sender_frames"] >= 6 and live["sender_lag_s"] > 0
     # every byte sent arrived, at one peer or the other
     assert gone["sent_bytes"] == b["recv_bytes"] + c["recv_bytes"]
     assert gone["recv_bytes"] == b["sent_bytes"] + c["sent_bytes"]
@@ -311,6 +366,10 @@ NEW_METRICS = {
                         / ((1300 - 100) + (1200 - 90))),
     "net_partial_bytes_share": ("%", "lower", "store", "req_p50_ms", None,
                                 100 * (80_000 - 20_000) / (10**8 - 10**6)),
+    "net_offload_share": ("%", "higher", "store", "req_p50_ms", None,
+                          100 * (1298 - 99) / (1300 - 100)),
+    "net_sender_lag_ms": ("ms", "lower", "store", "req_p50_ms", None,
+                          1000 * (0.7 - 0.05) / (1298 - 99)),
 }
 
 SCRAPE = """\
@@ -329,16 +388,19 @@ rpc_net_drain_seconds_count {sent}
 rpc_net_drain_seconds_sum {drain}
 rpc_net_send_queue_seconds_count {queued}
 rpc_net_send_queue_seconds_sum {queue_s}
+rpc_net_sender_frames_count {sender}
+rpc_net_sender_lag_seconds_count {sender}
+rpc_net_sender_lag_seconds_sum {lag}
 """
 TURNS = "node_loop_turns {turns}\n"
 BEFORE = dict(blocks=10, cpu_all=6.0, cpu_loop=4.0, sent=100,
               sent_bytes=10**6, recv=90, recv_bytes=5 * 10**5, partial=10,
               partial_bytes=20_000, drain=0.2, queued=80, queue_s=0.1,
-              turns=1000)
+              turns=1000, sender=99, lag=0.05)
 AFTER = dict(blocks=90, cpu_all=20.0, cpu_loop=12.0, sent=1300,
              sent_bytes=10**8, recv=1200, recv_bytes=6 * 10**7, partial=70,
              partial_bytes=80_000, drain=0.5, queued=400, queue_s=0.9,
-             turns=9000)
+             turns=9000, sender=1298, lag=0.7)
 
 
 class _Ctx:
@@ -384,3 +446,101 @@ def test_metric_file_reads_the_new_series(metric):
         assert untraced is None
     else:
         assert untraced == pytest.approx(want)
+
+
+def test_sender_threads_lose_no_count_on_one_wire_stats():
+    """Sixteen sender threads add to one node's WireStats at once, with
+    the interpreter switching threads as often as it can: no record and
+    no second of lag is lost to a race."""
+    import sys
+    import threading
+
+    async def main():
+        stats = WireStats()
+        pairs = [socket.socketpair() for _ in range(16)]
+        chans, readers = [], []
+        for s, peer in pairs:
+            r, w = await asyncio.open_connection(sock=s)
+            chans.append(PlainChannel(r, w, stats))
+
+            def drain(peer=peer):
+                while peer.recv(1 << 16):
+                    pass
+
+            readers.append(threading.Thread(target=drain, daemon=True))
+            readers[-1].start()
+
+        async def one(chan):
+            for i in range(50):
+                await chan.send_frame(i, 0, [bytes(3000)])
+
+        try:
+            await asyncio.gather(*(one(c) for c in chans))
+            await _until(lambda: stats.sender_frames == 16 * 50, 10)
+        finally:
+            for c in chans:
+                c.close()
+        for t in readers:
+            await asyncio.to_thread(t.join, 5)
+            assert not t.is_alive()
+        for _, peer in pairs:
+            peer.close()
+        return stats
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        st = run(main())
+    finally:
+        sys.setswitchinterval(old)
+    assert st.sent == st.sender_frames == 16 * 50
+    assert st.sent_bytes == 16 * 50 * (12 + 3000)
+    assert st.sender_lag_s > 0
+
+
+class _ScriptedSocket:
+    """Takes the bytes a script says, one entry a `sendmsg`."""
+
+    def __init__(self, takes):
+        self.takes, self.wire = list(takes), bytearray()
+
+    def sendmsg(self, bufs, ancdata=(), flags=0):
+        n = self.takes.pop(0)
+        self.wire += b"".join(bytes(b) for b in bufs)[:n]
+        return n
+
+
+@pytest.mark.parametrize("takes, partial, partial_bytes", [
+    ([180], 0, 0),  # all three records in one send
+    ([100, 80], 0, 0),  # a send ends on a record's boundary
+    ([120, 10, 50], 1, 30),  # B's first send takes 20 of 50
+    ([60, 40, 65, 15], 2, 40 + 15),  # A, then C's first send, short
+])
+def test_a_batch_counts_the_record_its_send_ended_inside(
+        takes, partial, partial_bytes):
+    """One `sendmsg` carries every record waiting; a record counts as a
+    partial write once, when the send that took its first bytes did not
+    take its last, and each record's lag is counted when it is whole."""
+    records = [(b"a" * 10, b"A" * 90), (b"b" * 10, b"B" * 40),
+               (b"c" * 10, b"C" * 20)]
+
+    async def main():
+        s, peer = socket.socketpair()
+        r, w = await asyncio.open_connection(sock=s)
+        chan = PlainChannel(r, w, WireStats())
+        real, fake = chan.sender._sock, _ScriptedSocket(takes)
+        try:
+            chan.sender._sock = fake  # the thread is idle: nothing queued
+            lag = chan.sender._write([(h, b, perf_counter())
+                                      for h, b in records])
+        finally:
+            chan.sender._sock = real
+            chan.close()
+            peer.close()
+        return lag, fake, chan.stats
+
+    lag, fake, st = run(main())
+    assert not fake.takes  # every send the script allows, no more
+    assert bytes(fake.wire) == b"".join(h + b for h, b in records)
+    assert (st.partial, st.partial_bytes) == (partial, partial_bytes)
+    assert lag > 0
